@@ -100,33 +100,16 @@ def trace_encoding_paths(enc, n: int = LINT_N) -> dict:
 def _shard_map_1dev(fn, in_specs):
     """Wrap ``fn`` in ``shard_map`` over a 1-device mesh with
     ``axis_name="shard"`` — the sharded engines' axis plumbing, which
-    is what a sharded trace pins (feature-detecting the check_rep /
-    check_vma kwarg rename across jax versions)."""
-    import inspect
-
+    is what a sharded trace pins; the varying-ness checker is off."""
     import jax
     import numpy as np
+    from jax import shard_map
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    kw = {}
-    try:
-        sm_params = inspect.signature(shard_map).parameters
-        if "check_rep" in sm_params:
-            kw["check_rep"] = False
-        elif "check_vma" in sm_params:
-            kw["check_vma"] = False
-    except (TypeError, ValueError):
-        kw["check_rep"] = False
-
     mesh = Mesh(np.array(jax.devices()[:1]), ("shard",))
     return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=P(), **kw
+        fn, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
 
 
@@ -226,7 +209,7 @@ def trace_engine_pipeline(enc, engine: str = "single",
     exact invocation style of each engine: ``single`` is the
     single-chip call; ``sharded`` wraps the call in ``shard_map`` with
     ``axis_name="shard"`` over a 1-device mesh (the axis plumbing —
-    ``lax.pvary`` carries etc. — is what differs, and is what this
+    ``lax.pcast`` carries etc. — is what differs, and is what this
     trace pins). ``compact`` selects the production
     compaction/tiled-mask branches (see :func:`engine_pipe_params`)."""
     import jax
@@ -251,37 +234,19 @@ def trace_engine_pipeline(enc, engine: str = "single",
     if engine != "sharded":
         raise ValueError(f"unknown engine {engine!r}")
 
-    import inspect
-
+    from jax import shard_map
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    # The replication checker must be off (the pipeline's fori_loop
-    # carries are shard-varying, same as the engine's own usage), but
-    # the kwarg was renamed check_rep -> check_vma across jax
-    # versions — feature-detect rather than assume.
-    kw = {}
-    try:
-        sm_params = inspect.signature(shard_map).parameters
-        if "check_rep" in sm_params:
-            kw["check_rep"] = False
-        elif "check_vma" in sm_params:
-            kw["check_vma"] = False
-    except (TypeError, ValueError):
-        kw["check_rep"] = False
-
+    # The varying-ness checker must be off (the pipeline's fori_loop
+    # carries are shard-varying, same as the engine's own usage).
     mesh = Mesh(np.array(jax.devices()[:1]), ("shard",))
     sm = shard_map(
         lambda fr, fv: pipe(fr, fv, axis_name="shard"),
         mesh=mesh,
         in_specs=(P(), P()),
         out_specs=P(),
-        **kw,
+        check_vma=False,
     )
     return jax.make_jaxpr(sm)(frontier, fval)
 
@@ -379,15 +344,14 @@ def trace_merge_kernels(n: int = LINT_N) -> dict:
     import jax
     import jax.numpy as jnp
 
-    from ..ops.merge import member_sorted, merge_sorted, pallas_available
+    from ..ops.merge import member_sorted, merge_sorted
 
     V, B, NF = 8 * n, 4 * n, n
     a = (jnp.zeros(V, jnp.uint32), jnp.zeros(V, jnp.uint32))
     q = (jnp.zeros(B, jnp.uint32), jnp.zeros(B, jnp.uint32))
     w = (jnp.zeros(NF, jnp.uint32), jnp.zeros(NF, jnp.uint32))
-    impls = ("xla",) + (("pallas",) if pallas_available() else ())
     out = {}
-    for impl in impls:
+    for impl in ("xla", "pallas"):
         out[f"merge:member:{impl}"] = jax.make_jaxpr(
             lambda al, ah, ql, qh, _i=impl: member_sorted(
                 al, ah, ql, qh, impl=_i
@@ -609,12 +573,7 @@ def run_lint(encodings: Optional[tuple] = None,
     all_findings.extend(fs)
     all_stats.extend(st)
     if wave_body:
-        from ..ops.merge import pallas_available
-
-        impls = ("xla",) + (
-            ("pallas",) if pallas_available() else ()
-        )
-        for impl in impls:
+        for impl in ("xla", "pallas"):
             fs, st = lint_wave_body(merge_impl=impl)
             all_findings.extend(fs)
             all_stats.extend(st)

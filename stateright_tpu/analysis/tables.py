@@ -133,7 +133,8 @@ def is_gather(primitive_name: str) -> bool:
 #: the ``--hlo`` cross-check classifies compiled modules with
 #: :data:`HLO_COLLECTIVE_OPS` below — the two vocabularies share the
 #: category strings, so "jaxpr reductions == HLO all-reduces" is one
-#: dict comparison, not a per-consumer mapping. ``pvary``/
+#: dict comparison, not a per-consumer mapping. ``pvary`` (what
+#: ``lax.pcast(..., to="varying")`` traces to) and
 #: ``axis_index`` are deliberately absent: they are axis PLUMBING
 #: (replication typing / shard identity), move zero bytes, and listing
 #: them here would inflate every byte total.

@@ -17,7 +17,7 @@ model-checking layout:
   round 10 it is kept **incrementally sorted**: every wave merges its
   winners into the sorted prefix with a streaming linear merge
   (``ops/merge.py`` — a Pallas kernel on chip, a sort-free XLA
-  fallback elsewhere), so no per-wave pass ever sorts O(C) rows.
+  implementation on the CPU), so no per-wave pass ever sorts O(C) rows.
 * Per wave: vmap-expand the frontier → fingerprint candidates →
   compact the valid candidates (tiled top-B sorts) → ONE B-row
   candidate order sort + a streaming membership pass against the
@@ -93,7 +93,7 @@ rebuild with B-scale sorts plus two O(V + B) streaming passes:
   per-tile sorts remain, but nothing re-sorts visited rows),
 * membership rides a streaming pass over the sorted visited prefix
   (``ops.merge.member_sorted`` — Pallas linear merge on chip, 2-limb
-  binary search on the XLA fallback), and intra-wave duplicates
+  binary search on the XLA implementation), and intra-wave duplicates
   resolve on the adjacent-equal check of the sorted candidates
   (stable sort ⇒ lowest buffer position wins, matching the old
   stable-concat winner exactly),
@@ -102,8 +102,8 @@ rebuild with B-scale sorts plus two O(V + B) streaming passes:
   class-local ``dynamic_update_slice`` block under the v-class
   switch.
 
-The ``merge_impl`` knob (None = auto: Pallas on TPU, XLA fallback on
-CPU/old JAX; ``"pallas_interpret"`` runs the same kernel under the
+The ``merge_impl`` knob (None = auto: Pallas on TPU, XLA on the CPU;
+``"pallas_interpret"`` runs the same kernel under the
 Pallas interpreter so tier-1 pins it on CPU) selects the
 implementation; it is cache-keyed and recorded in lane configs and
 bench provenance.
@@ -357,11 +357,10 @@ def sparse_pair_candidates(enc, frontier_t, fval_f, expand, *, EV, B_p,
     def pv(x):
         """Inside shard_map, fori_loop carries seeded from constants
         are 'unvarying' while the body outputs vary per shard — mark
-        the seeds as shard-varying to keep carry types equal. (Older
-        jax has no pvary and no unvarying carry typing: identity.)"""
-        if axis_name is None or not hasattr(lax, "pvary"):
+        the seeds as shard-varying to keep carry types equal."""
+        if axis_name is None:
             return x
-        return lax.pvary(x, axis_name)
+        return lax.pcast(x, axis_name, to="varying")
 
     bits, cnt = frontier_enabled_bits(
         enc, frontier_t, fval_f, expand,
@@ -447,6 +446,16 @@ def sparse_pair_candidates(enc, frontier_t, fval_f, expand, *, EV, B_p,
     return pidx, live, pslot, cnt, n_pairs, pair_ovf, tile_max
 
 
+def budget_store_path() -> str:
+    """The auto-budget store (``cand_capacity="auto"``), beside the
+    compile cache in ``memplan.cache_root()``."""
+    import os
+
+    from ..memplan import cache_root
+
+    return os.path.join(cache_root(), "stateright_tpu_budgets.json")
+
+
 class SortMergeTpuBfsChecker(TpuBfsChecker):
     """``CheckerBuilder.spawn_tpu_sortmerge()``.
 
@@ -513,7 +522,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
         #: heuristic), and a loud overflow triggers an automatic
         #: resize-and-rerun from the exact observed peak — the same
         #: metric a human re-tuner would read — then persists it for
-        #: later processes (~/.cache/stateright_tpu_budgets.json).
+        #: later processes (``memplan.cache_root()``).
         self.auto_budget = kwargs.get("cand_capacity") == "auto"
         if self.auto_budget:
             kwargs["cand_capacity"] = None
@@ -535,8 +544,8 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
         self.pair_width = pair_width
         self.mask_budget_cells = mask_budget_cells
         #: visited-dedup implementation (ops/merge.py): None = auto
-        #: (Pallas kernel on TPU, sort-free XLA fallback on CPU/old
-        #: JAX); "pallas_interpret" runs the kernel under the Pallas
+        #: (Pallas kernel on TPU, sort-free XLA implementation on the
+        #: CPU); "pallas_interpret" runs the kernel under the Pallas
         #: interpreter — the tier-1 CPU gate for the kernel itself.
         self.merge_impl = resolve_impl(merge_impl)
         #: Tiered visited set (stateright_tpu/tier.py, ROADMAP 1b):
@@ -617,11 +626,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
     # -- auto budget (VERDICT r4 item 7) -----------------------------------
 
     def _budget_store(self):
-        import os
-
-        return os.path.expanduser(
-            "~/.cache/stateright_tpu_budgets.json"
-        )
+        return budget_store_path()
 
     def _budget_key(self) -> str:
         enc = self.encoded
@@ -1576,7 +1581,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
             self._pair_width(),
             self.mask_budget_cells,
             # the visited-dedup implementation changes the compiled
-            # wave program (Pallas kernel vs XLA fallback).
+            # wave program (Pallas kernel vs XLA implementation).
             self.merge_impl,
             # traced runs carry the wave log: a different program.
             self._wave_log_enabled(),
@@ -1919,7 +1924,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
             * the v-ladder switch runs the MEMBERSHIP pass against
               the sorted visited prefix (``ops.merge.member_sorted``:
               the Pallas streaming kernel or the binary-search XLA
-              fallback, per ``merge_impl``); its only branch output
+              implementation, per ``merge_impl``); its only branch output
               is the ``bool[B_eff]`` mask;
             * winners — in KEY order, which IS their order in the
               sorted candidate array — come out of one order-
@@ -1986,7 +1991,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
             is_new = fresh & ~in_visited
             new_count = jnp.sum(is_new)
             # Order-preserving winner compaction (ops/merge.py,
-            # impl-adaptive: O(B) rank scatter on the XLA fallback,
+            # impl-adaptive: O(B) rank scatter on the XLA path,
             # one 4-lane B-scale sort on the Pallas/TPU path):
             # winners lead in key order, the order every consumer now
             # shares (fetch block, plog append, visited merge).

@@ -347,10 +347,20 @@ def compiled_memory(compiled) -> Optional[dict]:
     return out if any_real else None
 
 
+def cache_root() -> str:
+    """The one directory every on-disk cache of this package lives in
+    (XLA compile cache, auto-budget store, memory-analysis store):
+    ``$JAX_COMPILATION_CACHE_DIR`` when it is set, else the fixed,
+    git-ignored ``.stpu_cache`` at the root of the checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(here), ".stpu_cache")
+
+
 def _analysis_store() -> str:
-    return os.path.expanduser(
-        "~/.cache/stateright_tpu_memory_analysis.json"
-    )
+    return os.path.join(cache_root(), "stateright_tpu_memory_analysis.json")
 
 
 def compiled_memory_analysis(chunk_fn, carry_spec,
@@ -364,29 +374,24 @@ def compiled_memory_analysis(chunk_fn, carry_spec,
     configuration pays the AOT pass and later runs — including the
     overhead-measurement pools — read it back. A backend that can't
     REPORT the analysis caches its None (that answer is stable); a
-    FAILED lower/compile returns None without caching, so a
-    transient failure (interrupted process, device busy) doesn't
-    permanently disable the lane for that config.
+    FAILED lower/compile raises, uncached: the same program is about
+    to run on the device, and its compiler's refusal is the finding.
 
     ``on_build`` (round 14, the compile-cache ledger): called exactly
     once per RESOLVED lookup as ``on_build(tier, wall_sec)`` —
     ``"in_process"`` / ``"disk"`` for this module's result caches,
     ``"aot"`` when the AOT lower+compile actually ran (the caller
     refines that tier from its compile monitor: the AOT pass itself
-    may hit the persistent XLA cache). Not called on the degrade
-    paths (no jax, failed compile) — those produced nothing to
-    ledger."""
+    may hit the persistent XLA cache). Not called when the compile
+    raises — that produced nothing to ledger."""
     import time as _time
 
-    t0 = _time.monotonic()
-    try:
-        import jax
+    import jax
 
-        key = hashlib.sha1(
-            f"{jax.default_backend()}/{cache_token!r}".encode()
-        ).hexdigest()
-    except Exception:
-        return None
+    t0 = _time.monotonic()
+    key = hashlib.sha1(
+        f"{jax.default_backend()}/{cache_token!r}".encode()
+    ).hexdigest()
     if key in _ANALYSIS_CACHE:
         if on_build is not None:
             on_build("in_process", _time.monotonic() - t0)
@@ -403,10 +408,7 @@ def compiled_memory_analysis(chunk_fn, carry_spec,
             return disk[key]
     except (OSError, ValueError):
         pass
-    try:
-        compiled = chunk_fn.lower(carry_spec).compile()
-    except Exception:
-        return None  # transient: retry on the next traced run
+    compiled = chunk_fn.lower(carry_spec).compile()
     if on_build is not None:
         on_build("aot", _time.monotonic() - t0)
     result = compiled_memory(compiled)

@@ -21,28 +21,24 @@ membership, so callers mask sentinel queries themselves (the engines
 already gate on ``real``).
 
 Each op ships two implementations, selected by the engines'
-``merge_impl`` knob (auto: Pallas on TPU, XLA fallback elsewhere):
+``merge_impl`` knob (auto: Pallas on TPU, XLA on the CPU):
 
 * ``impl="pallas"`` / ``"pallas_interpret"`` — a hand-written Pallas
   kernel: the merged output is partitioned into ``block``-row tiles by
   a Merge Path diagonal search (:func:`merge_path_starts`, computed in
   plain XLA — G+1 binary searches, negligible), and each grid step
-  loads one bounded window of each input and produces its tile with a
-  rank-based block merge (broadcast compare + one-hot reduce — all
-  VPU-shaped work, no sort, no data-dependent control flow). Grid
-  iteration order is the sequential TPU/interpreter order; the member
-  kernel's overlapping window writes rely on it (last writer owns the
-  tile's true query range). ``pallas_interpret`` runs the SAME kernel
+  DMAs one lane-aligned window of each input from HBM and produces
+  its tile with a rank-based block merge (broadcast compare + one-hot
+  reduce — all VPU-shaped work, no sort, no data-dependent control
+  flow). The member kernel ORs each tile's bits into one
+  VMEM-resident output, so the grid runs sequentially
+  (``"arbitrary"``). ``pallas_interpret`` runs the SAME kernel
   through the Pallas interpreter, which is what lets a CPU-only CI
-  pin the kernel's semantics in tier-1 (tests/test_merge.py).
-  The windows staged per grid step are ``block``-bounded; the backing
-  refs are whole-array (fine under the interpreter and at the ≤C_pad
-  VMEM-resident sizes the ladder classes produce today — chip-scale
-  HBM staging via ``pltpu.ANY`` + double-buffered DMA is the
-  BENCH_r06 follow-up, same as every chip-gated verdict).
+  pin the kernel's semantics in tier-1 (tests/test_merge.py);
+  tests/test_chip_compile.py compiles it for a v5e chip.
 
-* ``impl="xla"`` — a pure-XLA O(B log V + M) fallback for CPU and
-  old-JAX paths: membership is a vectorized 2-limb binary search
+* ``impl="xla"`` — a pure-XLA O(B log V + M) implementation for the
+  CPU: membership is a vectorized 2-limb binary search
   (log₂ V unrolled gather steps — fast on CPU where the sequential
   gathers are cache-friendly, catastrophic on TPU per the round-5
   primitive microbenchmarks — PERF.md "Primitive costs", re-runnable
@@ -72,23 +68,15 @@ DEFAULT_BLOCK = 512
 IMPLS = ("xla", "pallas", "pallas_interpret")
 
 
-def pallas_available() -> bool:
-    try:  # gated: old-JAX paths fall back to the XLA impl
-        from jax.experimental import pallas as _  # noqa: F401
-    except Exception:
-        return False
-    return True
-
-
 def default_impl() -> str:
-    """Auto policy: the Pallas kernel where it wins (TPU), the XLA
-    fallback everywhere else (CPU binary search beats interpreting
-    the kernel by orders of magnitude)."""
+    """Auto policy, by backend: the compiled Pallas kernel on the TPU
+    (the XLA binary search's log₂ V sequential gathers are what it
+    replaces there), the XLA implementation on the CPU (where the
+    binary search is cache-friendly and the kernel could only be
+    interpreted, orders of magnitude slower)."""
     import jax
 
-    if jax.default_backend() == "tpu" and pallas_available():
-        return "pallas"
-    return "xla"
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def resolve_impl(impl):
@@ -98,11 +86,6 @@ def resolve_impl(impl):
         raise ValueError(
             f"merge_impl must be one of {IMPLS} or None (auto), "
             f"got {impl!r}"
-        )
-    if impl.startswith("pallas") and not pallas_available():
-        raise ValueError(
-            f"merge_impl={impl!r} requires jax.experimental.pallas; "
-            "this jax build lacks it — use merge_impl='xla'"
         )
     return impl
 
@@ -119,7 +102,7 @@ def _le(ah, al, bh, bl):
     return (ah < bh) | ((ah == bh) & (al <= bl))
 
 
-# -- XLA fallback ----------------------------------------------------------
+# -- XLA implementation ---------------------------------------------------
 
 
 def _count_in_sorted(a_lo, a_hi, q_lo, q_hi, strict: bool):
@@ -227,14 +210,81 @@ def merge_path_starts(a_lo, a_hi, b_lo, b_hi, block: int):
 
 
 # -- Pallas kernels --------------------------------------------------------
+#
+# Layout rules the TPU compiler (Mosaic) imposes, each of which the
+# interpreter never checked (PERF.md §bring-up):
+#
+# * the Merge Path ``starts`` table is a scalar-prefetch operand (SMEM):
+#   a scalar read from a VMEM ref must sit on a 128-aligned lane;
+# * both inputs stay in HBM (``memory_space=ANY``) and each grid step
+#   DMAs a ``block + 128``-wide window whose start is rounded DOWN to a
+#   multiple of 128: the real window rows are masked by global index,
+#   so the widening costs compares, never correctness. The visited
+#   prefix at paxos-4 sizes (2 x 2.6M uint32, ~21 MB) outgrows VMEM;
+# * reductions run over int32 (Mosaic has no unsigned reductions);
+#   keys are selected by a one-hot whose sum has one non-zero term, so
+#   a bitcast round-trip through int32 is exact;
+# * every pairwise matrix is [column operand, row operand]; the column
+#   copies come from an in-kernel transpose of the (2, W) window.
+
+_LANE = 128
 
 
-def _merge_kernel(starts_ref, a_ref, b_ref, out_ref, *, block, M):
+def _window(block: int) -> int:
+    """Rows per DMA window: a ``block``-wide range whose start is
+    rounded down to the lane tile spans at most ``block + 127`` rows;
+    the window stays a whole number of lane tiles."""
+    return -(-block // _LANE) * _LANE + _LANE
+
+
+def _pad_soa(lo, hi, block: int):
+    """``(2, round_up(n, 128) + window)`` sentinel-padded SoA copy: every
+    aligned window a grid step may DMA lies inside it."""
+    import jax.numpy as jnp
+
+    n = lo.shape[0]
+    pad_to = -(-n // _LANE) * _LANE + _window(block)
+    out = jnp.full((2, pad_to), _SENT, jnp.uint32)
+    return out.at[0, :n].set(lo).at[1, :n].set(hi)
+
+
+def _load_windows(a_hbm, a_al, a_buf, b_hbm, b_al, b_buf, sem):
+    """DMA the two aligned windows into VMEM; returns them row-major
+    ``(2, W)`` and column-major ``(W, 2)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    W = a_buf.shape[1]
+    ca = pltpu.make_async_copy(
+        a_hbm.at[:, pl.ds(a_al, W)], a_buf, sem.at[0]
+    )
+    cb = pltpu.make_async_copy(
+        b_hbm.at[:, pl.ds(b_al, W)], b_buf, sem.at[1]
+    )
+    ca.start()
+    cb.start()
+    ca.wait()
+    cb.wait()
+    a = a_buf[...]
+    b = b_buf[...]
+    return a, a.T, b, b.T
+
+
+def _aligned(x):
+    from jax.experimental import pallas as pl
+
+    return pl.multiple_of((x // _LANE) * _LANE, _LANE)
+
+
+def _merge_kernel(starts_ref, a_hbm, b_hbm, out_ref, a_buf, b_buf, sem,
+                  *, block, M):
     """One output tile of the streaming merge: rank-based block merge
-    of the tile's A/B windows. Validity masks (not sentinel rewrites)
-    keep out-of-range window rows from counting; their computed ranks
-    land >= the tile's real row count by the Merge Path bounds, so the
-    one-hot assembly never aliases a live output row."""
+    of the tile's A/B windows. Validity masks (by global row index)
+    keep rows outside ``[a_s, a_e)`` / ``[b_s, b_e)`` from counting;
+    a valid row's rank is its offset in its own range plus the number
+    of valid other-side rows that merge before it (ties A-first), so
+    the one-hot assembly hits each of the tile's ``rows`` outputs
+    exactly once."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -244,89 +294,144 @@ def _merge_kernel(starts_ref, a_ref, b_ref, out_ref, *, block, M):
     a_s = starts_ref[g]
     a_e = starts_ref[g + 1]
     b_s = d0 - a_s
-    a_cnt = a_e - a_s
-    rows = jnp.minimum(M - d0, block)
-    b_cnt = rows - a_cnt
-    aw = a_ref[:, pl.ds(a_s, block)]
-    bw = b_ref[:, pl.ds(b_s, block)]
-    iot = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    a_ok = iot < a_cnt
-    b_ok = iot < b_cnt
-    # ranks: A-first on ties (strict compare counts B before A,
-    # inclusive compare counts A before-or-at B)
-    b_lt_a = _lt(bw[1][None, :], bw[0][None, :],
-                 aw[1][:, None], aw[0][:, None]) & b_ok[None, :]
-    a_le_b = _le(aw[1][:, None], aw[0][:, None],
-                 bw[1][None, :], bw[0][None, :]) & a_ok[:, None]
-    pos_a = iot + jnp.sum(b_lt_a, axis=1, dtype=jnp.int32)
-    pos_b = iot + jnp.sum(a_le_b, axis=0, dtype=jnp.int32)
-    oh_a = (pos_a[:, None] == iot[None, :]) & a_ok[:, None]
-    oh_b = (pos_b[:, None] == iot[None, :]) & b_ok[:, None]
-    z = jnp.uint32(0)
+    b_e = jnp.minimum(d0 + block, M) - a_e
+    a_al = _aligned(a_s)
+    b_al = _aligned(b_s)
+    a, at, b, bt = _load_windows(a_hbm, a_al, a_buf, b_hbm, b_al, b_buf,
+                                 sem)
+    W = a_buf.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    a_ok_c = (a_al + col >= a_s) & (a_al + col < a_e)
+    b_ok_c = (b_al + col >= b_s) & (b_al + col < b_e)
+    a_ok_r = (a_al + row >= a_s) & (a_al + row < a_e)
+    b_ok_r = (b_al + row >= b_s) & (b_al + row < b_e)
+    # [A rows, B cols]: valid B strictly before each A (A-first ties)
+    b_lt_a = _lt(b[1:2, :], b[0:1, :], at[:, 1:2], at[:, 0:1]) & b_ok_r
+    # [B rows, A cols]: valid A at-or-before each B
+    a_le_b = _le(a[1:2, :], a[0:1, :], bt[:, 1:2], bt[:, 0:1]) & a_ok_r
+    pos_a = a_al + col - a_s + jnp.sum(
+        b_lt_a.astype(jnp.int32), axis=1, keepdims=True
+    )
+    pos_b = b_al + col - b_s + jnp.sum(
+        a_le_b.astype(jnp.int32), axis=1, keepdims=True
+    )
+    out_pos = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    oh_a = (pos_a == out_pos) & a_ok_c  # [W, block]
+    oh_b = (pos_b == out_pos) & b_ok_c
+    covered = jnp.sum(
+        oh_a.astype(jnp.int32), axis=0, keepdims=True
+    ) + jnp.sum(oh_b.astype(jnp.int32), axis=0, keepdims=True)
     for lane in range(2):
+        ka = jax.lax.bitcast_convert_type(at[:, lane:lane + 1], jnp.int32)
+        kb = jax.lax.bitcast_convert_type(bt[:, lane:lane + 1], jnp.int32)
         merged = jnp.sum(
-            jnp.where(oh_a, aw[lane][:, None], z), axis=0,
-            dtype=jnp.uint32,
-        ) + jnp.sum(
-            jnp.where(oh_b, bw[lane][:, None], z), axis=0,
-            dtype=jnp.uint32,
-        )
-        covered = jnp.sum(
-            oh_a.astype(jnp.uint32), axis=0, dtype=jnp.uint32
-        ) + jnp.sum(oh_b.astype(jnp.uint32), axis=0, dtype=jnp.uint32)
-        out_ref[lane, :] = jnp.where(
-            covered > 0, merged, jnp.uint32(_SENT)
+            jnp.where(oh_a, ka, 0), axis=0, keepdims=True
+        ) + jnp.sum(jnp.where(oh_b, kb, 0), axis=0, keepdims=True)
+        out_ref[lane:lane + 1, :] = jnp.where(
+            covered > 0,
+            jax.lax.bitcast_convert_type(merged, jnp.uint32),
+            jnp.uint32(_SENT),
         )
 
 
-def _member_kernel(starts_ref, a_ref, q_ref, out_ref, *, block, M):
-    """One merged tile's membership bits: a query matches iff an equal
-    A key sits in the tile's A window or is the window's immediate
-    predecessor ``A[a_s - 1]`` (ties order A-first, so the equal A key
-    — A is sorted — is the nearest A at or before the query's merge
-    position; Merge Path puts it no earlier than one element left of
-    the window). The ``block``-wide store past the tile's true query
-    range is overwritten by the later tiles that own those queries —
-    correct under the sequential grid order."""
+def _member_kernel(starts_ref, a_hbm, q_hbm, out_ref, a_buf, q_buf, sem,
+                   *, block, M):
+    """One merged tile's membership bits: a query in ``[q_s, q_e)``
+    matches iff an equal A key sits in ``[a_s - 1, a_e)`` — ties order
+    A-first, so the equal A key (A is sorted) is the nearest A at or
+    before the query's merge position, and Merge Path puts it no
+    earlier than one row left of the tile's A range. The A window is
+    aligned below ``a_s - 1`` so that predecessor is always in it.
+    Each query belongs to exactly one tile: the tile ORs its masked
+    bits into the VMEM-resident ``(rows, 128)`` output."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     g = pl.program_id(0)
+
+    @pl.when(g == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.int32)
+
     d0 = g * block
     a_s = starts_ref[g]
     a_e = starts_ref[g + 1]
     q_s = d0 - a_s
-    a_cnt = a_e - a_s
-    aw = a_ref[:, pl.ds(a_s, block)]
-    qw = q_ref[:, pl.ds(q_s, block)]
-    halo = jnp.maximum(a_s - 1, 0)
-    h_lo = a_ref[0, halo]
-    h_hi = a_ref[1, halo]
-    iot = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)[:, 0]
-    a_ok = iot < a_cnt
+    q_e = jnp.minimum(d0 + block, M) - a_e
+    a_al = _aligned(jnp.maximum(a_s - 1, 0))
+    q_al = _aligned(q_s)
+    _, at, q, _ = _load_windows(a_hbm, a_al, a_buf, q_hbm, q_al, q_buf,
+                                sem)
+    W = a_buf.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    a_ok_c = (a_al + col >= a_s - 1) & (a_al + col < a_e)
+    q_ok_r = (q_al + row >= q_s) & (q_al + row < q_e)
     eq = (
-        (aw[0][:, None] == qw[0][None, :])
-        & (aw[1][:, None] == qw[1][None, :])
-        & a_ok[:, None]
+        (at[:, 0:1] == q[0:1, :]) & (at[:, 1:2] == q[1:2, :]) & a_ok_c
     )
-    mem = jnp.any(eq, axis=0) | (
-        (a_s > 0) & (h_lo == qw[0]) & (h_hi == qw[1])
+    hit = jnp.where(
+        q_ok_r, jnp.max(eq.astype(jnp.int32), axis=0, keepdims=True), 0
     )
-    out_ref[0, pl.ds(q_s, block)] = mem.astype(jnp.uint32)
+    r0 = q_al // _LANE
+    for r in range(W // _LANE):
+        rows = pl.ds(r0 + r, 1)
+        out_ref[rows, :] = out_ref[rows, :] | hit[:, r * _LANE:(r + 1) * _LANE]
 
 
-def _pad_soa(lo, hi, pad_to: int):
+def _vmem_limit(block: int, resident: int) -> int:
+    """Scoped-VMEM request: the ``[W, W]`` and ``[W, block]`` int32
+    temporaries the block merge keeps live (a generous 12 of them), the
+    two DMA windows, and the caller's resident output block (twice, for
+    the pipeline's buffers), plus 4 MiB of slack."""
+    W = _window(block)
+    temps = 12 * W * max(W, block) * 4 + 2 * 2 * W * 4
+    return temps + 2 * resident + (4 << 20)
+
+
+def _pallas(kernel, starts, x, y, out_spec, out_shape, out_dtype, block,
+            interpret):
+    import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    n = lo.shape[0]
-    out = jnp.full((2, pad_to), _SENT, jnp.uint32)
-    out = out.at[0, :n].set(lo).at[1, :n].set(hi)
-    return out
+    G = starts.shape[0] - 1
+    W = _window(block)
+    # inside the sharded engine's shard_map the output varies over the
+    # mesh axes its inputs vary over
+    vma = jax.typeof(x).vma | jax.typeof(y).vma
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype, vma=vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(G,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=out_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, W), jnp.uint32),
+                pltpu.VMEM((2, W), jnp.uint32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            # the member kernel's output accumulates across steps
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit(
+                block, out_spec.block_shape[0] * out_spec.block_shape[1] * 4
+            ),
+        ),
+        interpret=interpret,
+    )(starts, x, y)
 
 
 def _merge_pallas(a_lo, a_hi, b_lo, b_hi, block, interpret):
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -337,26 +442,18 @@ def _merge_pallas(a_lo, a_hi, b_lo, b_hi, block, interpret):
     if Na == 0:
         return b_lo, b_hi
     G = max(1, -(-M // block))
-    starts = merge_path_starts(a_lo, a_hi, b_lo, b_hi, block)
-    a = _pad_soa(a_lo, a_hi, Na + block)
-    b = _pad_soa(b_lo, b_hi, Nb + block)
-    out = pl.pallas_call(
+    out = _pallas(
         partial(_merge_kernel, block=block, M=M),
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec(starts.shape, lambda g: (0,)),
-            pl.BlockSpec(a.shape, lambda g: (0, 0)),
-            pl.BlockSpec(b.shape, lambda g: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, block), lambda g: (0, g)),
-        out_shape=jax.ShapeDtypeStruct((2, G * block), jnp.uint32),
-        interpret=interpret,
-    )(starts, a, b)
+        merge_path_starts(a_lo, a_hi, b_lo, b_hi, block),
+        _pad_soa(a_lo, a_hi, block),
+        _pad_soa(b_lo, b_hi, block),
+        pl.BlockSpec((2, block), lambda g, starts: (0, g)),
+        (2, G * block), jnp.uint32, block, interpret,
+    )
     return out[0, :M], out[1, :M]
 
 
 def _member_pallas(a_lo, a_hi, q_lo, q_hi, block, interpret):
-    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -366,23 +463,17 @@ def _member_pallas(a_lo, a_hi, q_lo, q_hi, block, interpret):
     if Na == 0:
         return jnp.zeros(Nq, bool)
     M = Na + Nq
-    G = max(1, -(-M // block))
-    starts = merge_path_starts(a_lo, a_hi, q_lo, q_hi, block)
-    a = _pad_soa(a_lo, a_hi, Na + block)
-    q = _pad_soa(q_lo, q_hi, Nq + block)
-    out = pl.pallas_call(
+    q = _pad_soa(q_lo, q_hi, block)
+    rows = q.shape[1] // _LANE
+    out = _pallas(
         partial(_member_kernel, block=block, M=M),
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec(starts.shape, lambda g: (0,)),
-            pl.BlockSpec(a.shape, lambda g: (0, 0)),
-            pl.BlockSpec(q.shape, lambda g: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Nq + block), lambda g: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, Nq + block), jnp.uint32),
-        interpret=interpret,
-    )(starts, a, q)
-    return out[0, :Nq] != 0
+        merge_path_starts(a_lo, a_hi, q_lo, q_hi, block),
+        _pad_soa(a_lo, a_hi, block),
+        q,
+        pl.BlockSpec((rows, _LANE), lambda g, starts: (0, 0)),
+        (rows, _LANE), jnp.int32, block, interpret,
+    )
+    return out.reshape(-1)[:Nq] != 0
 
 
 # -- public entry points ---------------------------------------------------
@@ -422,10 +513,10 @@ def compact_winners(is_new, pos, lo, hi, nf: int, *, impl: str):
     parent-log append, and visited merge all share.
 
     Implementation-adaptive like the streaming passes: ``xla`` (the
-    CPU fallback) uses an O(B) rank scatter — collision-free
+    CPU implementation) uses an O(B) rank scatter — collision-free
     destinations from an inclusive-rank cumsum, non-winners routed
     past the output and dropped — which on CPU replaces the 4-lane
-    B-row compaction sort that was the fallback path's single
+    B-row compaction sort that was the XLA path's single
     costliest dedup stage (736 ms/wave at paxos-4 shapes, PERF.md
     §merge-kernel). The ``pallas`` impls keep the 4-lane stable sort:
     TPU scatters serialize, B-scale sorts do not."""

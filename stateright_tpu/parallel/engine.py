@@ -192,10 +192,7 @@ class ShardedTpuBfsChecker(TpuBfsChecker):
         import jax.numpy as jnp
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map  # jax >= 0.8
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         enc = self.encoded
         props = list(self.model.properties())
@@ -631,13 +628,6 @@ class ShardedTpuBfsChecker(TpuBfsChecker):
             e_overflow=P(),
             done=P(),
         )
-        # Older jax (no lax.pvary) has no replication rule for
-        # while_loop inside shard_map: disable the rep checker there
-        # (its named workaround; newer jax type-checks varying-ness,
-        # which the vma promotions in ops/hashset.py satisfy).
-        from jax import lax as _lax
-
-        sm_kw = {} if hasattr(_lax, "pvary") else {"check_rep": False}
         # Checkpoint/resume (stateright_tpu/checkpoint.py): a resumed
         # run places snapshot buffers with these exact shardings.
         self._carry_pspecs = dict(specs)
@@ -646,11 +636,9 @@ class ShardedTpuBfsChecker(TpuBfsChecker):
         )
         seed_sm = shard_map(
             seed_local, mesh=mesh, in_specs=P(), out_specs=specs,
-            **sm_kw,
         )
         chunk_sm = shard_map(
             chunk, mesh=mesh, in_specs=(specs,), out_specs=chunk_out,
-            **sm_kw,
         )
         # Tooling hook (analysis/comms.py): the shard_map-wrapped wave
         # body, re-traceable on the GLOBAL carry shapes — the hash
@@ -659,6 +647,5 @@ class ShardedTpuBfsChecker(TpuBfsChecker):
         # path too. Never called by the run loop: no behavioral change.
         self._wave_body_sm = shard_map(
             body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-            **sm_kw,
         )
         return jax.jit(seed_sm), jax.jit(chunk_sm, donate_argnums=0)

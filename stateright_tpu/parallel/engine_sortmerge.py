@@ -55,7 +55,7 @@ a dense ``[F, K]`` bool on any shard, hand paxos/2pc and the compiled
 actor encodings alike), and only real candidates enter the routing
 sort and the shuffle. This engine's invocation style of that pipeline
 — inside ``shard_map`` with ``axis_name="shard"``, which changes the
-traced program (``lax.pvary`` carry plumbing) — is pinned separately
+traced program (``lax.pcast`` carry plumbing) — is pinned separately
 by the kernel lint's ``engine:sharded`` trace
 (stateright_tpu/analysis/lint.py, ``pytest -m lint``).
 
@@ -317,10 +317,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         import jax.numpy as jnp
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map  # jax >= 0.8
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from ..checkers.tpu import frontier_props_t
         from ..checkers.tpu_sortmerge import (
@@ -1009,11 +1006,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                             return kl, kh, pok, nc, eov | ev, rok
 
                         def pv(x):
-                            # Older jax: no pvary, no unvarying carry
-                            # typing — identity.
-                            if not hasattr(lax, "pvary"):
-                                return x
-                            return lax.pvary(x, "shard")
+                            return lax.pcast(x, "shard", to="varying")
 
                         kl, kh, pok, nc_acc, eov_acc, row_ok = (
                             lax.fori_loop(
@@ -1528,11 +1521,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             e_overflow=P(),
             done=P(),
         )
-        # Older jax (no lax.pvary) has no replication rule for
-        # while_loop inside shard_map: disable the rep checker there
-        # (its named workaround). Newer jax type-checks varying-ness
-        # instead, which the pvary/pcast promotions satisfy.
-        sm_kw = {} if hasattr(lax, "pvary") else {"check_rep": False}
 
         if tier_mode:
             # -- the tiered chunk program (stateright_tpu/tier.py) -------
@@ -1643,7 +1631,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     overflow=overflow,
                     done=sel(~cont, c["done"]),
                     pend_valid=jnp.bool_(False),
-                    pend_n=jnp.zeros(1, jnp.uint32),
+                    # per-shard lane: varying, like the wave body's
+                    pend_n=lax.pcast(
+                        jnp.zeros(1, jnp.uint32), "shard", to="varying"
+                    ),
                 )
                 if track_paths:
                     p_lo = jnp.where(
@@ -1700,7 +1691,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             tier_sm = shard_map(
                 tier_chunk, mesh=mesh,
                 in_specs=(specs_t, P_shard), out_specs=chunk_out_t,
-                **sm_kw,
             )
             return jax.jit(tier_sm, donate_argnums=0)
 
@@ -1714,11 +1704,9 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         )
         seed_sm = shard_map(
             seed_local, mesh=mesh, in_specs=P(), out_specs=specs,
-            **sm_kw,
         )
         chunk_sm = shard_map(
             chunk, mesh=mesh, in_specs=(specs,), out_specs=chunk_out,
-            **sm_kw,
         )
         # Tooling hook (analysis/lint.py): the shard_map-wrapped wave
         # body, re-traceable on the GLOBAL carry shapes — the sharded
@@ -1726,7 +1714,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         # sharded wave-body fixture prices the per-shard log path).
         self._wave_body_sm = shard_map(
             body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-            **sm_kw,
         )
         return jax.jit(seed_sm), jax.jit(chunk_sm, donate_argnums=0)
 

@@ -334,6 +334,8 @@ class _StallChecker(SortMergeTpuBfsChecker):
             return (out[0], _SlowStats(out[1], self.STALL_SEC),
                     *out[2:])
 
+        # the traced run's memory analysis compiles the real program
+        slow_chunk.lower = chunk_fn.lower
         return seed_fn, slow_chunk
 
 

@@ -214,9 +214,10 @@ def test_untraced_run_emits_nothing_but_keeps_plan():
 
 def test_compiled_analysis_transient_failure_not_cached(tmp_path,
                                                         monkeypatch):
-    """A FAILED lower/compile must not poison the persisted analysis
-    cache — only a backend that genuinely can't report the analysis
-    caches its None."""
+    """A FAILED lower/compile raises (the compiler's refusal is not
+    hidden) and does not poison the persisted analysis cache — only a
+    backend that genuinely can't report the analysis caches its
+    None."""
     monkeypatch.setattr(
         memplan, "_analysis_store",
         lambda: str(tmp_path / "mem_analysis.json"),
@@ -227,7 +228,8 @@ def test_compiled_analysis_transient_failure_not_cached(tmp_path,
         def lower(self, spec):
             raise RuntimeError("device busy")
 
-    assert memplan.compiled_memory_analysis(Broken(), {}, "tok") is None
+    with pytest.raises(RuntimeError, match="device busy"):
+        memplan.compiled_memory_analysis(Broken(), {}, "tok")
     assert "tok" not in str(memplan._ANALYSIS_CACHE)
     assert not os.path.exists(str(tmp_path / "mem_analysis.json"))
     # a working compile afterwards lands and persists
@@ -549,3 +551,30 @@ def test_real_traced_ab_diffs_clean(tmp_path):
     assert not rep["memory"]["divergences"]
     assert not rep["memory"]["regressions"]
     assert rep["ok"]
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_cache_root_follows_env(tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR``, when set, is the compile cache's
+    directory and the root of the budget and memory-analysis stores;
+    unset, all three live in the checkout's fixed ``.stpu_cache``. A
+    fresh process, because JAX reads the variable at import."""
+    code = (
+        "import jax\n"
+        "from stateright_tpu import memplan\n"
+        "from stateright_tpu.checkers.tpu import _enable_persistent_cache\n"
+        "_enable_persistent_cache()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(memplan._analysis_store())\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(REPO_ROOT, ".stpu_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=REPO_ROOT,
+        capture_output=True, text=True, check=True,
+    ).stdout.split()
+    assert out[0] == want
+    assert os.path.dirname(out[1]) == want

@@ -154,7 +154,7 @@ _REDUCE = frozenset({
 })
 
 _CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "jit", "pjit", "closed_call", "core_call", "remat", "checkpoint",
     "custom_jvp_call", "custom_vjp_call",
 })
 

@@ -3,7 +3,7 @@
 One walk, every consumer: :func:`iter_eqns` yields each equation of a
 (closed) jaxpr together with the stack of enclosing higher-order
 primitives — ``scan``/``while`` bodies, ``cond``/``switch`` branches
-(with the branch index), ``pjit``/``custom_jvp`` call bodies, anything
+(with the branch index), ``jit``/``custom_jvp`` call bodies, anything
 that stores sub-jaxprs in its params — so a rule can ask "is this pad
 inside a switch branch?" without re-implementing the descent. The
 codegen-shape audit (:func:`audit_jaxpr`) and the lint rules
@@ -169,7 +169,7 @@ _UNIFORM_RESULT_COLLECTIVES = frozenset({
 
 #: call-like primitives whose sub-jaxpr I/O maps positionally.
 _CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
+    "jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
     "custom_vjp_call", "remat", "checkpoint",
 })
 

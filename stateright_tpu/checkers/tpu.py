@@ -1197,10 +1197,17 @@ class TpuBfsChecker(Checker):
         )
         box: dict = {}
         done = threading.Event()
+        # the worker records into the caller's tracer, thread-scoped
+        # ones included (a resident-service session's)
+        tracer = telemetry.current_tracer()
 
         def run():
             try:
-                box["out"] = thunk()
+                if tracer is None:
+                    box["out"] = thunk()
+                else:
+                    with tracer.activate_thread():
+                        box["out"] = thunk()
             except BaseException as exc:  # re-raised on the host side
                 box["exc"] = exc
             finally:
@@ -1226,6 +1233,7 @@ class TpuBfsChecker(Checker):
             )
             telemetry.emit("watchdog_timeout", **att)
             raise _ckpt.WatchdogTimeout(chunk_no, deadline, att)
+        worker.join()  # it has finished: no thread outlives the chunk
         if "exc" in box:
             raise box["exc"]
         return box["out"]

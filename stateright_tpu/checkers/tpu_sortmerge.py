@@ -1762,8 +1762,8 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
                 "property indices < 32; reorder properties() so eventually "
                 f"properties come first (got index {max(evt_idx)})"
             )
-        # XLA:CPU needs a gather-arrangement workaround in the sparse
-        # fetch (see the pay_fetch branches below).
+        # the pair-state gather seam is backend-adaptive
+        # (encoding.pair_step_seam)
         cpu_backend = jax.default_backend() == "cpu"
         K, W, F = enc.max_actions, enc.width, self.frontier_capacity
         C = self.capacity
@@ -2757,7 +2757,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
                 else:
                     (ck_lo, ck_hi, pair_ok, prow, eov,
                      succ_t, wv_canon) = eval_pairs(pidx, live, pslot)
-                    if pay_fetch and not cpu_backend:
+                    if pay_fetch:
                         # Without this barrier XLA fuses the pair-step
                         # producer (frontier/params/sendtab gathers +
                         # the whole transition ALU) separately into
@@ -2795,7 +2795,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
                     c["disc_found"], c["disc_lo"], c["disc_hi"],
                 )
 
-                if pay_fetch and not cpu_backend:
+                if pay_fetch:
                     # Packed candidate payload kept alive through the
                     # merge: winners' states, key limbs, and parent
                     # meta (ebits + parent fp, pre-gathered per pair as
@@ -2823,39 +2823,14 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
                         )
                         # seam transpose of the small winner block
                         return st.T, p_lo, p_hi, eb_w, k_lo_w, k_hi_w
-                elif pay_fetch:
-                    # XLA:CPU workaround (round 5): gathering a
-                    # CONCATENATED [Ba, W+k] payload in this sparse
-                    # program livelocks the XLA:CPU thunk runtime
-                    # inside the chunk while-loop (one Eigen thread
-                    # spins forever; bisected to exactly this op
-                    # arrangement — the same packed fetch is fine in
-                    # the dense wave, and fine on TPU). Same math,
-                    # separate gathers: the successor tensor is still
-                    # reused (no transition recompute), and the
-                    # column gather off [W, Ba] already returns the
-                    # [W, n] block the frontier write wants (measured
-                    # on CPU: cheaper than materializing a [Ba, W]
-                    # row view first — the fetch touches only the
-                    # winner columns).
-                    def fetch(nf_row):
-                        par_row = pidx[nf_row] // jnp.uint32(EV)
-                        return (
-                            succ_t[:, nf_row],
-                            f_lo[par_row] if track_paths else None,
-                            f_hi[par_row] if track_paths else None,
-                            eb[par_row],
-                            ck_lo[nf_row],
-                            ck_hi[nf_row],
-                        )
                 else:
                     # Recompute mode (chunked or over-budget payload):
                     # winners' successors are recomputed from their
                     # (row, slot) pairs — exact by the
                     # SparseEncodedModel purity contract. Index-feeding
-                    # gathers stay 1-D (the XLA:CPU hazard above), and
-                    # step_cols hands back the [W, n] block directly —
-                    # this path is transpose-free end to end.
+                    # gathers stay 1-D, and step_cols hands back the
+                    # [W, n] block directly — this path is
+                    # transpose-free end to end.
                     def fetch(nf_row):
                         pidx_w = pidx[nf_row]
                         par_row = pidx_w // jnp.uint32(EV)

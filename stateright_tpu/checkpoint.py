@@ -1219,11 +1219,11 @@ FAILURE_CLASSES = ("transient", "oom", "hang", "shard_fault",
 
 class WatchdogTimeout(RuntimeError):
     """A chunk dispatch/sync exceeded its derived watchdog deadline
-    (checkers/tpu.py ``watchdog_factor``) — the hung-dispatch shape of
-    the bisected XLA:CPU thunk-runtime livelock family (ROADMAP
-    §carried), which no exception path ever surfaces. Supervised: the
-    policy classifies it ``hang`` and retries from the last snapshot
-    where checkpointing allows (CPython cannot cancel a wedged XLA
+    (checkers/tpu.py ``watchdog_factor``) — the hung-dispatch shape
+    (a chunk that never syncs), which no exception path ever
+    surfaces. Supervised: the policy classifies it ``hang`` and
+    retries from the last snapshot where checkpointing allows
+    (CPython cannot cancel a wedged XLA
     sync — the hung worker thread is abandoned as a daemon — so
     in-process recovery re-dispatches and a genuinely wedged runtime
     exhausts the retry budget and raises this error through:
@@ -1234,10 +1234,10 @@ class WatchdogTimeout(RuntimeError):
                  attribution: Optional[dict] = None):
         super().__init__(
             f"watchdog: chunk {chunk} exceeded its derived deadline "
-            f"of {deadline_sec:.2f}s with no sync — a hung dispatch "
-            "(the thunk-runtime livelock shape). The dispatch thread "
-            "is abandoned (XLA offers no cancellation); recover from "
-            "the last snapshot or investigate the attribution."
+            f"of {deadline_sec:.2f}s with no sync — a hung dispatch. "
+            "The dispatch thread is abandoned (XLA offers no "
+            "cancellation); recover from the last snapshot or "
+            "investigate the attribution."
         )
         self.chunk = int(chunk)
         self.deadline_sec = float(deadline_sec)

@@ -37,10 +37,10 @@ docstring; this module makes every cell of the crash matrix
   the run onto the surviving shards — after which the fault stops
   firing, exactly as a dropped chip stops mattering;
 * **chunk-dispatch hang** — a ``hang`` sleeps ``hang_sec`` (default
-  30 s) at the dispatch site instead of raising: the XLA:CPU
-  thunk-runtime livelock family's shape (ROADMAP §carried), which no
-  exception path ever surfaces. Only the hung-dispatch watchdog
-  (checkers/tpu.py, ``watchdog_factor``) can see it;
+  30 s) at the dispatch site instead of raising: the shape of a
+  chunk that never syncs (the XLA:CPU chunk stalls of ROADMAP D5),
+  which no exception path ever surfaces. Only the hung-dispatch
+  watchdog (checkers/tpu.py, ``watchdog_factor``) can see it;
 * **collective-seam raise** — a ``raise`` armed at the
   ``collective_seam`` site fires only on mesh engines, just before
   the sharded dispatch: a device error surfacing from the all_to_all
@@ -266,7 +266,7 @@ def fire(site: str, chunk: int, shards=None) -> None:
             # buffers. os._exit is the honest model.
             os._exit(KILL_EXIT_CODE)
         if f["action"] == "hang":
-            # the livelock shape: the dispatch wedges, nothing raises
+            # a wedged dispatch: nothing raises
             import time
 
             time.sleep(f["hang_sec"])

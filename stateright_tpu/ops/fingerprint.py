@@ -34,6 +34,47 @@ def splitmix64(z: U64, xp=np) -> U64:
     return z
 
 
+def _golden(i: int) -> int:
+    return _GOLDEN * (i + 1) & 0xFFFFFFFFFFFFFFFF
+
+
+def _unrolled_lanes(w: int, xp) -> int:
+    """How many lanes the fold unrolls: all of them on numpy and on
+    the TPU, only the first under XLA:CPU.
+
+    XLA:CPU's fusion emitters evaluate an unrolled fold that is fused
+    into an indexed consumer (a dynamic-update-slice, a gather, a
+    scalar dynamic-slice) without sharing its subexpressions, so the
+    cost per element grows exponentially with W: 29 ms per element at
+    W=11 against about 2 us at W=4 (jaxlib 0.9.0). A chunk that
+    writes parent fingerprints into the parent log ran 30-60 s per
+    wave that way. A loop cannot fuse, so on the CPU the remaining
+    lanes fold in a ``fori_loop`` and every consumer reads one
+    materialized digest. The first lane stays unrolled so the carry
+    varies over the same mesh axes as the lanes inside a shard_map."""
+    if xp is np:
+        return w
+    import jax
+
+    return min(w, 1) if jax.default_backend() == "cpu" else w
+
+
+def _fold_loop(start: int, w: int, lane, zero, h: U64) -> U64:
+    """Lanes ``[start, w)`` of the fold as a ``fori_loop`` (jax only)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    g = np.array([_golden(i) for i in range(w)], dtype=np.uint64)
+    g_lo = jnp.asarray((g & 0xFFFFFFFF).astype(np.uint32))
+    g_hi = jnp.asarray((g >> 32).astype(np.uint32))
+
+    def body(i, h):
+        lane_i = u64_add(U64(lane(i), zero), U64(g_lo[i], g_hi[i]))
+        return tuple(splitmix64(u64_xor(U64(*h), lane_i), jnp))
+
+    return U64(*lax.fori_loop(start, w, body, tuple(h)))
+
+
 def _fold_lanes(w: int, lane, zero, xp) -> Tuple[Any, Any]:
     """THE fingerprint fold — one body for every lane layout.
 
@@ -45,12 +86,12 @@ def _fold_lanes(w: int, lane, zero, xp) -> Tuple[Any, Any]:
     device-side ones (the transposed engines).
     """
     h = U64(zero + xp.uint32(_SEED & 0xFFFFFFFF), zero + xp.uint32(_SEED >> 32))
-    for i in range(w):
-        lane_i = u64_add(
-            U64(lane(i), zero),
-            u64_const(_GOLDEN * (i + 1) & 0xFFFFFFFFFFFFFFFF, xp),
-        )
+    unrolled = _unrolled_lanes(w, xp)
+    for i in range(unrolled):
+        lane_i = u64_add(U64(lane(i), zero), u64_const(_golden(i), xp))
         h = splitmix64(u64_xor(h, lane_i), xp)
+    if unrolled < w:
+        h = _fold_loop(unrolled, w, lane, zero, h)
     # Reserve 0 as "empty" (NonZeroU64 convention).
     both_zero = (h.lo == 0) & (h.hi == 0)
     lo = xp.where(both_zero, xp.uint32(1), h.lo)
@@ -62,7 +103,8 @@ def fingerprint_u32v(vec: Any, xp=np) -> Tuple[Any, Any]:
 
     ``vec``: uint32[..., W] → ``(lo, hi)``: uint32[...] each, never
     both zero. The fold is sequential over the W lanes (W is small and
-    static; XLA unrolls it) and vectorized over every leading axis.
+    static; it unrolls everywhere but XLA:CPU, see
+    :func:`_unrolled_lanes`) and vectorized over every leading axis.
     """
     vec = xp.asarray(vec, dtype=xp.uint32)
     zero = xp.zeros(vec.shape[:-1], dtype=xp.uint32)

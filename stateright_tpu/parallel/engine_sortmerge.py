@@ -346,8 +346,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         ample_words = self._resolve_ample_words()
         props = list(self.model.properties())
         n_props = len(props)
-        # XLA:CPU needs a gather-arrangement workaround in the tile
-        # build (see dest_block below).
+        # XLA:CPU keeps its own gather arrangement in the tile build
+        # (see dest_block below).
         cpu_backend = jax.default_backend() == "cpu"
         evt_idx = [
             i for i, p in enumerate(props)
@@ -1155,13 +1155,12 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 else:
                     pmeta = fr_meta[cand_par]
                 if cpu_backend:
-                    # XLA:CPU workaround (round 5, mirrors the
-                    # single-chip engine): gathering a CONCATENATED
-                    # multi-lane payload livelocks the CPU thunk
-                    # runtime inside the chunk while-loop with some
-                    # encodings (observed with compiled actor
-                    # encodings + paths). Same math, per-destination
-                    # separate gathers.
+                    # XLA:CPU: per-destination separate gathers, same
+                    # math. The packed arrangement below pads a class
+                    # result inside the switch branch, which the
+                    # kernel lint (run on the CPU) refuses
+                    # (no-branch-pad-concat); kept until that pad
+                    # leaves the chip's program (ROADMAP D5).
                     def dest_block(start):
                         idx = jnp.clip(
                             start + jnp.arange(Bd_c, dtype=jnp.uint32),

@@ -384,13 +384,8 @@ def test_abd_sharded_sortmerge_fingerprint_only():
     composition (VERDICT r3 weak #7). The 544 count and the property
     set must match the host.
 
-    Fingerprint-only on the CPU mesh: with track_paths=True this exact
-    configuration (compiled encoding × sharded engine) hits an XLA:CPU
-    thunk-runtime livelock (same bug family as the concatenated-payload
-    gather livelock bisected in the single-chip engine, PERF.md
-    §gathers; hand encodings with paths run fine on the same mesh —
-    see test_sharded_sparse_paxos_with_paths). The full compiled ×
-    sharded × paths composition is covered on real TPU by
+    Fingerprint-only (track_paths=False): the engine's no-parent-log
+    program. The compiled × sharded × paths composition is
     test_abd_sharded_paths_on_tpu below."""
     from stateright_tpu.actor.register import DEFAULT_VALUE
     from stateright_tpu.models.linearizable_register import (
@@ -425,15 +420,11 @@ def test_abd_sharded_sortmerge_fingerprint_only():
 def test_abd_sharded_paths_on_tpu():
     """Compiler × sharding × PATHS (VERDICT r4 weak #4 / item 6): the
     compiled ABD encoding through spawn_tpu_sharded_sortmerge with
-    track_paths=True, a replayed discovery path included. Runs on the
-    real TPU only (single-device mesh) — on XLA:CPU this composition
-    livelocks the thunk runtime (see the fingerprint-only test above).
-    Executed on TPU v5 lite 2026-07-31: 544 states, 14s
-    end-to-end including compile, 11-action 'value chosen' path."""
+    track_paths=True, a replayed discovery path included, on a
+    single-device mesh (the first CPU device, or the chip). Executed
+    on TPU v5 lite 2026-07-31: 544 states, 14s end-to-end including
+    compile, 11-action 'value chosen' path."""
     import jax
-
-    if jax.default_backend() == "cpu":
-        pytest.skip("XLA:CPU thunk-runtime livelock; TPU-only")
     import numpy as np
     from jax.sharding import Mesh
 

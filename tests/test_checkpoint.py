@@ -24,6 +24,7 @@ import warnings
 import pytest
 
 from stateright_tpu import faultinject
+from stateright_tpu.checkers.hybrid import HybridChecker
 from stateright_tpu.checkpoint import (
     SnapshotCorruptError,
     SnapshotIncompatibleError,
@@ -358,13 +359,32 @@ def test_corrupt_budget_store_falls_back_with_warning(
 
 
 class _SlowHostTwoPhase(TwoPhaseSys):
-    """Host enumeration slowed so the device side wins the race
-    deterministically (the device engine never calls actions() during
-    the search — only path replay does)."""
+    """Host enumeration held until a side has claimed the race, so the
+    device side wins at any CPU load. The device side runs in the
+    race's calling thread and calls actions() only to replay paths;
+    the host DFS runs on a thread of its own, the one held here."""
+
+    race_decided = None  # a threading.Event, set by _RaceHybrid
+    device_thread = None
 
     def actions(self, state):
-        time.sleep(0.002)
+        if threading.current_thread() is not self.device_thread:
+            self.race_decided.wait(60)  # bounded: a broken race fails
         return super().actions(state)
+
+
+class _RaceHybrid(HybridChecker):
+    """The hybrid racer, releasing the held host when a side claims."""
+
+    @property
+    def winner(self):
+        return self.__dict__.get("_winner")
+
+    @winner.setter
+    def winner(self, name):
+        self.__dict__["_winner"] = name
+        if name is not None:
+            self.model.race_decided.set()
 
 
 def test_hybrid_resume_cancels_loser_cleanly(tmp_path):
@@ -383,9 +403,10 @@ def test_hybrid_resume_cancels_loser_cleanly(tmp_path):
     before = threading.active_count()
     tracer = RunTracer()
     with tracer.activate():
-        hy = _SlowHostTwoPhase(rm_count=3).checker().spawn_hybrid(
-            **dev_kw
-        )
+        model = _SlowHostTwoPhase(rm_count=3)
+        model.race_decided = threading.Event()
+        model.device_thread = threading.current_thread()
+        hy = _RaceHybrid(model.checker(), **dev_kw)
         hy.resume_from(snap)
         hy.join()
     assert hy.winner == "device"

@@ -542,3 +542,62 @@ def test_cli_runtime_flags():
             tier_hot_rows=None, degrade_on_fault=False,
             watchdog=None, straggler_factor=None,
         )
+
+
+# -- the suite's own time limits (tests/conftest.py) ----------------------
+
+
+def test_stalled_chunk_fails_only_its_own_test(monkeypatch):
+    """A chunk that never syncs, in a checker built without watchdog
+    options: the suite's chunk limit raises WatchdogTimeout in this
+    test, well inside its wall limit, and a fresh check runs to its
+    exact count while the abandoned chunk's thread still sleeps."""
+    import threading
+    import time
+
+    import conftest
+
+    monkeypatch.setattr(conftest, "CHUNK_LIMIT_SEC", 2.0)
+    c = _twopc3()
+    assert c.watchdog_cap_sec == c.watchdog_grace_sec == 2.0
+    faultinject.arm("hang", "mid_chunk", 1, hang_sec=15.0)
+    t0 = time.monotonic()
+    with pytest.raises(WatchdogTimeout) as ei:
+        c.join()
+    assert ei.value.chunk == 1
+    assert time.monotonic() - t0 < conftest.TEST_LIMIT_SEC / 10
+    abandoned = [t for t in threading.enumerate()
+                 if t.name == "stpu-watchdog-chunk1"]
+    assert abandoned and abandoned[0].is_alive()
+    fresh = _twopc3().join()
+    assert fresh.unique_state_count() == 288
+    assert abandoned[0].is_alive()
+
+
+def test_wall_limit_interrupts_python_waits():
+    """The per-test wall limit raises in the main thread out of a
+    Python-level wait, then the enclosing test's own limit resumes."""
+    import signal
+    import threading
+    import time
+
+    from conftest import WallLimitExceeded, wall_limit
+
+    outer_left = signal.getitimer(signal.ITIMER_REAL)[0]
+    assert outer_left > 0  # this test runs under its own limit
+    t0 = time.monotonic()
+    with pytest.raises(WallLimitExceeded, match="probe"):
+        with wall_limit(0.5, "probe"):
+            threading.Event().wait(60)
+    assert time.monotonic() - t0 < 10
+    assert 0 < signal.getitimer(signal.ITIMER_REAL)[0] < outer_left
+
+
+@pytest.mark.time_limit(900)
+def test_time_limit_marker_gives_the_limit():
+    import signal
+
+    import conftest
+
+    left = signal.getitimer(signal.ITIMER_REAL)[0]
+    assert conftest.TEST_LIMIT_SEC < left <= 901

@@ -154,15 +154,30 @@ def _failed_rules(res):
 
 def test_2pc_certifies_all_seven_obligations():
     res = certify_encoding(TwoPhaseSysEncoded(4), use_cache=False)
+    # fully precise interpretation: nothing was over-approximated (a
+    # call primitive the analyzer does not enter lands here first)
+    assert res.collapsed == []
     assert res.certified
     assert res.sym_certified is True
     assert res.ample_certified is True
     rules = [f.rule for f in res.obligations]
     assert tuple(rules) == SYM_OBLIGATIONS + AMPLE_OBLIGATIONS
     assert all(f.severity == "info" for f in res.obligations)
-    # fully precise interpretation: nothing was over-approximated
-    assert res.collapsed == []
     assert res.analyzer_sec > 0
+
+
+def test_call_primitive_names_match_jax():
+    """Both jaxpr walkers enter the call primitive this JAX emits for
+    a nested jit: a rename (``pjit`` became ``jit``) fails here instead
+    of silently over-approximating every nested call."""
+    import jax
+
+    from stateright_tpu.analysis import soundness, walker
+
+    jx = jax.make_jaxpr(lambda x: jax.jit(lambda y: y + 1)(x))(1)
+    name = jx.jaxpr.eqns[0].primitive.name
+    assert name in soundness._CALL_PRIMS
+    assert name in walker._CALL_PRIMS
 
 
 def test_register_family_certifies_symmetry():

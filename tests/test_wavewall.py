@@ -58,7 +58,11 @@ def test_wave_wall_report_on_cpu():
     """End-to-end on a real captured carry: the report carries the
     wall/carry-baseline timings and a non-empty category breakdown
     whose data-movement categories are populated (the wave writes
-    class-local blocks via dynamic-update-slice by design)."""
+    class-local blocks via dynamic-update-slice by design). The
+    checker runs the chip's merge (the Pallas kernels, interpreted),
+    so the breakdown is of the chip's op set: the CPU ``xla`` merge
+    scatters by design (ops/merge.py ``_merge_xla``,
+    ``compact_winners``)."""
     c = (
         TwoPhaseSys(rm_count=4)
         .checker()
@@ -68,6 +72,7 @@ def test_wave_wall_report_on_cpu():
             frontier_capacity=1 << 9,
             cand_capacity=1 << 11,
             track_paths=False,
+            merge_impl="pallas_interpret",
         )
     )
     c.keep_final_carry = True

@@ -32,8 +32,8 @@ answer and never a hang:
   (**continue-degraded**);
 * ``collective_raise`` — an injected raise at the mesh collective
   seam under supervision must recover like any chunk fault;
-* ``hang_watchdog`` — an injected chunk-dispatch hang (the livelock
-  shape: a sleep, no exception) under an armed watchdog: the breach
+* ``hang_watchdog`` — an injected chunk-dispatch hang (a wedged
+  dispatch: a sleep, no exception) under an armed watchdog: the breach
   must be DETECTED within the derived deadline, and the run either
   recovers from the snapshot (**recovered**) or raises the
   WatchdogTimeout with its attribution (**refused** — loudly, never
@@ -541,7 +541,7 @@ def main():
         faultinject.disarm_all()
 
     # -- cell: chunk-dispatch hang -> watchdog ----------------------------
-    # (the livelock shape: a sleep at the dispatch site, no exception
+    # (a wedged dispatch: a sleep at the dispatch site, no exception
     # — only the watchdog can see it; the verdict must be recovered
     # or refused-loudly-with-attribution, never a hang)
     from stateright_tpu.checkpoint import WatchdogTimeout

@@ -51,7 +51,7 @@ from .. import faultinject
 from ..checker import Checker, CheckerBuilder
 from ..encoding import EncodedModel, has_trivial_boundary
 from ..model import Expectation
-from ..ops.fingerprint import fingerprint_u32v
+from ..ops.fingerprint import fingerprint_row_host, fingerprint_u32v
 from ..ops.hashset import DeviceHashSet, insert
 from ..ops.u64 import U64, u64_add
 from ..path import Path
@@ -2242,8 +2242,7 @@ class TpuBfsChecker(Checker):
             row = canonicalize_rows(
                 self.sym_spec, np.asarray(row, np.uint32), np
             )
-        lo, hi = fingerprint_u32v(row.reshape(1, -1), np)
-        return _fp_int(lo[0], hi[0])
+        return fingerprint_row_host(row)
 
     def _reconstruct(self, fp: int) -> Path:
         """Walk the parent forest, then replay the HOST model matching
@@ -2266,10 +2265,14 @@ class TpuBfsChecker(Checker):
         # attribution needs them apart.
         with telemetry.span("cex_parent_drain"):
             generated = self._build_generated()
-        with telemetry.span("cex_host_decode"):
-            return self._decode_path(generated, fp)
+        with telemetry.span("cex_host_decode") as meta:
+            return self._decode_path(generated, fp, meta)
 
-    def _decode_path(self, generated, fp: int) -> Path:
+    def _decode_path(self, generated, fp: int, meta=None) -> Path:
+        """Replay the host model along the parent chain of ``fp``.
+        ``meta`` (a span's meta dict) receives ``replay_fingerprints``,
+        the successor rows fingerprinted, and ``steps``, the path's
+        length."""
         fps = [fp]
         while True:
             parent = generated.get(fps[-1])
@@ -2291,6 +2294,7 @@ class TpuBfsChecker(Checker):
                 "encode()/init_vecs() disagree"
             )
         steps = []
+        replayed = 0
         for next_fp in fps[1:]:
             found = False
             for action in model.actions(state):
@@ -2298,6 +2302,7 @@ class TpuBfsChecker(Checker):
                 if next_state is None:
                     continue
                 vec = np.asarray(enc.encode(next_state), np.uint32)
+                replayed += 1
                 if self._vec_fp(vec) == next_fp:
                     steps.append((state, action))
                     state = next_state
@@ -2309,4 +2314,8 @@ class TpuBfsChecker(Checker):
                     "vectorized step_vec disagrees with the host model"
                 )
         steps.append((state, None))
-        return Path(steps)
+        path = Path(steps)
+        if meta is not None:
+            meta["replay_fingerprints"] = replayed
+            meta["steps"] = len(path)
+        return path

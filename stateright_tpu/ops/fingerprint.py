@@ -81,9 +81,12 @@ def _fold_lanes(w: int, lane, zero, xp) -> Tuple[Any, Any]:
     ``lane(i)`` returns lane ``i`` of the batch (the only thing the
     row-major and lane-major entry points differ in); the seed, the
     per-lane GOLDEN offsets, the splitmix64 chain, and the NonZeroU64
-    zero-reservation live here exactly once, so a hash change cannot
-    silently fork the host-side digests (drain, seeds) from the
-    device-side ones (the transposed engines).
+    zero-reservation live here, so a hash change cannot silently fork
+    the host-side digests (drain, seeds) from the device-side ones (the
+    transposed engines). The one other body is
+    :func:`fingerprint_row_host`, the same chain over one row in native
+    64-bit Python ints for host path replay; ``tests/test_host_fold.py``
+    pins it to this one bit for bit at every width.
     """
     h = U64(zero + xp.uint32(_SEED & 0xFFFFFFFF), zero + xp.uint32(_SEED >> 32))
     unrolled = _unrolled_lanes(w, xp)
@@ -130,6 +133,33 @@ def fingerprint_u32v_t(vec_t: Any, xp=np) -> Tuple[Any, Any]:
     return _fold_lanes(
         vec_t.shape[0], lambda i: vec_t[i], zero, xp
     )
+
+
+_MASK64 = 0xFFFFFFFFFFFFFFFF
+
+
+def fingerprint_row_host(row: Any) -> int:
+    """Host-only digest of ONE encoded row, as a Python ``int``.
+
+    Equal to ``fingerprint_u32v(row[None], np)`` combined to 64 bits
+    (same seed, lane offsets, splitmix64 chain and zero reservation),
+    but folded in Python ints masked to 64 bits instead of the uint32
+    limb emulation, which costs a dozen numpy calls per step on arrays
+    of one element (on an x86 server core: 2 us a row at W=2 against
+    186 us, 22 us at W=16 against 1.6 ms). Host path replay
+    fingerprints successors one row at a time, so this is its fold;
+    batches stay on :func:`fingerprint_u32v`.
+    """
+    h = _SEED
+    for i, lane in enumerate(np.asarray(row, dtype=np.uint32).ravel().tolist()):
+        z = h ^ ((lane + _golden(i)) & _MASK64)
+        z ^= z >> 30
+        z = (z * _MIX1) & _MASK64
+        z ^= z >> 27
+        z = (z * _MIX2) & _MASK64
+        h = z ^ (z >> 31)
+    # Reserve 0 as "empty" (NonZeroU64 convention).
+    return h or 1
 
 
 def fingerprint_u32v_int(vec: Any) -> Any:

@@ -14,7 +14,8 @@ directory and hits); the last line is the JSON verdict, printed only
 when every phase passed on a TPU.
 
 ``--chips 4`` runs only the sharded sort-merge engine over a 4-chip
-mesh on paxos 4c/3s with explicit per-shard budgets, and shows that the
+mesh on paxos 4c/3s with the per-shard budgets of
+``models/paxos_tpu.py`` ``SHARDED_SIZES[4]``, and shows that the
 visited shards live on four distinct devices.
 
 Exits non-zero, with no verdict, when JAX finds no TPU (including under
@@ -32,20 +33,6 @@ import time
 TWOPC5_STATES = 8832
 PAXOS4_STATES = 2372188
 PAXOS4_DISCOVERIES = {"value chosen"}
-
-#: per-shard budgets of the 4-chip paxos-4 run: the sharded engine
-#: refuses cand_capacity="auto". capacity holds a shard's quarter of
-#: the 2,372,188 states with 1.3x room for fingerprint-routing skew;
-#: frontier and candidates are a quarter of the single-chip wave peaks
-#: (~524K frontier rows, 686,045 candidates) with 2-3x room.
-SHARDED_PAXOS4 = dict(
-    capacity=3 << 18,
-    frontier_capacity=1 << 18,
-    cand_capacity=1 << 19,
-    tile_rows=1 << 17,
-    track_paths=False,
-)
-
 
 def log(msg: str) -> None:
     print(msg, flush=True)
@@ -159,14 +146,16 @@ def phase_sharded_paxos4() -> None:
 
     from stateright_tpu.checkers import tpu
     from stateright_tpu.models.paxos import PaxosModelCfg, paxos_model
+    from stateright_tpu.models.paxos_tpu import SHARDED_SIZES
 
-    log(f"phase sharded paxos 4c/3s over 4 chips: {SHARDED_PAXOS4}")
+    log(f"phase sharded paxos 4c/3s over 4 chips: {SHARDED_SIZES[4]}")
     before = tpu.compile_ledger_totals()
     t0 = time.monotonic()
     checker = (
         paxos_model(PaxosModelCfg(client_count=4, server_count=3))
         .checker()
-        .spawn_tpu_sharded_sortmerge(n_shards=4, **SHARDED_PAXOS4)
+        .spawn_tpu_sharded_sortmerge(n_shards=4, track_paths=False,
+                                     **SHARDED_SIZES[4])
         .join()
     )
     n = checker.unique_state_count()

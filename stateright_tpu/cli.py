@@ -12,6 +12,7 @@ dispatches the same surface for every bundled workload:
     python -m stateright_tpu paxos check 2 [network]
     python -m stateright_tpu paxos-compiled check-tpu (compiled encoding)
     python -m stateright_tpu paxos check-tpu 4 --trace    (run telemetry)
+    python -m stateright_tpu paxos check-tpu 4 --shards 4 (four-device mesh)
     python -m stateright_tpu paxos explore 2 localhost:3000
     python -m stateright_tpu paxos spawn
 
@@ -274,12 +275,39 @@ def _2pc(sub: str, args: list[str]) -> None:
 def _paxos(sub: str, args: list[str]) -> None:
     from .models.paxos import PaxosModelCfg, paxos_model
 
+    shards, args = _pop_shards(args)
     client_count = _opt(args, 0, 2)
     cfg = PaxosModelCfg(client_count=client_count, server_count=3)
+    if shards is not None and sub != "check-tpu":
+        raise SystemExit("--shards needs paxos check-tpu")
     if sub == "check":
         network = _network(args, 1)
         print(f"Model checking Single Decree Paxos with {client_count} clients.")
         _report(paxos_model(cfg, network).checker().spawn_dfs())
+    elif sub == "check-tpu" and shards is not None:
+        # the sharded sort-merge engine over a mesh of the first
+        # `shards` devices, with the per-shard budgets a run has proven
+        from .models.paxos_tpu import SHARDED_SIZES
+
+        if client_count not in SHARDED_SIZES:
+            raise SystemExit(
+                f"paxos check-tpu --shards has per-shard budgets for "
+                f"{sorted(SHARDED_SIZES)} clients (got {client_count}): "
+                "SHARDED_SIZES in models/paxos_tpu.py"
+            )
+        try:
+            checker = paxos_model(cfg).checker().spawn_tpu_sharded_sortmerge(
+                n_shards=shards,
+                track_paths=client_count <= 2,
+                **SHARDED_SIZES[client_count],
+            )
+        except ValueError as exc:
+            raise SystemExit(f"paxos check-tpu --shards {shards}: {exc}")
+        print(
+            f"Model checking Single Decree Paxos with {client_count} "
+            f"clients on the TPU wave engine over {shards} shards."
+        )
+        _report(checker)
     elif sub == "check-tpu":
         print(
             f"Model checking Single Decree Paxos with {client_count} "
@@ -745,6 +773,12 @@ def _usage(model: str | None = None) -> None:
         "compares two)"
     )
     print(
+        "       --shards S on paxos check-tpu shards the search over the "
+        "first S devices (the sharded sort-merge engine: all_to_all "
+        "frontier shuffles and an owner-sharded visited set; per-shard "
+        "budgets in models/paxos_tpu.py SHARDED_SIZES)"
+    )
+    print(
         "       --checkpoint-every=N|auto [--checkpoint-path=P] on "
         "check-tpu lanes snapshots the chunk carry every N chunks "
         "(atomic; supervised fault retry; 'auto' picks the cadence "
@@ -840,6 +874,25 @@ def _pop_connect_flag(argv: list[str]) -> tuple[str | None, list[str]]:
         else:
             rest.append(a)
     return addr, rest
+
+
+def _pop_shards(args: list[str]) -> tuple[int | None, list[str]]:
+    """Strip ``--shards S`` / ``--shards=S`` from a lane's arguments:
+    the number of devices a check-tpu lane shards its search over."""
+    shards = None
+    rest = []
+    it = iter(args)
+    for a in it:
+        if a == "--shards" or a.startswith("--shards="):
+            val = a.split("=", 1)[1] if "=" in a else next(it, "")
+            if not val.isdigit() or int(val) < 1:
+                raise SystemExit(
+                    f"--shards {val}: needs a whole number of devices >= 1"
+                )
+            shards = int(val)
+        else:
+            rest.append(a)
+    return shards, rest
 
 
 def _pop_trace_flag(argv: list[str]) -> tuple[str | None, list[str]]:

@@ -1463,6 +1463,26 @@ STRUCTURAL_SIZES = {
             flat_budget_bytes=2 << 30, mask_budget_cells=1 << 26),
 }
 
+#: PER-SHARD engine sizes of the sharded sort-merge engine
+#: (``spawn_tpu_sharded_sortmerge``, ``paxos check-tpu N --shards S``),
+#: which takes no ``cand_capacity="auto"``. Only sizes a run has proven:
+#:
+#: * 2 clients: the whole 16,668-state space fits one shard, so the
+#:   same budgets serve every shard count; proven on the virtual CPU
+#:   mesh at S = 1, 2, 4 and 8 (tests/test_cli_shards.py).
+#: * 4 clients: proven on a four-chip v5e host at S = 4 (chip_smoke.py
+#:   --chips 4; the benchmark cell paxos-4c3s-mesh4.verify): capacity
+#:   holds a quarter of the 2,372,188 states with 1.3x room for routing
+#:   skew; frontier and candidates are a quarter of the one-chip wave
+#:   peaks (~524K frontier rows, 686,045 candidates) with 2-3x room.
+#:   Fewer shards overflow these budgets.
+SHARDED_SIZES = {
+    2: dict(capacity=1 << 15, frontier_capacity=1 << 12,
+            cand_capacity=1 << 14),
+    4: dict(capacity=3 << 18, frontier_capacity=1 << 18,
+            cand_capacity=1 << 19, tile_rows=1 << 17),
+}
+
 
 def paxos_encoded(
     client_count: int = 2, server_count: int = 3, put_count: int = 1

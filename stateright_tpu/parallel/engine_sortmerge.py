@@ -209,6 +209,28 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             )
             self.metrics["max_wave_candidates"] = int(extra[2])
 
+    def _run_stats(self) -> dict:
+        """The trace's run_end summary plus the shuffle's run-level
+        counters: the shard count, the rows routed off-shard (the
+        psum'd ``sent`` counter) and the worst per-wave candidate skew
+        (max/mean over shards) of the run's ``shard_wave`` events."""
+        from .. import telemetry
+
+        stats = super()._run_stats()
+        worst = None
+        tracer = telemetry.current_tracer()
+        if tracer is not None:
+            with tracer._lock:
+                events = list(tracer.events)
+            bal = telemetry.shard_balance(events, run=tracer._run_idx)
+            worst = (bal or {}).get("candidate_skew_worst")
+        stats.update(
+            n_shards=self.n_shards,
+            routed_rows=self.metrics.get("shuffle_volume"),
+            candidate_skew_worst=worst["skew"] if worst else None,
+        )
+        return stats
+
     def _wave_log_pairs_valid(self) -> bool:
         # The sharded GLOBAL log wrapper can't see the enabled-pair
         # popcount (it lives inside the per-shard wave switch): lane 1

@@ -984,6 +984,37 @@ def test_cli_trace_writes_artifacts(tmp_path, monkeypatch, capsys):
 # -- shared artifact numbering / provenance ------------------------------
 
 
+def test_cli_trace_sharded_run_end_counters(tmp_path, monkeypatch):
+    """``paxos check-tpu 2 --shards 4 --trace`` on the CPU mesh: the
+    run_end event carries the shuffle's run-level counters, each equal
+    to what the per-shard ``shard_wave`` events and the engine's own
+    psum'd ``sent`` counter give."""
+    from stateright_tpu import cli
+    from stateright_tpu.telemetry import shard_balance
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the virtual CPU mesh")
+    monkeypatch.setattr(artifacts, "repo_root", lambda: str(tmp_path))
+    got = []
+    monkeypatch.setattr(cli._SESSION_HOOK, "hook", got.append,
+                        raising=False)
+    cli.main(["paxos", "check-tpu", "2", "--shards", "4", "--trace"])
+    (checker,) = got
+    assert checker.unique_state_count() == 16668
+    jsonl = next(f for f in os.listdir(tmp_path) if f.endswith(".jsonl"))
+    evs = load_trace(str(tmp_path / jsonl))
+    validate_events(evs)
+    assert {e["shard"] for e in evs if e["ev"] == "shard_wave"} == \
+        {0, 1, 2, 3}
+    end = next(e for e in evs if e["ev"] == "run_end")
+    bal = shard_balance(evs)
+    assert end["n_shards"] == bal["n_shards"] == 4
+    assert end["routed_rows"] == checker.metrics["shuffle_volume"] == \
+        bal["routed_rows_total"] > 0
+    assert end["candidate_skew_worst"] == \
+        bal["candidate_skew_worst"]["skew"] >= 1.0
+
+
 def test_artifact_numbering_shared_across_families(tmp_path):
     root = str(tmp_path)
     assert artifacts.next_round(root) == 1

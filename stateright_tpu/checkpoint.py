@@ -92,12 +92,19 @@ SNAPSHOT_VERSION = 1
 
 _SENT = 0xFFFFFFFF
 
-#: trace-gated carry leaves resume may synthesize (zeros) when the
-#: snapshot and the resuming run disagree on tracer state or
-#: waves_per_sync — their content is telemetry, rewritten inside the
-#: chunk before any row is read.
+#: carry leaves resume may synthesize (zeros) when the snapshot lacks
+#: them or disagrees on their shape (tracer state, waves_per_sync, a
+#: re-shard): trace-gated telemetry rewritten inside the chunk before
+#: any row is read, the sharded engine's per-receive-class wave
+#: counter (telemetry, restarting at zero) and its routed-payload
+#: scratch.
 _SYNTH_LEAVES = frozenset({"wlog", "slog", "swave", "wv_pairs",
-                           "wv_canon", "pstash"})
+                           "wv_canon", "pstash", "route", "recv_waves"})
+
+#: carry leaves a snapshot leaves out: scratch rewritten inside every
+#: wave before any row is read (the sharded engine's routed payload),
+#: so resume synthesizes them like the trace-gated leaves above.
+_SCRATCH_LEAVES = frozenset({"route"})
 
 #: tiered-mode carry leaves a snapshot may carry on top of the
 #: untiered spec (the deferred-commit staging of stateright_tpu/
@@ -328,7 +335,8 @@ def write_snapshot(checker, carry, path: str, *, chunk: int,
     from . import telemetry
 
     t0 = time.monotonic()
-    buffers = {k: np.asarray(v) for k, v in carry.items()}
+    buffers = {k: np.asarray(v) for k, v in carry.items()
+               if k not in _SCRATCH_LEAVES}
     tier_block = None
     if tier is not None:
         runs = tier.snapshot_runs()

@@ -16,11 +16,12 @@ Per wave, inside one ``shard_map``-wrapped ``lax.while_loop``:
    candidates by destination shard — routing and compaction in ONE
    sort, no per-destination scatters (the job_market.rs:66-147
    replacement, with the communication pattern chosen for ICI),
-3. each destination's contiguous run is sliced into its fixed-size
-   tile of the send buffer (``dynamic_slice`` at the run offset —
-   contiguous copies, never scatters) and one ``lax.all_to_all`` swaps
-   tiles so every candidate lands on the shard owning
-   ``fp_lo % n_shards``,
+3. the routed payload is written, in that order, into the shard's
+   fixed-shape ``route`` carry lane; each destination's contiguous run
+   is sliced out of it into a tile of the wave's RECEIVE CLASS
+   (``dynamic_slice`` at the run offset — contiguous copies, never
+   scatters) and one ``lax.all_to_all`` swaps tiles so every
+   candidate lands on the shard owning ``fp_lo % n_shards``,
 4. owner-local dedup is the streaming sort-merge (round 10, shared
    with the single-chip engine): each shard's visited array is kept
    INCREMENTALLY SORTED, one B-scale sort orders the received
@@ -44,9 +45,23 @@ single-chip version caused the round-2 rm=8 cliff. Waves now dispatch
 through the same frontier/visited class ladders as the single-chip
 engine — every shard agrees on the class via ``lax.pmax`` over local
 frontier/unique counts (collectives are collective: the ``lax.switch``
-must take the same branch on every shard) — and the routing sort,
-per-destination tiles, the ``all_to_all`` itself, and the merge all
-scale with the running wave. Encodings implementing
+must take the same branch on every shard). The frontier class sizes
+the send side: expansion, fingerprints, the routing sort and the
+routed payload. The receive side has a class of its own, because the
+frontier class does not bound a wave's routed rows: at paxos-4's
+mesh budgets ``F_c * EV`` fills the candidate budget in every
+frontier class, so a per-destination tile sized by it is the lossless
+cap ``Bd_c`` in every wave. After the routing sort all shards agree on
+the largest per-destination count (``lax.pmax``) and take the smallest
+receive class that holds it, on the ladder ``Bd, Bd/4, Bd/16, Bd/64``
+(no rung below 128 rows; :func:`recv_ladder`, :func:`recv_tile_class`)
+— the tiles, the ``all_to_all`` and the owner-local merge (candidate
+sort, membership, winner compaction, visited append, winner fetch)
+run at ``S * Bd_r`` rows, sized by the wave. The class holds the
+largest count, so no candidate is dropped and every wave's visited
+set, winners and their order are those of the lossless cap. The two
+switches run in sequence, so the merge is traced once per receive
+class. Encodings implementing
 ``SparseEncodedModel`` get sparse action dispatch here too: pairs are
 extracted and stepped shard-locally (the shared pipeline in
 checkers/tpu_sortmerge.py — including the round-6 WORD-NATIVE enabled
@@ -83,6 +98,24 @@ from ..checkers.tpu_sortmerge import SortMergeTpuBfsChecker
 _SENT = 0xFFFFFFFF
 
 
+def recv_ladder(top: int, step: int = 4, rungs: int = 4,
+                floor: int = 128) -> list:
+    """The receive-tile classes, smallest first: the lossless
+    per-destination cap ``top``, then ``top/4``, ``top/16``,
+    ``top/64``, no rung below ``floor`` rows (nor below ``top`` where
+    that is smaller)."""
+    lo = min(floor, top)
+    return sorted({max(lo, top // step ** i) for i in range(rungs)})
+
+
+def recv_tile_class(n, ladder):
+    """Index of the smallest rung of ``ladder`` that holds ``n`` rows,
+    for a Python int or a traced scalar alike. A count above the top
+    rung picks the top; the routing stage's overflow check has then
+    already refused the wave."""
+    return sum((n > rung) * 1 for rung in ladder[:-1])
+
+
 def dest_tile_width(w: int, track_paths: bool) -> int:
     """Lanes of a routed destination tile (see dest_tile_pack)."""
     return (w + 3 if track_paths else w + 1) + 2
@@ -91,10 +124,11 @@ def dest_tile_width(w: int, track_paths: bool) -> int:
 def dest_tile_pack(jnp, state, par_lo, par_hi, ebits, key_lo, key_hi):
     """THE sharded routed-tile lane layout: ``[state 0:W | par_lo W |
     par_hi W+1 (paths only) | ebits E-1 | key_lo E | key_hi E+1]``
-    with ``E = W+3`` (paths) or ``W+1`` — every ``dest_block`` variant
-    packs through this helper, and ``merge_stage`` unpacks by the same
-    offsets (``recv[:, E]``/``recv[:, EB]``), so the tile layout can't
-    drift between the three pack sites and the post-shuffle merge.
+    with ``E = W+3`` (paths) or ``W+1`` — the routed payload and the
+    recomputed (memory-lean) tiles both pack through this helper, and
+    ``merge_stage`` unpacks by the same offsets (``recv[:, E]``/
+    ``recv[:, EB]``), so the tile layout can't drift between the pack
+    sites and the post-shuffle merge.
     NOT the single-chip payload layout: ``payload_pack``
     (checkers/tpu_sortmerge.py) orders key limbs before parent meta
     and is unpacked by ``payload_unpack`` at the merge fetch.
@@ -208,12 +242,20 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 int(extra[1]) << 32
             )
             self.metrics["max_wave_candidates"] = int(extra[2])
+        n_recv = len((self._build_info or {}).get("recv_classes", ()))
+        if n_recv and extra.size >= 3 + n_recv:
+            # waves per receive class, smallest first (pack_stats'
+            # last lanes)
+            self.metrics["recv_class_waves"] = [
+                int(x) for x in extra[-n_recv:]
+            ]
 
     def _run_stats(self) -> dict:
         """The trace's run_end summary plus the shuffle's run-level
         counters: the shard count, the rows routed off-shard (the
-        psum'd ``sent`` counter) and the worst per-wave candidate skew
-        (max/mean over shards) of the run's ``shard_wave`` events."""
+        psum'd ``sent`` counter), the worst per-wave candidate skew
+        (max/mean over shards) of the run's ``shard_wave`` events, and
+        the waves run at each receive class, smallest first."""
         from .. import telemetry
 
         stats = super()._run_stats()
@@ -228,6 +270,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             n_shards=self.n_shards,
             routed_rows=self.metrics.get("shuffle_volume"),
             candidate_skew_worst=worst["skew"] if worst else None,
+            recv_class_waves=self.metrics.get("recv_class_waves"),
         )
         return stats
 
@@ -243,8 +286,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         # Mirrors the shard_map out_specs in _build_programs: these
         # carry leaves shard along their row axis, so their ledger
         # rows report per_shard_bytes = bytes / n_shards.
-        return ("vkeys", "plog", "pl_n", "frontier", "fval", "ebits",
-                "n_loc", "u_loc", "slog", "swave")
+        return ("vkeys", "plog", "pl_n", "route", "frontier", "fval",
+                "ebits", "n_loc", "u_loc", "slog", "swave")
 
     def _lane_config(self) -> dict:
         lane = super()._lane_config()
@@ -260,6 +303,12 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             # occupancy watch uses the exact-capacity headroom
             # threshold (stateright_tpu/occupancy.py)
             visited_exact=True,
+            # the receive-tile classes; the shard log's dest_cap is
+            # the wave's class, the top the lossless cap the routing
+            # overflow check holds a run to
+            recv_classes=recv_ladder(
+                max(p[5] for p in self._f_class_params())
+            ),
         )
         return lane
 
@@ -334,6 +383,46 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
 
     # -- device programs ---------------------------------------------------
 
+    def _f_class_params(self) -> list:
+        """Static per-shard shapes of each frontier class, smallest
+        first: ``(F_c, NT, T, R_src, B_c, Bd_c)`` — frontier rows,
+        pair tiles and their rows, the candidate buffer, the candidate
+        budget, and the lossless per-destination cap."""
+        from ..checkers.tpu_sortmerge import _divisor_at_least, _ladder
+
+        K, F, S = (self.encoded.max_actions, self.frontier_capacity,
+                   self.n_shards)
+        B_user = min(self.cand_capacity or F * K, F * K)
+        use_sparse = self._use_sparse()
+        EV = self._pair_width() if use_sparse else 0
+        out = []
+        for F_c in _ladder(self.f_min, F, self.ladder_step):
+            if use_sparse:
+                NPg = F_c * EV
+                B_c = min(B_user, NPg)
+                compaction = NPg > B_c
+                want = -(-NPg // self.tile_rows)
+                if compaction:
+                    # Keep the packed-append headroom ≤ B_c/4 (see
+                    # the single-chip engine's make_sparse_wave).
+                    want = max(want, -(-(4 * NPg) // max(B_c, 1)))
+                NT = _divisor_at_least(F_c, want) if compaction else 1
+                T = F_c // NT
+                R_src = (B_c + T * EV) if compaction else NPg
+            else:
+                NT = T = 1
+                R_src = F_c * K
+                B_c = min(B_user, R_src)
+            if self.bucket_capacity is not None:
+                Bd_c = min(self.bucket_capacity, B_c)
+            elif S == 1:
+                Bd_c = B_c
+            else:
+                # Near-uniform fingerprint split: 4x the expected share.
+                Bd_c = min(B_c, max(128, (4 * B_c + S - 1) // S))
+            out.append((F_c, NT, T, R_src, B_c, Bd_c))
+        return out
+
     def _build_programs(self, n0: int, tiered: bool = False):
         import jax
         import jax.numpy as jnp
@@ -343,7 +432,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
 
         from ..checkers.tpu import frontier_props_t
         from ..checkers.tpu_sortmerge import (
-            _divisor_at_least,
             _ladder,
             sparse_pair_candidates,
         )
@@ -368,8 +456,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         ample_words = self._resolve_ample_words()
         props = list(self.model.properties())
         n_props = len(props)
-        # XLA:CPU keeps its own gather arrangement in the tile build
-        # (see dest_block below).
         cpu_backend = jax.default_backend() == "cpu"
         evt_idx = [
             i for i, p in enumerate(props)
@@ -384,7 +470,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         K, W, F = enc.max_actions, enc.width, self.frontier_capacity
         S = self.n_shards
         C = self.capacity
-        B_user = min(self.cand_capacity or F * K, F * K)
         use_sparse = self._use_sparse()
         EV = self._pair_width() if use_sparse else 0
         sparse_has_trunc = sparse_boundary = False
@@ -426,10 +511,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         # * the PER-SHARD mesh log (SHARD_LOG_FIELDS) — NOT
         #   psum-collapsed: each shard's wave row (local frontier/
         #   pairs/candidates, routed and received rows, dest-tile
-        #   fill vs the lossless Bd cap, local post-dedup new, local
-        #   visited count) is assembled INSIDE the wave switch (where
-        #   those quantities exist) as the ``swave`` carry lane, and
-        #   the body wrapper appends it to ``slog``. Both logs ride
+        #   fill vs the wave's receive class, local post-dedup new,
+        #   local visited count) is assembled INSIDE the wave
+        #   switches (where those quantities exist) as the ``swave``
+        #   carry lane, and the body wrapper appends it to ``slog``. Both logs ride
         #   the chunk carry and download at the existing per-chunk
         #   sync (slog as a second, shard-sharded stats output — same
         #   dispatch, same blocking point, no extra round trip).
@@ -446,34 +531,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         f_ladder = _ladder(self.f_min, F, self.ladder_step)
         v_ladder = _ladder(self.v_min, C, self.v_ladder_step)
 
-        def class_params(fc: int):
-            """Static per-frontier-class shapes (per shard)."""
-            F_c = f_ladder[fc]
-            if use_sparse:
-                NPg = F_c * EV
-                B_c = min(B_user, NPg)
-                compaction = NPg > B_c
-                want = -(-NPg // self.tile_rows)
-                if compaction:
-                    # Keep the packed-append headroom ≤ B_c/4 (see
-                    # the single-chip engine's make_sparse_wave).
-                    want = max(want, -(-(4 * NPg) // max(B_c, 1)))
-                NT = _divisor_at_least(F_c, want) if compaction else 1
-                T = F_c // NT
-                R_src = (B_c + T * EV) if compaction else NPg
-            else:
-                NT = T = 1
-                R_src = F_c * K
-                B_c = min(B_user, R_src)
-            if self.bucket_capacity is not None:
-                Bd_c = min(self.bucket_capacity, B_c)
-            elif S == 1:
-                Bd_c = B_c
-            else:
-                # Near-uniform fingerprint split: 4x the expected share.
-                Bd_c = min(B_c, max(128, (4 * B_c + S - 1) // S))
-            return F_c, NT, T, R_src, B_c, Bd_c
-
         # Per-shard parent-log rows: every unique state a shard owns
         # (<= C) gets one entry; the append block is F rows (the
         # next-frontier width).
@@ -486,6 +543,29 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         E = dest_tile_width(W, track_paths) - 2
         EB = E - 1
         mesh = self.mesh
+
+        # The receive side's own ladder (see the module docstring): its
+        # top is the largest lossless per-destination cap, and the
+        # per-class route_ovf checks keep refusing a wave whose run
+        # exceeds its frontier class's cap.
+        f_params = self._f_class_params()
+        r_ladder = recv_ladder(max(p[5] for p in f_params))
+        # The routed payload lands in the fixed-shape ``route`` carry
+        # lane, rows [0, R_src) of the wave's frontier class written
+        # in routing order; R_route covers the largest class. Where a
+        # class fingerprints in chunks (the memory-lean mode: the
+        # [R_src, W] successor tensor would blow the flat budget) the
+        # lane holds (pair, slot, parent meta, key) rows instead and
+        # the receive class recomputes each tile's successors — one
+        # layout for every class, so the receive branches need not
+        # know the frontier class.
+        row_pad = -(-W // 128) * 512
+        recompute = use_sparse and any(
+            p[3] * row_pad > self.flat_budget_bytes for p in f_params
+        )
+        n_meta = 3 if track_paths else 1  # ebits (+ parent fp limbs)
+        R_route = max(p[3] for p in f_params)
+        route_lanes = 2 + n_meta + 2 if recompute else E + 2
 
         def bool_any(x):
             return lax.psum(x.astype(jnp.uint32), "shard") > 0
@@ -554,6 +634,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 vkeys=vkeys,
                 plog=jnp.zeros((4, L), jnp.uint32),
                 pl_n=jnp.zeros(1, jnp.uint32),
+                route=jnp.zeros((R_route, route_lanes), jnp.uint32),
+                recv_waves=jnp.zeros(len(r_ladder), jnp.uint32),
                 frontier=frontier,
                 fval=fval,
                 ebits=ebits,
@@ -604,7 +686,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
 
             ``shard_log`` (traced runs only) is the wave's
             ``(enabled_pairs, routed_rows, dest_fill_peak, dest_cap)``
-            per-shard scalars from the routing stage; this stage adds
+            per-shard scalars from the routing stage and the receive
+            class; this stage adds
             the quantities it owns (received rows, post-dedup new,
             the visited total) and returns the assembled
             ``swave: uint32[SHARD_LOG_LANES]`` row in the carry — 36
@@ -663,10 +746,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             nf_pos, w_lo, w_hi = compact_winners(
                 is_new, s_pos, s_lo, s_hi, NFs, impl=self.merge_impl
             )
-            if R_c < F:
-                nf_pos = jnp.concatenate(
-                    [nf_pos, jnp.full(F - R_c, _SENT, jnp.uint32)]
-                )
 
             if tier_mode:
                 # the commit phase (next dispatch) owns the per-shard
@@ -679,14 +758,31 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 )
             nf_valid = jnp.arange(F) < new_count
             f_overflow = f_overflow0 | bool_any(new_count > F)
-            nf_row = jnp.where(nf_valid, nf_pos - 1, jnp.uint32(0))
+            # At most NFs winners: the fetch gathers NFs rows and the
+            # resident frontier/ebits/parent-log take class-local
+            # blocks at offset 0. Rows past NFs keep stale values that
+            # fval masks, as every reader of the frontier does.
+            nf_ok = nf_valid[:NFs]
+            nf_row = jnp.where(nf_ok, nf_pos - 1, jnp.uint32(0))
             next_fe = recv[nf_row]
             # The winners come off the routed-tile row gather; one
             # seam transpose feeds the [W, F] resident frontier.
-            next_frontier = jnp.where(
-                nf_valid[:, None], next_fe[:, :W], jnp.uint32(0)
-            ).T
-            next_ebits = jnp.where(nf_valid, next_fe[:, EB], 0)
+            z2 = (jnp.uint32(0), jnp.uint32(0))
+            next_frontier = lax.dynamic_update_slice(
+                c["frontier"],
+                jnp.where(
+                    nf_ok[:, None], next_fe[:, :W], jnp.uint32(0)
+                ).T,
+                z2,
+            )
+            next_ebits = lax.dynamic_update_slice(
+                c["ebits"],
+                jnp.where(nf_ok, next_fe[:, EB], jnp.uint32(0)),
+                (jnp.uint32(0),),
+            )
+
+            def fe_lane(j):
+                return jnp.where(nf_ok, next_fe[:, j], jnp.uint32(0))
 
             # Visited append (sorted invariant): linear-merge the
             # sorted winner block into the shard's sorted prefix and
@@ -725,10 +821,11 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 plog_new = c["plog"]
                 pl_n = c["pl_n"]
                 pend_extra = dict(
-                    pend_par=jnp.stack([
-                        jnp.where(nf_valid, next_fe[:, W], 0),
-                        jnp.where(nf_valid, next_fe[:, W + 1], 0),
-                    ])
+                    pend_par=lax.dynamic_update_slice(
+                        c["pend_par"],
+                        jnp.stack([fe_lane(W), fe_lane(W + 1)]),
+                        z2,
+                    )
                 )
             elif track_paths:
                 # Parent AND child limbs (round 10): the sorted merge
@@ -738,19 +835,17 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 plog_new = lax.dynamic_update_slice(
                     c["plog"],
                     jnp.stack([
-                        jnp.where(nf_valid, next_fe[:, W], 0),
-                        jnp.where(nf_valid, next_fe[:, W + 1], 0),
-                        jnp.where(nf_valid, next_fe[:, E], 0),
-                        jnp.where(nf_valid, next_fe[:, E + 1], 0),
+                        fe_lane(W), fe_lane(W + 1),
+                        fe_lane(E), fe_lane(E + 1),
                     ]),
                     (jnp.uint32(0), c["pl_n"][0]),
                 )
-                # Clamp to the F rows the block write actually wrote
-                # (on an f_overflow wave new_count can exceed F; _run
-                # raises before reconstruction, but the live-count
-                # invariant should hold regardless).
+                # Clamp to the NFs rows the block write actually
+                # wrote (on an f_overflow wave new_count can exceed
+                # F; _run raises before reconstruction, but the
+                # live-count invariant should hold regardless).
                 pl_n = c["pl_n"] + jnp.minimum(
-                    new_count.astype(jnp.uint32), jnp.uint32(F)
+                    new_count.astype(jnp.uint32), jnp.uint32(NFs)
                 )
             else:
                 plog_new = c["plog"]
@@ -906,11 +1001,13 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 done=~cont,
             )
 
-        def make_wave(fc: int, v_class):
-            F_c, NT, T, R_src, B_c, Bd_c = class_params(fc)
-            R_c = S * Bd_c
+        def make_route(fc: int):
+            """The send side at frontier class ``fc``: expansion,
+            fingerprints, the routing sort and the routed payload,
+            written into the ``route`` lane."""
+            F_c, NT, T, R_src, B_c, Bd_c = f_params[fc]
 
-            def wave(c):
+            def route(c):
                 frontier_t = c["frontier"][:, :F_c]
                 fval_c = c["fval"][:F_c]
                 ebits_c = c["ebits"][:F_c]
@@ -987,9 +1084,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     # Memory-lean mode (mirrors the single-chip chunked
                     # path): when the [R_src, W] successor tensor would
                     # blow the flat budget, fingerprint pairs in chunks
-                    # and RECOMPUTE the routed tiles' successors inside
-                    # dest_tile (step_slot purity makes this exact).
-                    row_pad = -(-W // 128) * 512
+                    # and RECOMPUTE the routed tiles' successors in the
+                    # receive class (step_slot purity makes this exact).
                     chunked = (
                         R_src * row_pad > self.flat_budget_bytes
                     )
@@ -1058,7 +1154,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                         else:
                             has_succ = cnt > 0
                             n_cand = n_pairs
-                        cand_state = None  # recomputed per dest_tile
+                        cand_state = None  # the receive class recomputes
                     else:
                         (succ_t, k_lo, k_hi, pair_ok, _,
                          eov) = eval_pairs(pidx, live, pslot)
@@ -1152,18 +1248,14 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 route_ovf = jnp.any(counts > jnp.uint32(Bd_c))
                 c_overflow = c_overflow | bool_any(route_ovf)
 
-                # Build the send tiles from ONE routed payload gather +
-                # per-destination SLICES (PERF.md §gathers: TPU gathers
-                # cost ~12ns/row regardless of lane count, so the old
-                # per-destination payload/fp/ebits/key gathers — ~6×
-                # R_src rows per wave — collapse into a single
-                # [R_src, E+2] multi-lane gather; slices are free).
-                # Parent meta (ebits + parent fp) packs into the same
-                # payload: broadcast for dense (candidate // K is a
-                # K-fold repeat, no gather), one packed gather for
-                # sparse. Buffers are padded by one tile so a
-                # destination run ending at R_src slices without the
-                # dynamic_slice start-clamp silently shifting live rows.
+                # The routed payload in ONE gather (PERF.md §gathers:
+                # TPU gathers cost ~12ns/row regardless of lane count,
+                # so one [R_src, lanes] multi-lane gather replaces
+                # per-destination payload/fp/ebits/key gathers; the
+                # receive class's tiles are then slices). Parent meta
+                # (ebits + parent fp) packs into the same payload:
+                # broadcast for dense (candidate // K is a K-fold
+                # repeat, no gather), one packed gather for sparse.
                 fr_meta = jnp.stack(
                     [ex["ebits"]]
                     + ([ex["f_lo"], ex["f_hi"]] if track_paths else []),
@@ -1176,155 +1268,159 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     ).reshape(R_src, fr_meta.shape[1])
                 else:
                     pmeta = fr_meta[cand_par]
-                if cpu_backend:
-                    # XLA:CPU: per-destination separate gathers, same
-                    # math. The packed arrangement below pads a class
-                    # result inside the switch branch, which the
-                    # kernel lint (run on the CPU) refuses
-                    # (no-branch-pad-concat); kept until that pad
-                    # leaves the chip's program (ROADMAP D5).
-                    def dest_block(start):
-                        idx = jnp.clip(
-                            start + jnp.arange(Bd_c, dtype=jnp.uint32),
-                            0,
-                            jnp.uint32(R_src - 1),
-                        )
-                        srow = s_row[idx]
-                        if cand_par is None:
-                            par = srow // jnp.uint32(K)
-                        else:
-                            par = cand_par[srow]
-                        if cand_state is not None:
-                            st = cand_state[srow]
-                        else:
-                            st_t, _, _ = step_cols(
-                                pair_states(par), pslot[srow]
-                            )
-                            st = st_t.T
-                        return dest_tile_pack(
-                            jnp, st,
-                            ex["f_lo"][par] if track_paths else None,
-                            ex["f_hi"][par] if track_paths else None,
-                            ex["ebits"][par], s_lo[idx], s_hi[idx],
-                        )
-                elif cand_state is not None:
-                    cpay = dest_tile_pack(
+                if recompute:
+                    # successors are never materialized at [R_src, W]
+                    # here: the receive class recomputes its tiles'
+                    # successors from (pair, slot)
+                    spay = jnp.concatenate(
+                        [
+                            jnp.concatenate(
+                                [pidx[:, None], pslot[:, None], pmeta],
+                                axis=1,
+                            )[s_row],
+                            jnp.stack([s_lo, s_hi], axis=1),
+                        ],
+                        axis=1,
+                    )
+                else:
+                    spay = dest_tile_pack(
                         jnp, cand_state,
                         pmeta[:, 1:2] if track_paths else None,
                         pmeta[:, 2:3] if track_paths else None,
                         pmeta[:, 0:1], k_lo, k_hi,
-                    )
-                    spay = jnp.pad(
-                        cpay[s_row], ((0, Bd_c), (0, 0))
-                    )
-
-                    def dest_block(start):
-                        return lax.dynamic_slice(
-                            spay, (start, jnp.uint32(0)), (Bd_c, E + 2)
-                        )
-                else:
-                    # Chunked sparse: successors are never materialized
-                    # at [R_src, W]; recompute per destination from a
-                    # packed (pair, slot, meta, key) gather.
-                    mparts = [pidx[:, None], pslot[:, None], pmeta]
-                    smeta = jnp.pad(
-                        jnp.concatenate(mparts, axis=1)[s_row],
-                        ((0, Bd_c), (0, 0)),
-                    )
-                    skeys = jnp.pad(
-                        jnp.stack([s_lo, s_hi], axis=1),
-                        ((0, Bd_c), (0, 0)),
-                    )
-                    NM = 2 + fr_meta.shape[1]
-
-                    def dest_block(start):
-                        z = jnp.uint32(0)
-                        m = lax.dynamic_slice(
-                            smeta, (start, z), (Bd_c, NM)
-                        )
-                        kk = lax.dynamic_slice(
-                            skeys, (start, z), (Bd_c, 2)
-                        )
-                        par = m[:, 0] // jnp.uint32(EV)
-                        succ_d_t, _, _ = step_cols(
-                            pair_states(par), m[:, 1]
-                        )
-                        return dest_tile_pack(
-                            jnp, succ_d_t.T,
-                            m[:, 3:4] if track_paths else None,
-                            m[:, 4:5] if track_paths else None,
-                            m[:, 2:3], kk[:, 0:1], kk[:, 1:2],
-                        )
-
-                def dest_tile(d):
-                    start = starts[d]
-                    cnt_d = counts[d]
-                    live_d = jnp.arange(Bd_c, dtype=jnp.uint32) < cnt_d
-                    tile = dest_block(start)
-                    return jnp.where(
-                        live_d[:, None], tile, jnp.uint32(0)
-                    )
-
-                send = jnp.concatenate(
-                    [dest_tile(d) for d in range(S)], axis=0
-                )
+                    )[s_row]
                 cross = n_cand - counts[me]
                 g_cross = lax.psum(cross.astype(jnp.uint32), "shard")
                 sent = u64_add(
                     U64(c["sent_lo"], c["sent_hi"]),
                     U64(g_cross, jnp.uint32(0)),
                 )
-
-                recv = lax.all_to_all(
-                    send, "shard", split_axis=0, concat_axis=0,
-                    tiled=True,
+                out = dict(
+                    route=lax.dynamic_update_slice(
+                        c["route"], spay,
+                        (jnp.uint32(0), jnp.uint32(0)),
+                    ),
+                    starts=starts,
+                    counts=counts,
+                    n_cand=n_cand.astype(jnp.uint32),
+                    sent_lo=sent.lo,
+                    sent_hi=sent.hi,
+                    disc_found=disc_found,
+                    disc_lo=disc_lo,
+                    disc_hi=disc_hi,
+                    c_overflow=c_overflow,
+                    e_overflow=e_overflow,
                 )
-
-                shard_log = None
                 if trace_log:
                     # Routing-stage lanes of the per-shard log: the
                     # local enabled-pair popcount (the quantity the
                     # global log can't see; candidates on the dense
-                    # path, mirroring the single-chip convention),
-                    # rows routed off-shard, and the peak destination
-                    # run vs this class's lossless tile cap.
+                    # path, mirroring the single-chip convention) and
+                    # the rows routed off-shard.
+                    out.update(
+                        wv_pairs=(n_pairs if use_sparse
+                                  else n_cand).astype(jnp.uint32),
+                        cross=cross.astype(jnp.uint32),
+                    )
+                return out
+
+            return route
+
+        def make_recv(rc: int, v_class):
+            """The receive side at receive class ``rc``: per-destination
+            tiles of ``Bd_r`` rows sliced from the ``route`` lane, the
+            ``all_to_all``, and the owner-local merge at ``S * Bd_r``
+            rows."""
+            Bd_r = r_ladder[rc]
+
+            def recv_side(args):
+                c, rt = args
+                route = rt["route"]
+                starts, counts = rt["starts"], rt["counts"]
+                if recompute:
+                    pair_states = make_pair_states(
+                        c["frontier"], c["frontier"]
+                    )
+                pos = jnp.arange(Bd_r, dtype=jnp.uint32)
+
+                def dest_tile(d):
+                    # The slice start is clamped to keep the tile inside
+                    # the lane, so a run near its end lands ``skip``
+                    # rows into the tile; its rows keep their order,
+                    # and so the stable winner order of the merge.
+                    start = jnp.minimum(
+                        starts[d], jnp.uint32(R_route - Bd_r)
+                    )
+                    skip = starts[d] - start
+                    tile = lax.dynamic_slice(
+                        route, (start, jnp.uint32(0)),
+                        (Bd_r, route_lanes),
+                    )
+                    if recompute:
+                        succ_t, _, _ = step_cols(
+                            pair_states(tile[:, 0] // jnp.uint32(EV)),
+                            tile[:, 1],
+                        )
+                        m = 2 + n_meta
+                        tile = dest_tile_pack(
+                            jnp, succ_t.T,
+                            tile[:, 3:4] if track_paths else None,
+                            tile[:, 4:5] if track_paths else None,
+                            tile[:, 2:3], tile[:, m:m + 1],
+                            tile[:, m + 1:m + 2],
+                        )
+                    live = (pos >= skip) & (pos < skip + counts[d])
+                    return jnp.where(live[:, None], tile, jnp.uint32(0))
+
+                send = jnp.concatenate(
+                    [dest_tile(d) for d in range(S)], axis=0
+                )
+                recv = lax.all_to_all(
+                    send, "shard", split_axis=0, concat_axis=0,
+                    tiled=True,
+                )
+                shard_log = None
+                if trace_log:
+                    # The receive stage's lanes of the per-shard log:
+                    # the peak destination run and the tile it
+                    # travelled in (the receive class).
                     shard_log = (
-                        n_pairs if use_sparse else n_cand,
-                        cross,
-                        jnp.max(counts),
-                        jnp.uint32(Bd_c),
+                        rt["wv_pairs"], rt["cross"], jnp.max(counts),
+                        jnp.uint32(Bd_r),
                     )
                 return merge_stage(
-                    c, v_class, R_c, recv, n_cand, sent,
-                    (disc_found, disc_lo, disc_hi),
+                    c, v_class, S * Bd_r, recv, rt["n_cand"],
+                    U64(rt["sent_lo"], rt["sent_hi"]),
+                    (rt["disc_found"], rt["disc_lo"], rt["disc_hi"]),
                     (c["overflow"], c["f_overflow"],
-                     c_overflow, e_overflow),
+                     rt["c_overflow"], rt["e_overflow"]),
                     shard_log=shard_log,
                 )
 
-            return wave
+            return recv_side
 
         # Memory ledger (stateright_tpu/memplan.py): per-ladder-class
         # staging rows, PER SHARD (the shard_map body's view), from
-        # the same class_params the wave programs compile from. The
-        # chunked memory-lean gate mirrors make_wave's (R_src rows at
+        # the same _f_class_params the wave programs compile from. The
+        # chunked memory-lean gate mirrors make_route's (R_src rows at
         # the padded ~512 B/row cost vs the flat budget); chunked
         # classes land an ``engine_mode`` record like the single-chip
-        # engine's.
+        # engine's. The tiles are priced at the largest receive class
+        # a wave of the frontier class can take.
         from ..memplan import buffer_entry, plan_total
 
         from ..ops.bitmask import mask_words as _mask_words
 
-        _row_pad = -(-W // 128) * 512
         _classes = []
         _modes = []
         for fc in range(len(f_ladder)):
-            F_c, NT_c, _T_c, R_src, B_c, Bd_c = class_params(fc)
+            F_c, NT_c, _T_c, R_src, B_c, Bd_c = f_params[fc]
+            Bd_hi = r_ladder[recv_tile_class(Bd_c, r_ladder)]
             staging = [
                 buffer_entry("cand_keys", (2, R_src), "uint32"),
-                buffer_entry("send_tiles", (S * Bd_c, E + 2),
+                buffer_entry("send_tiles", (S * Bd_hi, E + 2),
                              "uint32"),
-                buffer_entry("recv_tiles", (S * Bd_c, E + 2),
+                buffer_entry("recv_tiles", (S * Bd_hi, E + 2),
                              "uint32"),
             ]
             chunked_c = False
@@ -1335,9 +1431,9 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 staging.insert(1, buffer_entry(
                     "pair_index", (3, R_src), "uint32"
                 ))
-                chunked_c = R_src * _row_pad > self.flat_budget_bytes
+                chunked_c = R_src * row_pad > self.flat_budget_bytes
                 if chunked_c:
-                    NC_c = -(-(R_src * _row_pad)
+                    NC_c = -(-(R_src * row_pad)
                              // self.flat_budget_bytes)
                     Bc_c = -(-R_src // NC_c)
                     staging.append(buffer_entry(
@@ -1346,7 +1442,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     _modes.append(dict(
                         engine=type(self).__name__, mode="chunked",
                         f_class=fc, buffer_rows=R_src, chunks=NC_c,
-                        chunk_rows=Bc_c, row_pad_bytes=_row_pad,
+                        chunk_rows=Bc_c, row_pad_bytes=row_pad,
                         flat_budget_bytes=self.flat_budget_bytes,
                     ))
                 else:
@@ -1367,10 +1463,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             ))
         from ..memplan import v_class_entries
 
-        _NFmax = min(F, max(c["buffer_rows"] for c in _classes))
         self._build_info = dict(
             classes=_classes,
-            v_classes=v_class_entries(v_ladder, _NFmax),
+            v_classes=v_class_entries(v_ladder, min(F, S * r_ladder[-1])),
+            recv_classes=r_ladder,
             engine_modes=_modes,
         )
 
@@ -1396,10 +1492,31 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 n_tot = lax.psum(c["n_loc"][0], "shard")
             ci = {k: v for k, v in c.items()
                   if k not in ("wlog", "slog", "pstash")}
-            c2 = lax.switch(
+            rt = lax.switch(
                 f_class,
-                [make_wave(fc, v_class) for fc in range(len(f_ladder))],
+                [make_route(fc) for fc in range(len(f_ladder))],
                 ci,
+            )
+            # The receive class, agreed like the others: the smallest
+            # rung holding every shard's largest destination run.
+            r_class = jnp.asarray(
+                recv_tile_class(
+                    lax.pmax(jnp.max(rt["counts"]), "shard"), r_ladder
+                ),
+                jnp.int32,
+            )
+            cr = {k: v for k, v in ci.items() if k != "route"}
+            c2 = lax.switch(
+                r_class,
+                [make_recv(rc, v_class) for rc in range(len(r_ladder))],
+                (cr, rt),
+            )
+            c2 = dict(
+                c2,
+                route=rt["route"],
+                recv_waves=c["recv_waves"] + (
+                    jnp.arange(len(r_ladder)) == r_class
+                ).astype(jnp.uint32),
             )
             if trace_log and tier_mode:
                 # the wave-log/shard-log rows can't be written yet —
@@ -1495,6 +1612,9 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             ]
             if trace_log:
                 parts.append(c["wlog"].reshape(-1))
+            # waves per receive class, smallest first: the last lanes
+            # (_consume_extra_stats)
+            parts.append(c["recv_waves"])
             stats = jnp.concatenate(parts)
             if trace_log:
                 # The per-shard mesh log returns as a SECOND stats
@@ -1519,6 +1639,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             vkeys=P(None, "shard"),
             plog=P(None, "shard"),
             pl_n=P_shard,
+            route=P("shard", None),
+            recv_waves=P(),
             frontier=P(None, "shard"),
             fval=P_shard,
             ebits=P_shard,

@@ -147,7 +147,7 @@ SHARD_LOG_FIELDS = (
     "routed_rows",     # rows this shard sent to OTHER shards (send side)
     "recv_rows",       # valid rows received after the all_to_all
     "dest_fill_peak",  # peak per-destination send-tile fill this wave
-    "dest_cap",        # the lossless per-destination tile cap (Bd_c)
+    "dest_cap",        # the wave's receive-tile class (rows, Bd_r)
     "new_states",      # post-dedup winners this shard appended
     "visited_total",   # this shard's visited count AFTER the wave
 )
@@ -1211,6 +1211,12 @@ def shard_balance(events: list[dict], run: int | None = None,
     # the hash engine's open addressing degrades from ~70% (probe
     # pressure — its own threshold and failure mode).
     visited_exact = bool(lane.get("visited_exact", True))
+    # The lossless per-destination cap a run is held to: the top of
+    # the sharded sort-merge engine's receive-tile classes (whose
+    # dest_cap lane is the wave's class, sized to its fill), else the
+    # logged tile cap itself.
+    recv_classes = lane.get("recv_classes")
+    lossless_cap = max(recv_classes) if recv_classes else None
 
     per_wave: list[dict] = []
     routed_total = recv_total = 0
@@ -1259,9 +1265,11 @@ def shard_balance(events: list[dict], run: int | None = None,
             if worst_cand is None or m["candidate_skew"] > \
                     worst_cand[0]:
                 worst_cand = (m["candidate_skew"], w)
-        if util is not None and (worst_fill is None
-                                 or util > worst_fill[0]):
-            worst_fill = (util, fill, cap, w)
+        head_cap = lossless_cap or cap
+        head = round(fill / head_cap, 4) if head_cap else None
+        if head is not None and (worst_fill is None
+                                 or head > worst_fill[0]):
+            worst_fill = (head, fill, head_cap, w)
         for r in rows:
             final_visited[r["shard"]] = r["visited_total"]
 

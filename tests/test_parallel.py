@@ -299,3 +299,159 @@ def test_sharded_sparse_chunked_mode_matches():
     assert sorted(ck.discoveries()) == sorted(host.discoveries())
     p = ck.discovery("value chosen")
     assert p is not None and len(p.actions()) >= 1
+
+
+# -- the receive-tile class (parallel/engine_sortmerge.py) -----------------
+
+#: the paxos-4 mesh cell's lossless per-destination cap, 2^19 rows
+_RECV_LADDER = [1 << 13, 1 << 15, 1 << 17, 1 << 19]
+
+
+@pytest.mark.parametrize(
+    "count, want",
+    [
+        (1 << 13, 0), (1 << 15, 1), (1 << 17, 2), (1 << 19, 3),
+        ((1 << 13) + 1, 1), ((1 << 15) + 1, 2), ((1 << 17) + 1, 3),
+        (0, 0),
+        ((1 << 19) + 1, "overflow"),
+    ],
+)
+def test_recv_tile_class(count, want):
+    """The receive class holds the wave's largest destination run: a
+    count equal to a rung takes that rung, one more takes the next,
+    nothing at all takes the floor. A run above the top rung takes the
+    top, and the routing stage's overflow check refuses the wave with
+    the existing candidate-overflow error."""
+    import jax.numpy as jnp
+
+    from stateright_tpu.parallel.engine_sortmerge import (
+        recv_ladder,
+        recv_tile_class,
+    )
+
+    assert recv_ladder(1 << 19) == _RECV_LADDER
+    if want == "overflow":
+        assert recv_tile_class(count, _RECV_LADDER) == 3
+        # the same past the top of a real engine's ladder: per-shard
+        # destination runs of 2pc rm=3 outgrow an 8-row cap
+        ck = (
+            TwoPhaseSys(rm_count=3)
+            .checker()
+            .spawn_tpu_sharded_sortmerge(
+                n_shards=2,
+                capacity=512,
+                frontier_capacity=128,
+                cand_capacity=1024,
+                bucket_capacity=8,
+                track_paths=False,
+            )
+        )
+        with pytest.raises(RuntimeError, match="candidate/bucket overflow"):
+            ck.join()
+        assert ck._build_info["recv_classes"] == [8]
+        return
+    assert recv_tile_class(count, _RECV_LADDER) == want
+    assert int(recv_tile_class(jnp.uint32(count), _RECV_LADDER)) == want
+
+
+@pytest.fixture(scope="module")
+def host_2pc4():
+    return TwoPhaseSys(rm_count=4).checker().spawn_bfs().join()
+
+
+@pytest.mark.parametrize(
+    "shards, track_paths, tier_hot_rows",
+    [
+        (2, True, None),
+        (4, True, None),
+        (4, False, None),
+        (2, True, 64),
+        (4, False, 64),
+    ],
+)
+def test_sharded_recv_classes_match_host(shards, track_paths,
+                                         tier_hot_rows, host_2pc4):
+    """Waves that run below the lossless receive cap explore exactly
+    the host's space, with paths and in tiered mode alike, and every
+    wave lands in one receive class."""
+    import jax
+
+    if len(jax.devices()) < shards:
+        pytest.skip(f"need {shards} devices")
+    kw = {} if tier_hot_rows is None else dict(tier_hot_rows=tier_hot_rows)
+    c = (
+        TwoPhaseSys(rm_count=4)
+        .checker()
+        .spawn_tpu_sharded_sortmerge(
+            n_shards=shards,
+            capacity=1 << 11,
+            frontier_capacity=256,
+            cand_capacity=2048,
+            waves_per_sync=8,
+            track_paths=track_paths,
+            **kw,
+        )
+        .join()
+    )
+    assert c.unique_state_count() == host_2pc4.unique_state_count()
+    assert c.discovered_property_names() == set(host_2pc4.discoveries())
+    if track_paths:
+        for name, path in c.discoveries().items():
+            prop = c.model.property_by_name(name)
+            assert prop.condition(c.model, path.last_state()), name
+    waves = c.metrics["recv_class_waves"]
+    assert len(waves) == len(c._build_info["recv_classes"])
+    if tier_hot_rows is None:
+        assert sum(waves) == c.metrics["waves"]
+    else:
+        assert c.metrics["tier_spills"] >= 1
+
+
+def test_sharded_recv_class_waves_2pc_rm5():
+    """2pc rm=5 over four shards: wave 1 (one initial state) travels in
+    the floor class, the run uses more than one class, and the traced
+    and untraced runs report the same per-class wave counts — the
+    traced one in ``run_end`` and in each wave's ``dest_cap``."""
+    import jax
+
+    from stateright_tpu.telemetry import RunTracer
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the virtual CPU mesh")
+
+    def spawn():
+        return (
+            TwoPhaseSys(rm_count=5)
+            .checker()
+            .spawn_tpu_sharded_sortmerge(
+                n_shards=4,
+                capacity=1 << 12,
+                frontier_capacity=1 << 10,
+                cand_capacity=1 << 12,
+                waves_per_sync=32,
+                track_paths=False,
+            )
+        )
+
+    c0 = spawn().join()
+    tr = RunTracer()
+    with tr.activate():
+        c1 = spawn().join()
+    assert c0.unique_state_count() == c1.unique_state_count() == 8832
+    ladder = c1._build_info["recv_classes"]
+    waves = c1.metrics["recv_class_waves"]
+    assert waves == c0.metrics["recv_class_waves"]
+    assert sum(waves) == c1.metrics["waves"]
+    assert sum(1 for n in waves if n) > 1
+    end = next(e for e in tr.events if e["ev"] == "run_end")
+    assert end["recv_class_waves"] == waves
+    caps = {}
+    for e in tr.events:
+        if e["ev"] == "shard_wave":
+            caps.setdefault(e["wave"], set()).add(e["dest_cap"])
+            assert e["dest_fill_peak"] <= e["dest_cap"]
+    assert caps[min(caps)] == {ladder[0]}
+    assert [
+        sum(1 for cap in caps.values() if cap == {rung})
+        for rung in ladder
+    ] == waves

@@ -604,19 +604,23 @@ def test_trace_diff_cli_exit_codes(tmp_path):
 def _synthetic_shard_trace(tmp_path, name, per_shard_new, *,
                            permute=False, dest_cap=512, fill=8,
                            capacity=1024, visited0=1,
-                           visited_exact=True):
+                           visited_exact=True, recv_classes=None):
     """A schema-valid sharded trace: ``per_shard_new[wave][shard]`` is
     the post-dedup new count; global rows are the shard sums, so the
     two log levels reconcile the way a real engine's do. ``permute``
     reverses the shard numbering (the relabeling the multiset
     alignment must tolerate); ``fill``/``dest_cap`` set the dest-tile
-    lanes; ``visited0`` seeds each shard's visited counter."""
+    lanes; ``visited0`` seeds each shard's visited counter;
+    ``recv_classes`` is the sharded sort-merge engine's receive-tile
+    ladder in the lane config."""
     tr = RunTracer()
     with tr.activate():
         S = len(per_shard_new[0])
+        extra = {} if recv_classes is None else dict(
+            recv_classes=recv_classes)
         tr.begin_run(lane=dict(engine="T", n_shards=S,
                                capacity=capacity, dest_tile_lanes=10,
-                               visited_exact=visited_exact))
+                               visited_exact=visited_exact, **extra))
         visited = [visited0] * S
         prev_front = [1] * S
         u = S * visited0
@@ -734,6 +738,21 @@ def test_shard_balance_headroom_warnings(tmp_path):
     assert bal["occupancy_max"] is not None
     assert any("visited array" in w and "overflows exactly" in w
                for w in bal["warnings"])
+
+    # the sharded sort-merge engine logs each wave's receive class as
+    # dest_cap, sized to the wave: a full class is no risk, the
+    # ladder's top (the lossless cap) is what the run is held to
+    roomy = load_trace(
+        _synthetic_shard_trace(
+            tmp_path, "roomy.jsonl", BALANCED,
+            dest_cap=100, fill=95, recv_classes=[100, 400],
+        )
+    )
+    bal_r = shard_balance(roomy)
+    assert bal_r["per_wave"][0]["dest_util"] == 0.95
+    assert bal_r["dest_fill_worst"]["util"] == 0.2375
+    assert bal_r["dest_fill_worst"]["cap"] == 400
+    assert not any("dest tile" in w for w in bal_r["warnings"])
 
     # a HASH-engine lane (visited_exact=False) watches probe
     # pressure instead: warns earlier (0.7 bar) with the

@@ -13,13 +13,12 @@ machine-checked on CPU before any chip time is spent.
 
 Fixtures (``comms_fixture_params`` / :func:`trace_comms_fixture`):
 
-* BOTH sharded engines' full wave bodies — the sort-merge engine
-  (parallel/engine_sortmerge.py, routing SORT seam) and the hash
-  engine (parallel/engine.py, owner-position SCATTER seam) — each in
-  its traced (per-shard ``slog`` mesh log compiled in) and untraced
-  form, on a real multi-shard mesh (S=2: the tile math, Bd cap and
-  all_to_all shapes are shard-count-dependent, unlike the kernel
-  lint's 1-device axis-plumbing fixture);
+* the mesh engine's full wave body (parallel/engine_sortmerge.py,
+  routing SORT seam) in its traced (per-shard ``slog`` mesh log
+  compiled in) and untraced form, on a real multi-shard mesh (S=2:
+  the tile math, Bd cap and all_to_all shapes are
+  shard-count-dependent, unlike the kernel lint's 1-device
+  axis-plumbing fixture), and its TIERED chunk program;
 * the RECONCILIATION fixture: the sort-merge body at the exact
   ``dryrun_multichip`` 2pc rm=5 / S=8 / traced config TRACE_r16 was
   recorded under, so the static ``all_to_all_row_bytes`` in the COMM
@@ -95,52 +94,44 @@ def _mesh(n_shards: int):
 
 
 def comms_fixture_params(reconciliation: bool = True) -> list:
-    """The wave-body fixture matrix: (engine, traced) x the default
-    2pc-rm3/S=2 config, plus the rm=5/S=8 reconciliation config,
-    plus the TIERED sort-merge chunk program (round 16,
-    stateright_tpu/tier.py — the deferred-commit wave + commit path
-    holds to the same five comms rules: the commit's v-ladder switch
-    must stay collective-free, its termination psums scalar-only)."""
-    out = []
-    for engine in ("sortmerge", "hash"):
-        for traced in (False, True):
-            out.append(dict(engine=engine, traced=traced))
-    out.append(dict(engine="sortmerge", traced=True, tiered=True))
+    """The wave-body fixture matrix: untraced and traced at the
+    default 2pc-rm3/S=2 config, plus the TIERED chunk program (round
+    16, stateright_tpu/tier.py — the deferred-commit wave + commit
+    path holds to the same five comms rules: the commit's v-ladder
+    switch must stay collective-free, its termination psums
+    scalar-only), plus the rm=5/S=8 reconciliation config."""
+    out = [dict(traced=False), dict(traced=True),
+           dict(traced=True, tiered=True)]
     if reconciliation:
-        out.append(dict(
-            engine="sortmerge", traced=True,
-            config=RECONCILIATION_CONFIG,
-        ))
+        out.append(dict(traced=True, config=RECONCILIATION_CONFIG))
     return out
 
 
-def comms_fixture_name(engine: str, traced: bool,
-                       config: Optional[dict] = None,
+def comms_fixture_name(traced: bool, config: Optional[dict] = None,
                        tiered: bool = False) -> str:
     cfg = config or {}
     rm = cfg.get("rm_count", 3)
     s = cfg.get("n_shards", COMMS_WAVE_SHARDS)
     return (
-        f"comms(2pc-rm{rm},{engine},S{s}"
+        f"comms(2pc-rm{rm},sortmerge,S{s}"
         + (",tiered" if tiered else "")
         + (",traced" if traced else "")
         + ")"
     )
 
 
-def trace_comms_fixture(engine: str = "sortmerge",
-                        traced: bool = False,
+def trace_comms_fixture(traced: bool = False,
                         config: Optional[dict] = None,
                         tiered: bool = False) -> dict:
-    """Build one sharded engine on a real S-shard mesh and trace its
-    full wave body (the ``_wave_body_sm`` hook both engines expose)
-    on the seed program's carry shapes — abstract (``eval_shape``), no
-    buffers. Returns the fixture dict the driver and the --hlo pass
-    share: ``name``, ``closed`` (the jaxpr), ``fn`` + ``carry`` (the
-    compilable callable for --hlo), ``seam`` (the engine's routing
-    idiom the no-unsorted-all-to-all rule requires), and ``lane`` (the
-    engine's telemetry lane config — ``dest_tile_lanes`` is the
-    runtime side of the row-bytes reconciliation)."""
+    """Build the mesh engine on a real S-shard mesh and trace its full
+    wave body (the ``_wave_body_sm`` hook) on the seed program's carry
+    shapes — abstract (``eval_shape``), no buffers. Returns the
+    fixture dict the driver and the --hlo pass share: ``name``,
+    ``closed`` (the jaxpr), ``fn`` + ``carry`` (the compilable
+    callable for --hlo), ``seam`` (the routing idiom the
+    no-unsorted-all-to-all rule requires), and ``lane`` (the engine's
+    telemetry lane config — ``dest_tile_lanes`` is the runtime side
+    of the row-bytes reconciliation)."""
     import jax
     import jax.numpy as jnp
 
@@ -158,20 +149,12 @@ def trace_comms_fixture(engine: str = "sortmerge",
     )
     if config:
         cfg.update(config)
-    name = comms_fixture_name(engine, traced, cfg, tiered)
+    name = comms_fixture_name(traced, cfg, tiered)
     rm = cfg.pop("rm_count")
     mesh = _mesh(cfg.pop("n_shards"))
-    builder = TwoPhaseSys(rm_count=rm).checker()
-    if engine == "sortmerge":
-        checker = builder.spawn_tpu_sharded_sortmerge(
-            mesh=mesh, f_min=64, v_min=256, merge_impl="xla", **cfg
-        )
-        seam = "sort"
-    elif engine == "hash":
-        checker = builder.spawn_tpu_sharded(mesh=mesh, **cfg)
-        seam = "scatter"
-    else:
-        raise ValueError(f"unknown comms engine {engine!r}")
+    checker = TwoPhaseSys(rm_count=rm).checker().spawn_tpu_sharded_sortmerge(
+        mesh=mesh, f_min=64, v_min=256, merge_impl="xla", **cfg
+    )
     if traced:
         # a truthy tracer stand-in flips _wave_log_enabled exactly as
         # a real RunTracer would — the per-shard slog path compiles in
@@ -218,7 +201,7 @@ def trace_comms_fixture(engine: str = "sortmerge",
             closed=jax.make_jaxpr(fn)(carry),
             fn=fn,
             carry=carry,
-            seam=seam,
+            seam="sort",
             lane=checker._lane_config(),
             n_shards=int(mesh.devices.size),
         )
@@ -228,7 +211,7 @@ def trace_comms_fixture(engine: str = "sortmerge",
         closed=jax.make_jaxpr(fn)(carry_shapes),
         fn=fn,
         carry=carry_shapes,
-        seam=seam,
+        seam="sort",
         lane=checker._lane_config(),
         n_shards=int(mesh.devices.size),
     )

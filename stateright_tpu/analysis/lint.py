@@ -99,7 +99,7 @@ def trace_encoding_paths(enc, n: int = LINT_N) -> dict:
 
 def _shard_map_1dev(fn, in_specs):
     """Wrap ``fn`` in ``shard_map`` over a 1-device mesh with
-    ``axis_name="shard"`` — the sharded engines' axis plumbing, which
+    ``axis_name="shard"`` — the mesh engine's axis plumbing, which
     is what a sharded trace pins; the varying-ness checker is off."""
     import jax
     import numpy as np

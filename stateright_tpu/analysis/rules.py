@@ -105,10 +105,9 @@ class TraceCtx:
     #: single-chip contract paths, which trace no axis context
     check_comms: bool = False
     #: routing seam the no-unsorted-all-to-all rule requires every
-    #: all_to_all operand to derive from: "sort" (the sort-merge
-    #: engine's (owner, fp) routing sort), "scatter" (the hash
-    #: engine's owner-position tile build), or None (rule off — paths
-    #: with no shuffle)
+    #: all_to_all operand to derive from: "sort" (the mesh engine's
+    #: (owner, fp) routing sort), or None (rule off — paths with no
+    #: shuffle)
     routing_seam: Optional[str] = None
 
 
@@ -491,7 +490,7 @@ def _carry_copy_bytes(ctx: TraceCtx, sites: list) -> Iterable[Finding]:
 # paths (analysis/comms.py traces them; ISSUE: a misplaced collective
 # only surfaces as a deadlock or a silent traffic blow-up ON CHIP,
 # where debugging is most expensive). Each rule pins one documented
-# invariant of parallel/engine_sortmerge.py / parallel/engine.py:
+# invariant of parallel/engine_sortmerge.py:
 #
 # * ``no-collective-in-switch`` — a collective under a cond/switch
 #   whose index is SHARD-VARYING deadlocks: collectives are
@@ -499,8 +498,8 @@ def _carry_copy_bytes(ctx: TraceCtx, sites: list) -> Iterable[Finding]:
 #   class switches are legal exactly because their indices are
 #   pmax-agreed (walker.shard_varying_vars proves it);
 # * ``no-unsorted-all-to-all`` — every all_to_all operand must be
-#   data-dependent on the routing seam (the (owner, fp) sort / the
-#   owner-position scatter), or the shuffle ships unrouted candidates;
+#   data-dependent on the routing seam (the (owner, fp) sort), or the
+#   shuffle ships unrouted candidates;
 # * ``scalar-only-reductions`` — psum/pmax/pmin operands stay rank-0/
 #   tiny; a reduction over a resident buffer is an accidental
 #   replication (S x the buffer per wave in all-reduce bandwidth);
@@ -568,12 +567,7 @@ def _no_collective_in_switch(ctx: TraceCtx, sites) -> Iterable[Finding]:
 def _no_unsorted_all_to_all(ctx: TraceCtx, sites) -> Iterable[Finding]:
     if not ctx.check_comms or ctx.routing_seam is None:
         return
-    seam = _walk_of(sites).seam_derived(ctx.routing_seam)
-    seam_desc = (
-        "the (owner, fp) routing sort"
-        if ctx.routing_seam == "sort"
-        else "the owner-position tile scatter"
-    )
+    seam = _walk_of(sites).seam_derived()
     for site in sites:
         if site.primitive != "all_to_all":
             continue
@@ -589,9 +583,9 @@ def _no_unsorted_all_to_all(ctx: TraceCtx, sites) -> Iterable[Finding]:
                 path=ctx.path,
                 message=(
                     "all_to_all operand is not data-dependent on "
-                    f"{seam_desc} — the shuffle ships unrouted "
-                    "candidates, so rows land on shards that do not "
-                    "own their fingerprints and the owner-local "
+                    "the (owner, fp) routing sort — the shuffle ships "
+                    "unrouted candidates, so rows land on shards that "
+                    "do not own their fingerprints and the owner-local "
                     "dedup contract breaks silently "
                     "(engine_sortmerge.py wave step 2-3)"
                 ),
@@ -818,8 +812,8 @@ COMMS_RULES: tuple = (
     Rule(
         name="no-unsorted-all-to-all",
         description=(
-            "every all_to_all operand derives from the routing seam "
-            "(owner-sort / owner-scatter), never raw candidates"
+            "every all_to_all operand derives from the (owner, fp) "
+            "routing sort, never raw candidates"
         ),
         run=_no_unsorted_all_to_all,
     ),

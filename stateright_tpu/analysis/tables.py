@@ -198,17 +198,12 @@ ALL_GATHER_ALLOWANCES: dict = {}
 #:   never psum-collapsed (its contract), so tracing adds exactly ONE
 #:   scalar psum (the global wave row's n_tot back-fill, 4 B); the
 #:   shared budget pins that zero-traffic claim;
-#: * hash engine: 57,424 B untraced / 57,428 traced (same all_to_all
-#:   tile, no class ladder — one fixed-shape wave, 12-13 scalar
-#:   psums);
 #: * the reconciliation fixture (2pc rm=5 at the TRACE_r16 dryrun
 #:   config, S=8): 229,472 B — all_to_all [8*1024, 7] = 229,376 B +
 #:   scalar psums.
 COMMS_BYTE_BUDGETS = {
     "comms(2pc-rm3,sortmerge,S2)": 75_000,
     "comms(2pc-rm3,sortmerge,S2,traced)": 75_000,
-    "comms(2pc-rm3,hash,S2)": 75_000,
-    "comms(2pc-rm3,hash,S2,traced)": 75_000,
     "comms(2pc-rm5,sortmerge,S8,traced)": 300_000,
     # the TIERED chunk program (round 16, stateright_tpu/tier.py):
     # the same wave body plus the commit phase's scalar psums/pmax —

@@ -280,25 +280,18 @@ def shard_varying_vars(closed) -> set:
     )
 
 
-def seam_derived_vars(closed, kind: str) -> set:
-    """ids of vars data-dependent on the routing seam: ``kind="sort"``
-    marks forward from multi-key ``sort`` eqns (the sharded sort-merge
-    engine's (owner, fp) routing sort — ``num_keys >= 2`` excludes
-    incidental single-key value sorts), ``kind="scatter"`` from
-    scatter eqns (the hash engine's owner-position tile build). An
-    ``all_to_all`` operand outside this set never went through the
-    routing stage."""
-    if kind == "sort":
-        def seeds(eqn):
-            return (
-                eqn.primitive.name == "sort"
-                and eqn.params.get("num_keys", 1) >= 2
-            )
-    elif kind == "scatter":
-        def seeds(eqn):
-            return eqn.primitive.name.startswith("scatter")
-    else:
-        raise ValueError(f"unknown routing seam kind {kind!r}")
+def seam_derived_vars(closed) -> set:
+    """ids of vars data-dependent on the routing seam: marks forward
+    from multi-key ``sort`` eqns (the mesh engine's (owner, fp)
+    routing sort — ``num_keys >= 2`` excludes incidental single-key
+    value sorts). An ``all_to_all`` operand outside this set never
+    went through the routing stage."""
+    def seeds(eqn):
+        return (
+            eqn.primitive.name == "sort"
+            and eqn.params.get("num_keys", 1) >= 2
+        )
+
     return _fixpoint(closed, seeds=seeds)
 
 
@@ -318,11 +311,10 @@ class SiteWalk(list):
             self._marks["varying"] = shard_varying_vars(self.closed)
         return self._marks["varying"]
 
-    def seam_derived(self, kind: str) -> set:
-        key = f"seam:{kind}"
-        if key not in self._marks:
-            self._marks[key] = seam_derived_vars(self.closed, kind)
-        return self._marks[key]
+    def seam_derived(self) -> set:
+        if "seam" not in self._marks:
+            self._marks["seam"] = seam_derived_vars(self.closed)
+        return self._marks["seam"]
 
 
 def source_of(eqn) -> str:

@@ -139,17 +139,6 @@ class CheckerBuilder:
 
         return SortMergeTpuBfsChecker(self, **kwargs)
 
-    def spawn_tpu_sharded(self, **kwargs) -> "Checker":
-        """Spawn the multi-chip wave engine: the frontier and visited
-        set sharded over a ``jax.sharding.Mesh``, with per-wave
-        all-to-all frontier shuffles replacing the reference's
-        work-stealing job market (src/job_market.rs). Owner-local
-        dedup uses the hash table; prefer
-        :meth:`spawn_tpu_sharded_sortmerge` on real TPU hardware."""
-        from .parallel import ShardedTpuBfsChecker
-
-        return ShardedTpuBfsChecker(self, **kwargs)
-
     def spawn_tpu_simulation(self, **kwargs) -> "Checker":
         """Spawn the device simulation checker: N parallel random walks
         under vmap, advancing in lockstep inside one jitted loop — the
@@ -170,12 +159,14 @@ class CheckerBuilder:
         return HybridChecker(self, **kwargs)
 
     def spawn_tpu_sharded_sortmerge(self, **kwargs) -> "Checker":
-        """Spawn the multi-chip SORT-MERGE wave engine: the all-to-all
-        routing of spawn_tpu_sharded with owner-local dedup on the
-        sorted-array fast path the repo benchmarks (PERF.md) — route
-        and compact via one (owner, key) sort, merge via stable sorts,
-        parent forest as an append-only log. No scatters in the hot
-        loop (see parallel/engine_sortmerge.py)."""
+        """Spawn the multi-chip wave engine: the frontier and visited
+        set sharded over a ``jax.sharding.Mesh``, every wave's
+        candidates routed to the shard owning their fingerprint by one
+        ``all_to_all`` (replacing the reference's work-stealing job
+        market, src/job_market.rs), owner-local dedup by the
+        single-chip sort-merge engine's sorted visited array, parent
+        forest as an append-only log. No scatters in the hot loop (see
+        parallel/engine_sortmerge.py)."""
         from .parallel import ShardedSortMergeTpuBfsChecker
 
         return ShardedSortMergeTpuBfsChecker(self, **kwargs)

@@ -637,7 +637,7 @@ class TpuBfsChecker(Checker):
         self._resume = None
         self._resume_path = None
         self._last_snapshot = None
-        #: sharded engines record their carry PartitionSpecs here at
+        #: the mesh engine records its carry PartitionSpecs here at
         #: program build (rides the program cache) so a resume can
         #: place snapshot buffers with the exact mesh sharding.
         self._carry_pspecs = None
@@ -1054,16 +1054,15 @@ class TpuBfsChecker(Checker):
     def _fault_shards(self):
         """The live shard-id set the fault-injection hook filters
         persistent ``shard_fault`` faults against (None = single-chip
-        / unfiltered). The sharded engines set ``_shard_ids`` at mesh
+        / unfiltered). The mesh engine sets ``_shard_ids`` at mesh
         construction; a degrade removes the dropped shard, which is
         exactly what makes the injected dead chip stop firing."""
         return getattr(self, "_shard_ids", None)
 
     def _can_degrade_shards(self) -> bool:
         """Whether the supervisor may drop a shard from this run: a
-        mesh engine with more than one shard left. Both families
-        qualify — the sort-merge re-shard and the sharded-hash
-        re-insertion route both carry a snapshot to the new count."""
+        mesh engine with more than one shard left (the sort-merge
+        re-shard carries a snapshot to the new count)."""
         return (getattr(self, "mesh", None) is not None
                 and int(getattr(self, "n_shards", 1)) > 1)
 
@@ -1453,7 +1452,7 @@ class TpuBfsChecker(Checker):
                 if getattr(self, "mesh", None) is not None:
                     faultinject.fire("collective_seam", chunk_no,
                                      shards=self._fault_shards())
-                # Sharded engines return a third output when traced:
+                # The mesh engine returns a third output when traced:
                 # the per-shard mesh wave log
                 # (telemetry.SHARD_LOG_FIELDS), sharded across
                 # devices — it rides the same dispatch and the same
@@ -2070,7 +2069,8 @@ class TpuBfsChecker(Checker):
 
     def _consume_extra_stats(self, extra: np.ndarray) -> None:
         """Hook for engine variants that append metric lanes after the
-        per-property discovery lanes (see parallel/engine.py)."""
+        per-property discovery lanes (the mesh engine,
+        parallel/engine_sortmerge.py)."""
 
     # -- fused multi-session hooks (stateright_tpu/batch.py) ---------------
     #
@@ -2130,7 +2130,7 @@ class TpuBfsChecker(Checker):
         unpacked from its device-axis concatenation to
         ``[n_shards, waves_per_sync, telemetry.SHARD_LOG_LANES]``.
         ``shard_log`` is None on single-chip engines and untraced runs
-        (only the sharded engines return a third chunk output, so
+        (only the mesh engine returns a third chunk output, so
         ``n_shards`` is always defined when this reshapes)."""
         if shard_log is None:
             return None

@@ -1595,7 +1595,7 @@ class SortMergeTpuBfsChecker(TpuBfsChecker):
     # -- telemetry (stateright_tpu/telemetry.py) ---------------------------
     #
     # _wave_log_enabled is inherited from TpuBfsChecker (one home for
-    # the tracer→program gate; the sharded engines key their per-shard
+    # the tracer→program gate; the mesh engine keys its per-shard
     # mesh log on the same flag).
 
     def _wave_log_rows(self, s: np.ndarray, n_props: int):

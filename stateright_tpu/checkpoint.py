@@ -31,8 +31,9 @@ bit rot) fails the zip/CRC checks and raises
   leaves (the wave/shard logs) synthesized to match the resuming
   run's tracer state, sharded leaves placed with the engine's own
   ``PartitionSpec``\\ s;
-* DIFFERENT shard count / capacity (the sort-merge family) — elastic
-  **re-shard**: per-shard visited prefixes, frontier blocks, and
+* DIFFERENT shard count / capacity — elastic **re-shard**, on the
+  sort-merge family (the single-chip sort-merge engine and the mesh
+  engine): per-shard visited prefixes, frontier blocks, and
   parent-log entries are concatenated and re-routed host-side through
   the exact (owner, fp) ordering the mesh wave's routing sort uses
   (owner = ``fp_lo % S``, keys ordered ``(hi, lo)`` — the
@@ -40,9 +41,8 @@ bit rot) fails the zip/CRC checks and raises
   ``np.lexsort``), then re-uploaded at the new layout. Shard count
   becomes a resume-time choice, not a run-time constant; single-chip
   ⇄ sharded conversions ride the same path (single-chip is the
-  S=1 layout). The hash-table family resumes same-config only
-  (re-inserting an open-addressed table is a different primitive) and
-  refuses loudly otherwise.
+  S=1 layout). The hash family (the single-chip hash-table engine)
+  resumes same-config only and refuses any re-layout loudly.
 
 Staleness is refused, never guessed around: a manifest whose encoding
 fingerprint disagrees with the target checker, or whose git SHA
@@ -157,7 +157,7 @@ class SnapshotStaleError(SnapshotError):
 class SnapshotIncompatibleError(SnapshotError):
     """The snapshot can't be restored into this engine configuration
     (family mismatch, track_paths flip, a target capacity too small
-    for the carried state, or a hash-family re-shard)."""
+    for the carried state, or a re-layout of a hash-family snapshot)."""
 
 
 # -- identity -------------------------------------------------------------
@@ -529,15 +529,6 @@ def resume_from(checker, path: str, *,
             buffers = reshard_sortmerge(
                 manifest, buffers, checker, visited_counts=hot_src
             )
-        elif (family == "hash"
-                and manifest.get("kind") == "sharded"
-                and _engine_kind(checker) == "sharded"):
-            # sharded-hash -> sharded-hash: the per-shard tables
-            # rebuild host-side by re-INSERTION of the snapshot's key
-            # set through the same (owner, fp) route the sort-merge
-            # re-shard uses (the degrade path needs this so the hash
-            # family can drop a shard too).
-            buffers = reshard_hash(manifest, buffers, checker)
         else:
             raise SnapshotIncompatibleError(
                 f"{path}: shard/capacity re-layout (snapshot "
@@ -546,12 +537,11 @@ def resume_from(checker, path: str, *,
                 f"C={manifest.get('capacity')}, target "
                 f"S={getattr(checker, 'n_shards', 1)} "
                 f"kind={_engine_kind(checker)} "
-                f"C={checker.capacity}) is supported on the "
-                "sort-merge family (all directions) and on "
-                "sharded-hash -> sharded-hash only — the hash "
-                "family's single-chip ⇄ sharded conversions are "
-                "not implemented; resume on the original kind, or "
-                "use the sort-merge family for fully elastic layouts"
+                f"C={checker.capacity}) of a hash-family snapshot — "
+                "the hash family resumes on its original "
+                "configuration only; the sort-merge family "
+                "(spawn_tpu_sortmerge, spawn_tpu_sharded_sortmerge) "
+                "re-shards in every direction"
             )
     if tier_m:
         buffers = _route_tier_target(
@@ -1013,132 +1003,6 @@ def reshard_sortmerge(manifest: dict, buffers: dict,
     return out
 
 
-def reshard_hash(manifest: dict, buffers: dict, checker) -> dict:
-    """The hash-family elastic re-shard (sharded -> sharded only):
-    rebuild the per-shard open-addressed tables host-side by
-    re-INSERTING the snapshot's occupied key set through the same
-    (owner, fp) route the mesh wave uses — owner = ``fp_lo % S_new``,
-    insertion via the numpy path of :func:`ops.hashset.insert`, which
-    retraces the exact triangular probe sequence the device insert
-    compiled, so the rebuilt tables are ones the device could have
-    built itself. Parent-forest entries (slot-indexed side tables)
-    move with their keys to the new slots; frontier rows re-route by
-    their fingerprints. Refuses loudly BEFORE device work when the
-    target tables can't absorb the keys (probe exhaustion at the
-    target capacity) or a shard's frontier share overflows."""
-    from .ops.fingerprint import fingerprint_u32v
-    from .ops.hashset import DeviceHashSet, insert
-
-    W = int(manifest["width"])
-    track_paths = bool(manifest["track_paths"])
-    S_a = int(manifest.get("n_shards", 1))
-    C_a = int(manifest["capacity"])
-    F_a = int(manifest["frontier_capacity"])
-    S_b = int(getattr(checker, "n_shards", 1))
-    C_b = int(checker.capacity)
-    F_b = int(checker.frontier_capacity)
-
-    t_lo = buffers["t_lo"].reshape(S_a * C_a)
-    t_hi = buffers["t_hi"].reshape(S_a * C_a)
-    occupied = (t_lo != 0) | (t_hi != 0)
-    keys_lo = t_lo[occupied]
-    keys_hi = t_hi[occupied]
-    if track_paths:
-        par_lo = buffers["p_lo_t"].reshape(S_a * C_a)[occupied]
-        par_hi = buffers["p_hi_t"].reshape(S_a * C_a)[occupied]
-
-    key_owner = (keys_lo % np.uint32(max(S_b, 1))).astype(np.int64)
-    t_lo_t = np.zeros(S_b * C_b, np.uint32)
-    t_hi_t = np.zeros(S_b * C_b, np.uint32)
-    p_lo_t = np.zeros(S_b * C_b if track_paths else 0, np.uint32)
-    p_hi_t = np.zeros(S_b * C_b if track_paths else 0, np.uint32)
-    for d in range(S_b):
-        sel = key_owner == d
-        kl, kh = keys_lo[sel], keys_hi[sel]
-        if kl.size > C_b:
-            raise SnapshotIncompatibleError(
-                f"hash re-shard: shard {d} of {S_b} would own "
-                f"{kl.size:,} visited keys but per-shard capacity is "
-                f"{C_b:,} — raise the target capacity"
-            )
-        table = DeviceHashSet.empty(C_b, np)
-        table, _, ovf, slots = insert(
-            table, kl, kh, np.ones(kl.size, bool), np,
-            rounds=int(checker.probe_rounds),
-        )
-        if bool(np.any(ovf)):
-            raise SnapshotIncompatibleError(
-                f"hash re-shard: shard {d} of {S_b} exhausted "
-                f"{checker.probe_rounds} probe rounds re-inserting "
-                f"{kl.size:,} keys at capacity {C_b:,} — raise the "
-                "target capacity or probe_rounds"
-            )
-        base = d * C_b
-        t_lo_t[base:base + C_b] = table.lo
-        t_hi_t[base:base + C_b] = table.hi
-        if track_paths:
-            p_lo_t[base + slots.astype(np.int64)] = par_lo[sel]
-            p_hi_t[base + slots.astype(np.int64)] = par_hi[sel]
-
-    # frontier rows re-route by their own fingerprints (dense [F, W]
-    # row-major blocks on the hash family), deterministically ordered
-    # per shard so the re-shard stays bit-reproducible
-    frontier = buffers["frontier"].reshape(S_a * F_a, W)
-    fval = buffers["fval"].reshape(S_a * F_a).astype(bool)
-    ebits = buffers["ebits"].reshape(S_a * F_a)
-    rows = frontier[fval]
-    eb = ebits[fval]
-    fr_lo, fr_hi = fingerprint_u32v(rows, np)
-    fr_owner = (fr_lo % np.uint32(max(S_b, 1))).astype(np.int64)
-    frontier_t = np.zeros((S_b * F_b, W), np.uint32)
-    fval_t = np.zeros(S_b * F_b, bool)
-    ebits_t = np.zeros(S_b * F_b, np.uint32)
-    for d in range(S_b):
-        fsel = fr_owner == d
-        n_d = int(fsel.sum())
-        if n_d > F_b:
-            raise SnapshotIncompatibleError(
-                f"hash re-shard: shard {d} of {S_b} would own "
-                f"{n_d:,} frontier rows but frontier_capacity is "
-                f"{F_b:,} — raise the target frontier_capacity"
-            )
-        forder = np.lexsort((fr_lo[fsel], fr_hi[fsel]))
-        base = d * F_b
-        frontier_t[base:base + n_d] = rows[fsel][forder]
-        ebits_t[base:base + n_d] = eb[fsel][forder]
-        fval_t[base:base + n_d] = True
-
-    def src(name, default):
-        b = buffers.get(name)
-        return np.array(b) if b is not None else default
-
-    return dict(
-        t_lo=t_lo_t,
-        t_hi=t_hi_t,
-        p_lo_t=p_lo_t,
-        p_hi_t=p_hi_t,
-        frontier=frontier_t,
-        fval=fval_t,
-        ebits=ebits_t,
-        depth=np.int32(buffers["depth"]),
-        wchunk=np.int32(0),
-        waves=np.uint32(buffers["waves"]),
-        gen_lo=np.uint32(buffers["gen_lo"]),
-        gen_hi=np.uint32(buffers["gen_hi"]),
-        new=np.uint32(buffers["new"]),
-        sent_lo=src("sent_lo", np.uint32(0)),
-        sent_hi=src("sent_hi", np.uint32(0)),
-        disc_found=np.array(buffers["disc_found"], bool),
-        disc_lo=np.uint32(buffers["disc_lo"]),
-        disc_hi=np.uint32(buffers["disc_hi"]),
-        overflow=np.bool_(buffers["overflow"]),
-        f_overflow=np.bool_(buffers["f_overflow"]),
-        c_overflow=np.bool_(buffers["c_overflow"]),
-        e_overflow=np.bool_(buffers["e_overflow"]),
-        done=np.bool_(buffers["done"]),
-    )
-
-
 def build_resume_carry(checker, manifest: dict, buffers: dict,
                        spec: dict) -> dict:
     """Assemble the initial device carry for a resumed run from staged
@@ -1149,10 +1013,6 @@ def build_resume_carry(checker, manifest: dict, buffers: dict,
     shapes, and sharded leaves are placed with the engine's
     ``PartitionSpec``\\ s when available (plain arrays otherwise — jit
     re-shards uncommitted inputs)."""
-    synth = set(_SYNTH_LEAVES)
-    if checker._checkpoint_family() == "hash":
-        # the hash engine's u_loc is a trace-only metric lane
-        synth.add("u_loc")
     carry_np: dict[str, np.ndarray] = {}
     for name in spec:
         leaf = spec[name]
@@ -1165,7 +1025,7 @@ def build_resume_carry(checker, manifest: dict, buffers: dict,
         if have is not None and tuple(have.shape) == want_shape:
             carry_np[name] = np.asarray(have, dtype=want_dtype)
             continue
-        if name in synth:
+        if name in _SYNTH_LEAVES:
             carry_np[name] = np.zeros(want_shape, want_dtype)
             continue
         raise SnapshotIncompatibleError(

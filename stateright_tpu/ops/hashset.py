@@ -166,34 +166,6 @@ def insert(
     return DeviceHashSet(lo, hi), inserted, overflow | pending, slot
 
 
-def _match_vma(x, vma):
-    """Promote ``x`` to vary over the manual axes in ``vma`` (no-op
-    outside shard_map). Needed because this module's while_loop carries
-    mix fresh constants (unvarying) with shard-local keys (varying) —
-    the vma checker requires carry in/out types to agree."""
-    import jax
-    from jax import lax
-
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:  # older jax: no vma typing, nothing to promote
-        return x
-    cur = getattr(typeof(x), "vma", frozenset())
-    need = tuple(sorted(set(vma) - set(cur)))
-    return lax.pcast(x, need, to="varying") if need else x
-
-
-def _inputs_vma(*arrays) -> frozenset:
-    import jax
-
-    typeof = getattr(jax, "typeof", None)
-    vma: frozenset = frozenset()
-    if typeof is None:  # older jax: no vma typing
-        return vma
-    for a in arrays:
-        vma = vma | getattr(typeof(a), "vma", frozenset())
-    return vma
-
-
 def _insert_jax(
     table: DeviceHashSet, key_lo: Any, key_hi: Any, active: Any, rounds: int
 ) -> Tuple[DeviceHashSet, Any, Any, Any]:
@@ -205,7 +177,6 @@ def _insert_jax(
     import jax.numpy as jnp
     from jax import lax
 
-    vma = _inputs_vma(table.lo, table.hi, key_lo, key_hi, active)
     n = key_lo.shape[0]
     cap = table.capacity
     mask = jnp.uint32(cap - 1)
@@ -275,9 +246,6 @@ def _insert_jax(
         slot=jnp.zeros(n, dtype=jnp.uint32),
         r=jnp.int32(0),
     )
-    init = {
-        k: (_match_vma(v, vma) if k != "r" else v) for k, v in init.items()
-    }
     out = lax.while_loop(cond, body, init)
     return (
         DeviceHashSet(out["lo"], out["hi"]),

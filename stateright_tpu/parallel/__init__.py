@@ -10,7 +10,6 @@ and no shared mutable state exists at all. Termination and counters are
 ``psum`` reductions over the mesh (SURVEY.md §2.5 items 2-4).
 """
 
-from .engine import ShardedTpuBfsChecker
 from .engine_sortmerge import ShardedSortMergeTpuBfsChecker
 
-__all__ = ["ShardedTpuBfsChecker", "ShardedSortMergeTpuBfsChecker"]
+__all__ = ["ShardedSortMergeTpuBfsChecker"]

@@ -1,12 +1,10 @@
-"""The sharded SORT-MERGE wave engine: multi-chip BFS on the fast path.
+"""The sharded sort-merge wave engine: multi-chip BFS over a device mesh.
 
-Round 2 shipped two engines pulling in opposite directions: the
-single-chip sort-merge engine (checkers/tpu_sortmerge.py) — dedup via
-``lax.sort`` merges, ~10x faster on chip than scatter-based open
-addressing — and a sharded engine (parallel/engine.py) whose
-owner-local dedup still used the hash-table scatters. This module
-closes that gap (VERDICT r2 item 4): the scale-out path now runs the
-same sorted-visited-array dedup the repo benchmarks and recommends.
+Every device owns the fingerprints with ``fp_lo % n_shards ==
+shard_index``: its slice of the visited set (a sorted fingerprint
+array, the single-chip engine's dedup of checkers/tpu_sortmerge.py),
+its block of the frontier and its part of the parent log. This is the
+one mesh engine, ``CheckerBuilder.spawn_tpu_sharded_sortmerge()``.
 
 Per wave, inside one ``shard_map``-wrapped ``lax.while_loop``:
 
@@ -299,10 +297,6 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             dest_tile_lanes=dest_tile_width(
                 self.encoded.width, self.track_paths
             ),
-            # sorted arrays work to exactly 100%: shard_balance's
-            # occupancy watch uses the exact-capacity headroom
-            # threshold (stateright_tpu/occupancy.py)
-            visited_exact=True,
             # the receive-tile classes; the shard log's dest_cap is
             # the wave's class, the top the lossless cap the routing
             # overflow check holds a run to

@@ -19,7 +19,7 @@ on — PAPERS.md: arXiv 1801.05857, 1203.6806):
   stats — one readback per chunk, so the default path keeps async
   dispatch and the <5% overhead bar; see WAVE_LOG_LANES).
 * **Per-shard wave events** (round 11, the mesh observability layer)
-  — the sharded engines keep a second device log that is NOT
+  — the mesh engine keeps a second device log that is NOT
   psum-collapsed (``SHARD_LOG_FIELDS``: local frontier/enabled/
   candidate counts, routed and received row counts, dest-tile fill
   vs the lossless ``Bd`` cap, per-shard post-dedup new and visited
@@ -127,7 +127,7 @@ WAVE_LOG_OPT_FIELDS = (
 )
 
 #: per-SHARD device wave-log lane layout (the round-11 mesh
-#: observability layer): the sharded engines additionally keep a
+#: observability layer): the mesh engine additionally keeps a
 #: ``uint32[waves_per_sync, SHARD_LOG_LANES]`` log PER SHARD that is
 #: NOT psum-collapsed — it rides the chunk carry next to the global
 #: log and is downloaded with the packed stats (one extra device
@@ -1186,11 +1186,7 @@ def shard_balance(events: list[dict], run: int | None = None,
     dest-tile fill past ``HEADROOM_THRESHOLD``, plus a skew warning
     past 2x. The ``per_wave`` list carries the full trajectory for
     tools/shard_report.py."""
-    from .occupancy import (
-        HEADROOM_THRESHOLD,
-        PROBE_PRESSURE_THRESHOLD,
-        occupancy_warning,
-    )
+    from .occupancy import HEADROOM_THRESHOLD, occupancy_warning
 
     runs = _runs(events)
     if not runs:
@@ -1206,11 +1202,6 @@ def shard_balance(events: list[dict], run: int | None = None,
     # numbers, round 12): headroom warnings price the fill in bytes
     visited_row_bytes = lane.get("visited_row_bytes")
     tile_row_bytes = int(tile_lanes) * 4 if tile_lanes else None
-    # Visited-set semantics come from the lane config: the sort-merge
-    # engines' sorted arrays work to exactly 100% (headroom watch),
-    # the hash engine's open addressing degrades from ~70% (probe
-    # pressure — its own threshold and failure mode).
-    visited_exact = bool(lane.get("visited_exact", True))
     # The lossless per-destination cap a run is held to: the top of
     # the sharded sort-merge engine's receive-tile classes (whose
     # dest_cap lane is the wave's class, sized to its fill), else the
@@ -1309,28 +1300,18 @@ def shard_balance(events: list[dict], run: int | None = None,
     occ_max = None
     if per_shard_capacity and visited:
         occ_max = round(max(visited) / per_shard_capacity, 4)
-        if visited_exact:
-            occ_threshold = HEADROOM_THRESHOLD
-            occ_consequence = (
-                "the sorted visited array overflows exactly at "
-                "100% — raise the per-shard capacity"
-            )
-        else:
-            occ_threshold = PROBE_PRESSURE_THRESHOLD
-            occ_consequence = (
-                "open addressing degrades before it fills — probe "
-                "failures become likely past ~85%; raise the "
-                "per-shard capacity"
-            )
         for s in sorted(final_visited):
             msg = occupancy_warning(
                 final_visited[s] / per_shard_capacity,
                 kind=f"shard {s} visited array",
-                threshold=occ_threshold,
+                threshold=HEADROOM_THRESHOLD,
                 used=final_visited[s],
                 capacity=per_shard_capacity,
                 bytes_per_row=visited_row_bytes,
-                consequence=occ_consequence,
+                consequence=(
+                    "the sorted visited array overflows exactly at "
+                    "100% — raise the per-shard capacity"
+                ),
             )
             if msg:
                 warnings.append(msg)

@@ -6,7 +6,8 @@ every chunk boundary (and once mid-chunk via an injected fault under
 supervision) resumes to the exact pinned 16,668; 2pc rm=7 kill/resume
 reproduces the pinned 296,448; the 2pc rm=4 virtual mesh killed at
 every boundary resumes both same-shard and through the 2→4
-(owner, fp) re-shard to the host oracle's 1,568 — plus the
+(owner, fp) re-shard to the host oracle's 1,568, and single-chip ⇄
+mesh through the same re-shard in both directions — plus the
 refuse-loudly cells (torn snapshot, stale manifest, incompatible
 target), the supervised-retry/overflow boundary, the hardened
 auto-budget store, the hybrid racer's clean loser cancellation on
@@ -249,7 +250,7 @@ def test_stale_manifest_refused(tmp_path, twopc3_snapshot):
         _paxos2().resume_from(twopc3_snapshot)
 
 
-def test_incompatible_targets_refused(twopc3_snapshot):
+def test_incompatible_targets_refused(tmp_path, twopc3_snapshot):
     # cross-family: the hash engine can't interpret a sorted prefix
     with pytest.raises(SnapshotIncompatibleError):
         TwoPhaseSys(rm_count=3).checker().spawn_tpu(
@@ -266,45 +267,66 @@ def test_incompatible_targets_refused(twopc3_snapshot):
     )
     with pytest.raises(SnapshotIncompatibleError, match="capacity"):
         tiny.resume_from(twopc3_snapshot)
+    # the hash family resumes on its original configuration only: a
+    # hash snapshot at another capacity refuses BY NAME, pointing at
+    # the sort-merge family, which re-shards
+    hash_snap = str(tmp_path / "hash.ckpt")
 
-
-def test_hash_reshard_directions(tmp_path):
-    """The degrade-and-continue round lifted PR 11's refuse-by-name
-    for the sharded-hash -> sharded-hash case: the per-shard tables
-    rebuild host-side by re-insertion through the (owner, fp) route.
-    Single-chip ⇄ sharded hash keeps refusing — with a message that
-    names the supported direction."""
-    snap = str(tmp_path / "hash.ckpt")
-
-    def spawn(n, **kw):
-        return TwoPhaseSys(rm_count=3).checker().spawn_tpu_sharded(
-            n_shards=n, capacity=1 << 10, frontier_capacity=128,
-            cand_capacity=512, bucket_capacity=256, waves_per_sync=2,
+    def spawn_hash(capacity, **kw):
+        return TwoPhaseSys(rm_count=3).checker().spawn_tpu(
+            capacity=capacity, frontier_capacity=128, waves_per_sync=2,
             **kw,
         )
 
-    _kill_at(lambda **kw: spawn(2, **kw), snap, 1)
-    # same-config hash resume works (direct upload)...
-    r = spawn(2)
-    r.resume_from(snap)
-    r.join()
-    assert r.unique_state_count() == 288
-    # ...and the sharded -> sharded re-shard now works too: 2 -> 4
-    # by host-side re-insertion, exact count + discoveries
-    re4 = spawn(4)
-    manifest = re4.resume_from(snap)
-    assert manifest["n_shards"] == 2
-    re4.join()
-    assert re4.unique_state_count() == 288
-    assert sorted(re4.discoveries()) == sorted(r.discoveries())
-    # single-chip ⇄ sharded hash keeps refusing BY NAME, and the
-    # message says which direction IS supported
-    single = TwoPhaseSys(rm_count=3).checker().spawn_tpu(
-        capacity=1 << 10, frontier_capacity=128, waves_per_sync=2,
-    )
+    _kill_at(lambda **kw: spawn_hash(1 << 10, **kw), hash_snap, 1)
+    same = spawn_hash(1 << 10)
+    same.resume_from(hash_snap)
+    same.join()
+    assert same.unique_state_count() == 288
     with pytest.raises(SnapshotIncompatibleError,
-                       match="sharded-hash -> sharded-hash"):
-        single.resume_from(snap)
+                       match="sort-merge family"):
+        spawn_hash(1 << 11).resume_from(hash_snap)
+
+
+def _single2pc4(**kw):
+    # 256 frontier rows (the mesh's per-shard budget) overflow on one
+    # chip: 2pc rm=4's widest wave needs more
+    return TwoPhaseSys(rm_count=4).checker().spawn_tpu_sortmerge(
+        capacity=1 << 12, frontier_capacity=1024, cand_capacity=8192,
+        waves_per_sync=4, **kw,
+    )
+
+
+@pytest.mark.parametrize("direction", ["single_to_mesh", "mesh_to_single"])
+def test_sortmerge_reshard_single_and_mesh(tmp_path, host_2pc4,
+                                           direction):
+    """Single-chip ⇄ mesh through the (owner, fp) re-shard: a 2pc
+    rm=4 snapshot killed at chunk 1 on one side resumes on the other
+    to the host oracle's exact count and an uninterrupted run's
+    discoveries (single-chip is the S=1 layout of the same family)."""
+    mesh2 = lambda **kw: _mesh2pc4(2, **kw)  # noqa: E731
+    if direction == "single_to_mesh":
+        src, dst, kind, n_shards = _single2pc4, mesh2, "single", 1
+    else:
+        src, dst, kind, n_shards = mesh2, _single2pc4, "sharded", 2
+    snap = str(tmp_path / f"{direction}.ckpt")
+    _kill_at(src, snap, 1)
+    resumed = dst()
+    manifest = resumed.resume_from(snap)
+    assert manifest["family"] == "sortmerge"
+    assert manifest["kind"] == kind
+    assert manifest["n_shards"] == n_shards
+    resumed.join()
+    assert resumed.unique_state_count() == 1568
+    assert host_2pc4.unique_state_count() == 1568
+    whole = dst().join()
+    assert sorted(resumed.discoveries()) == sorted(whole.discoveries())
+    assert sorted(resumed.discoveries()) == sorted(
+        host_2pc4.discoveries()
+    )
+    for name, path in resumed.discoveries().items():
+        prop = resumed.model.property_by_name(name)
+        assert prop.condition(resumed.model, path.last_state())
 
 
 def test_engine_overflow_is_not_supervised(tmp_path):

@@ -2,8 +2,8 @@
 
 Same two-halves structure as the codegen gate (tests/test_lint.py):
 
-* the GATE — the comms rule family over both sharded engines' wave
-  bodies (traced + untraced, real S=2 mesh), the rm=5/S=8
+* the GATE — the comms rule family over the mesh engine's wave
+  body (traced + untraced + tiered, real S=2 mesh), the rm=5/S=8
   reconciliation fixture, and every registry encoding's sharded pair
   pipeline comes back clean (what ``tools/lint_comms.py`` exits 0 on);
 * the TEETH — deliberate regressions (a collective moved inside a
@@ -84,7 +84,7 @@ def _ctx(name="synthetic", seam=None):
 @pytest.fixture(scope="module")
 def gate_report():
     """ONE full run_comms_lint() serves every gate assertion: each
-    run rebuilds both sharded engines x traced/untraced (incl. the
+    run rebuilds the mesh engine traced/untraced/tiered (incl. the
     S=8 rm=5 reconciliation engine) and harvests every registry
     encoding, and the output is deterministic (pinned by the verify
     skill's json-compare probe) — re-running per test only re-buys
@@ -93,7 +93,7 @@ def gate_report():
 
 
 def test_comms_lint_clean_all_fixtures(gate_report):
-    """Both sharded engines x traced/untraced bodies, the
+    """The mesh engine's traced/untraced bodies, the
     reconciliation fixture, and every registry encoding's sharded
     pipeline: zero gated comms findings. This is the tier-1 mesh
     communication-contract gate."""
@@ -103,15 +103,14 @@ def test_comms_lint_clean_all_fixtures(gate_report):
     ]
     assert report["clean"], errors
     covered = {(p["encoding"], p["path"]) for p in report["paths"]}
-    for engine in ("sortmerge", "hash"):
-        for traced in (False, True):
-            name = comms_fixture_name(engine, traced)
-            assert (name, "wave-body") in covered, name
+    for traced in (False, True):
+        name = comms_fixture_name(traced)
+        assert (name, "wave-body") in covered, name
     # the TIERED chunk program (round 16, stateright_tpu/tier.py)
     # rides the same gate: its deferred-commit phase must stay
     # collective-clean too
     assert (
-        comms_fixture_name("sortmerge", True, tiered=True),
+        comms_fixture_name(True, tiered=True),
         "wave-body",
     ) in covered
     assert (RECONCILIATION_FIXTURE, "wave-body") in covered
@@ -148,18 +147,13 @@ def test_traced_mesh_log_adds_no_collective_traffic(gate_report):
     untraced one by at most ONE scalar psum (the global wave row's
     n_tot back-fill, 4 bytes) — the telemetry layer rides the
     existing sync, it does not add traffic."""
-    for engine in ("sortmerge", "hash"):
-        plain = gate_report["comms"][
-            comms_fixture_name(engine, False)
-        ]
-        traced = gate_report["comms"][
-            comms_fixture_name(engine, True)
-        ]
-        delta = (
-            traced["per_wave_peak_bytes"]
-            - plain["per_wave_peak_bytes"]
-        )
-        assert 0 <= delta <= 4, (engine, delta)
+    plain = gate_report["comms"][comms_fixture_name(False)]
+    traced = gate_report["comms"][comms_fixture_name(True)]
+    delta = (
+        traced["per_wave_peak_bytes"]
+        - plain["per_wave_peak_bytes"]
+    )
+    assert 0 <= delta <= 4, delta
 
 
 # -- the teeth -------------------------------------------------------------

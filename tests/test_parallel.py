@@ -20,20 +20,34 @@ def host_2pc3():
     return TwoPhaseSys(rm_count=3).checker().spawn_bfs().join()
 
 
-@pytest.mark.parametrize("n_shards", [1, 2, 8])
-def test_sharded_2pc_matches_host(n_shards, host_2pc3):
+@pytest.mark.parametrize("layout", ["n_shards", "mesh"])
+@pytest.mark.parametrize("shards", [1, 2, 8])
+def test_sharded_2pc_matches_host(shards, layout, host_2pc3):
+    """State-identical across shard counts, built from a shard count
+    or from an explicit mesh, WITH path tracking: discovery paths
+    replay through the host model."""
+    if layout == "mesh":
+        import jax
+        from jax.sharding import Mesh
+
+        where = dict(
+            mesh=Mesh(np.array(jax.devices()[:shards]), ("shard",))
+        )
+    else:
+        where = dict(n_shards=shards)
     c = (
         TwoPhaseSys(rm_count=3)
         .checker()
-        .spawn_tpu_sharded(
-            n_shards=n_shards,
-            capacity=1 << 10,
+        .spawn_tpu_sharded_sortmerge(
+            capacity=512,
             frontier_capacity=128,
-            cand_capacity=512,
-            bucket_capacity=256
+            cand_capacity=1024,
+            bucket_capacity=512,
+            **where,
         )
         .join()
     )
+    assert c.n_shards == shards
     assert c.unique_state_count() == 288
     assert c.unique_state_count() == host_2pc3.unique_state_count()
     assert sorted(c.discoveries()) == sorted(host_2pc3.discoveries())
@@ -49,7 +63,7 @@ def test_sharded_2pc_5rms_8832():
     c = (
         TwoPhaseSys(rm_count=5)
         .checker()
-        .spawn_tpu_sharded(
+        .spawn_tpu_sharded_sortmerge(
             n_shards=8,
             capacity=1 << 12,
             frontier_capacity=512,
@@ -69,8 +83,9 @@ def test_sharded_single_shard_no_shuffle():
     c = (
         TwoPhaseSys(rm_count=3)
         .checker()
-        .spawn_tpu_sharded(
-            n_shards=1, capacity=1 << 10, frontier_capacity=128, cand_capacity=512
+        .spawn_tpu_sharded_sortmerge(
+            n_shards=1, capacity=1 << 10, frontier_capacity=128,
+            cand_capacity=512,
         )
         .join()
     )
@@ -90,7 +105,7 @@ def test_sharded_agrees_with_single_chip_engine():
     sharded = (
         TwoPhaseSys(rm_count=4)
         .checker()
-        .spawn_tpu_sharded(
+        .spawn_tpu_sharded_sortmerge(
             n_shards=8,
             capacity=1 << 10,
             frontier_capacity=256,
@@ -147,7 +162,7 @@ def test_sharded_eventually_property():
     )
     checker = (
         model.checker()
-        .spawn_tpu_sharded(
+        .spawn_tpu_sharded_sortmerge(
             encoded=DGraphEncoded(model),
             n_shards=4,
             capacity=64,
@@ -171,7 +186,7 @@ def test_sharded_target_max_depth():
         TwoPhaseSys(rm_count=3)
         .checker()
         .target_max_depth(5)
-        .spawn_tpu_sharded(
+        .spawn_tpu_sharded_sortmerge(
             n_shards=4,
             capacity=1 << 10,
             frontier_capacity=128,
@@ -188,7 +203,7 @@ def test_sharded_fast_mode_discovery_fingerprints():
     c = (
         TwoPhaseSys(rm_count=3)
         .checker()
-        .spawn_tpu_sharded(
+        .spawn_tpu_sharded_sortmerge(
             n_shards=2,
             capacity=1 << 10,
             frontier_capacity=128,
@@ -203,41 +218,6 @@ def test_sharded_fast_mode_discovery_fingerprints():
     assert names == {"abort agreement", "commit agreement"}
     with pytest.raises(RuntimeError):
         c.discoveries()
-
-
-@pytest.mark.parametrize("shards", [1, 2, 8])
-def test_sharded_sortmerge_matches_host(shards):
-    """The sharded SORT-MERGE engine (VERDICT r2 #4): owner-local dedup
-    on the sorted-array fast path, state-identical across shard counts,
-    WITH path tracking — discovery paths replay through the host model."""
-    import jax
-
-    devices = jax.devices()
-    if len(devices) < shards:
-        pytest.skip(f"need {shards} devices")
-    from jax.sharding import Mesh
-
-    import numpy as np
-
-    mesh = Mesh(np.array(devices[:shards]), ("shard",))
-    host = TwoPhaseSys(rm_count=3).checker().spawn_bfs().join()
-    c = (
-        TwoPhaseSys(rm_count=3)
-        .checker()
-        .spawn_tpu_sharded_sortmerge(
-            mesh=mesh,
-            capacity=512,
-            frontier_capacity=128,
-            cand_capacity=1024,
-            bucket_capacity=512,
-        )
-        .join()
-    )
-    assert c.unique_state_count() == host.unique_state_count() == 288
-    assert sorted(c.discoveries()) == sorted(host.discoveries())
-    for name, path in c.discoveries().items():
-        prop = c.model.property_by_name(name)
-        assert prop.condition(c.model, path.last_state())
 
 
 def test_sharded_sparse_paxos_with_paths():

@@ -365,51 +365,6 @@ def test_traced_sharded_parity_and_shard_log_8_mesh():
     assert rep["ok"], rep["divergences"]
 
 
-def test_traced_sharded_hash_engine_shard_log():
-    """The hash-table sharded engine (parallel/engine.py) grew BOTH
-    logs in round 11 — it previously traced chunk events only. Counts
-    unchanged, wave events present, shard rows reconcile."""
-    from jax.sharding import Mesh
-
-    from stateright_tpu.models.two_phase_commit import TwoPhaseSys
-
-    devices = jax.devices()
-    if len(devices) < 4:
-        pytest.skip("needs the virtual multi-device CPU mesh")
-    mesh = Mesh(np.array(devices[:4]), ("shard",))
-
-    def spawn():
-        return (
-            TwoPhaseSys(rm_count=3)
-            .checker()
-            .spawn_tpu_sharded(
-                mesh=mesh,
-                capacity=1 << 10,
-                frontier_capacity=256,
-                cand_capacity=1024,
-                bucket_capacity=512,
-                waves_per_sync=8,
-                track_paths=False,
-            )
-        )
-
-    c0 = spawn().join()
-    tr = RunTracer()
-    with tr.activate():
-        c1 = spawn().join()
-    assert c1.unique_state_count() == c0.unique_state_count() == 288
-    validate_events(tr.events)
-    waves = [e for e in tr.events if e["ev"] == "wave"]
-    shard_waves = [e for e in tr.events if e["ev"] == "shard_wave"]
-    assert waves[-1]["unique_total"] == 288
-    for w in waves:
-        rows = [e for e in shard_waves if e["wave"] == w["wave"]]
-        assert len(rows) == 4
-        assert sum(r["new_states"] for r in rows) == w["new_states"]
-        assert sum(r["visited_total"] for r in rows) == \
-            w["unique_total"]
-
-
 def test_auto_budget_retry_event_and_warning(tmp_path):
     """Satellite: a forced overflow on the geometric capacity ladder
     must produce a telemetry event AND a one-line warning naming the
@@ -603,8 +558,7 @@ def test_trace_diff_cli_exit_codes(tmp_path):
 
 def _synthetic_shard_trace(tmp_path, name, per_shard_new, *,
                            permute=False, dest_cap=512, fill=8,
-                           capacity=1024, visited0=1,
-                           visited_exact=True, recv_classes=None):
+                           capacity=1024, visited0=1, recv_classes=None):
     """A schema-valid sharded trace: ``per_shard_new[wave][shard]`` is
     the post-dedup new count; global rows are the shard sums, so the
     two log levels reconcile the way a real engine's do. ``permute``
@@ -620,7 +574,7 @@ def _synthetic_shard_trace(tmp_path, name, per_shard_new, *,
             recv_classes=recv_classes)
         tr.begin_run(lane=dict(engine="T", n_shards=S,
                                capacity=capacity, dest_tile_lanes=10,
-                               visited_exact=visited_exact, **extra))
+                               **extra))
         visited = [visited0] * S
         prev_front = [1] * S
         u = S * visited0
@@ -754,34 +708,12 @@ def test_shard_balance_headroom_warnings(tmp_path):
     assert bal_r["dest_fill_worst"]["cap"] == 400
     assert not any("dest tile" in w for w in bal_r["warnings"])
 
-    # a HASH-engine lane (visited_exact=False) watches probe
-    # pressure instead: warns earlier (0.7 bar) with the
-    # open-addressing failure mode, not exact-capacity headroom
-    probing = load_trace(
-        _synthetic_shard_trace(
-            tmp_path, "probe.jsonl", BALANCED,
-            capacity=200, visited0=40, visited_exact=False,
-        )
-    )
-    bal2 = shard_balance(probing)
-    assert any("probe failures" in w for w in bal2["warnings"])
-    assert not any("overflows exactly" in w for w in bal2["warnings"])
-    # at ~0.37 occupancy an exact-capacity lane is quiet where the
-    # probe watch would also be — threshold semantics, not noise
-    mid = load_trace(
-        _synthetic_shard_trace(
-            tmp_path, "mid.jsonl", BALANCED,
-            capacity=200, visited0=11, visited_exact=False,
-        )
-    )
-    # 11 + 148 = 159/200 = 0.795 > 0.7: the probe watch fires where
-    # the exact-capacity watch (0.8 bar) would stay quiet
-    bal3 = shard_balance(mid)
-    assert any("probe failures" in w for w in bal3["warnings"])
+    # 11 + 148 = 159/200 = 0.795: the exact-capacity watch (0.8
+    # bar) stays quiet — threshold semantics, not noise
     mid_exact = load_trace(
         _synthetic_shard_trace(
             tmp_path, "mid_exact.jsonl", BALANCED,
-            capacity=200, visited0=11, visited_exact=True,
+            capacity=200, visited0=11,
         )
     )
     assert not any("visited array" in w

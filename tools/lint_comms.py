@@ -4,8 +4,8 @@
 Runs the comms rule family (stateright_tpu/analysis/comms.py —
 ``no-collective-in-switch``, ``no-unsorted-all-to-all``,
 ``scalar-only-reductions``, ``no-all-gather``, the gated
-``comms-bytes`` budget) over BOTH sharded engines' full wave bodies
-(sort-merge + hash, traced and untraced forms, real S=2 mesh), the
+``comms-bytes`` budget) over the mesh engine's full wave body
+(traced, untraced and tiered forms, real S=2 mesh), the
 rm=5/S=8 reconciliation fixture at the committed TRACE_r16 dryrun
 config, and every registry encoding's ``engine:sharded`` pair
 pipeline. Exit status 0 iff clean — the same gate ``pytest -m lint``
@@ -92,7 +92,7 @@ def main():
 
     # the gate traces each wave-body fixture once; --hlo reuses the
     # same fixture objects (fn + carry shapes) instead of rebuilding
-    # the sharded engines and re-tracing
+    # the mesh engines and re-tracing
     fixtures: list = []
     report = run_comms_lint(
         wave_bodies=not args.no_wave_body,

@@ -2,8 +2,8 @@
 """Shard-balance report: the human-readable view of a mesh TRACE.
 
 Reads a ``TRACE_r*.jsonl`` run-telemetry artifact whose run carries
-``shard_wave`` events (a traced run of either sharded engine —
-``spawn_tpu_sharded_sortmerge`` / ``spawn_tpu_sharded``) and renders
+``shard_wave`` events (a traced run of the mesh engine,
+``spawn_tpu_sharded_sortmerge``) and renders
 the numbers that decide whether the (owner, fp)-sort shuffle scales
 (ROADMAP direction 1):
 
@@ -178,8 +178,8 @@ def main():
     if bal is None:
         print(
             "shard_report: no shard_wave events in this trace — "
-            "trace a SHARDED engine run "
-            "(spawn_tpu_sharded_sortmerge / spawn_tpu_sharded)",
+            "trace a mesh engine run "
+            "(spawn_tpu_sharded_sortmerge)",
             file=sys.stderr,
         )
         sys.exit(2)

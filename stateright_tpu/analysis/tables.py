@@ -95,22 +95,26 @@ CARRY_COPY_BYTE_BUDGETS = {
     # same budget pins it.
     "engine-fixture(2pc-rm3,merge=pallas)": 450_000,
     # The SHARDED engine's wave body in its TRACED form (round 11,
-    # registry.SHARDED_WAVE_BODY_FIXTURE): 8 switches / 196,488 B
-    # measured at the fixture shapes (9 / 153,780 B before the
-    # receive-tile class). The per-shard mesh log's only
-    # switch-carry addition is the 36 B ``swave`` row the merge stage
-    # returns (9 uint32 lanes; the ``slog`` appends live OUTSIDE the
-    # switches, in the body wrapper, so they price as loop-body DUS,
-    # not branch carry) — i.e. the telemetry layer moved the carry
-    # budget by 36 B, not by the log size. The frontier-class switch
-    # returns the routed payload written into the ``route`` carry lane
-    # (2,336 rows x 7 lanes, 65,408 B); the receive-class switch
-    # returns the lean per-shard carry (C=2^11 per shard) and its
-    # three classes hold one membership and one append switch each;
-    # the dest tiles and recv buffers are wave-local temporaries. The
-    # budget was set ~30% above the first measurement, same policy as
-    # the rows above.
-    "engine-fixture(2pc-rm3,sharded+slog)": 200_000,
+    # registry.SHARDED_WAVE_BODY_FIXTURE): 9 switches / 215,286 B
+    # measured at the fixture shapes (8 / 196,488 B before the pair
+    # class, 9 / 153,780 B before the receive-tile class). The
+    # per-shard mesh log's only switch-carry addition is the 40 B
+    # ``swave`` row the merge stage returns (10 uint32 lanes; the
+    # ``slog`` appends live OUTSIDE the switches, in the body wrapper,
+    # so they price as loop-body DUS, not branch carry). The
+    # frontier-class switch returns the pair buffer (keys + slots,
+    # 2 x 2,336 rows, 18,688 B) and the frontier-row props, ebits and
+    # fingerprints written into F-row blocks (8,192 B); the pair-class
+    # switch returns the routed payload written into the ``route``
+    # carry lane at the pair ladder's top (2,048 rows x 7 lanes,
+    # 57,344 B); the receive-class switch returns the lean per-shard
+    # carry (C=2^11 per shard) and its three classes hold one
+    # membership and one append switch each; the dest tiles and recv
+    # buffers are wave-local temporaries. The pair class was a
+    # deliberate, priced addition of 18,798 B (a second send-side
+    # switch boundary), and the budget sits ~16% above it: one more
+    # payload-sized switch boundary still fails it.
+    "engine-fixture(2pc-rm3,sharded+slog)": 250_000,
 }
 
 

@@ -304,7 +304,7 @@ def frontier_enabled_bits(enc, frontier_t, fval_f, expand, *,
 def sparse_pair_candidates(enc, frontier_t, fval_f, expand, *, EV, B_p,
                            NT, T, mask_budget_cells, Ba,
                            axis_name=None, n_rows=None,
-                           ample_words=None):
+                           ample_words=None, compact=False):
     """The sparse-dispatch pair pipeline, shared by the single-chip and
     sharded sort-merge engines (PERF.md §sparse): per-slot enabled
     mask → per-row bitmaps (tiled so the [F, K] bool mask never
@@ -337,6 +337,12 @@ def sparse_pair_candidates(enc, frontier_t, fval_f, expand, *, EV, B_p,
     pair_ovf, tile_max)`` — ``pair_ovf`` is True when a row enabled
     more than EV slots or the wave enabled more than B_p pairs.
 
+    ``compact`` forces the packed-append compaction where the class's
+    ``F_f * EV`` pair grid already fits the budget (the mesh engine
+    sizes its pair step by the live pairs, so it wants them as a dense
+    prefix in every class); the live pairs keep their pair-index
+    order either way.
+
     Codegen contract (pinned by ``pytest -m lint`` /
     tools/lint_kernels.py for every registered encoding, in BOTH
     invocation styles — this direct call and the sharded engine's
@@ -352,7 +358,7 @@ def sparse_pair_candidates(enc, frontier_t, fval_f, expand, *, EV, B_p,
     K = enc.max_actions
     L = mask_words(K)
     NPg = F_f * EV
-    compaction = NPg > B_p
+    compaction = compact or NPg > B_p
 
     def pv(x):
         """Inside shard_map, fori_loop carries seeded from constants

@@ -95,11 +95,12 @@ _SENT = 0xFFFFFFFF
 #: carry leaves resume may synthesize (zeros) when the snapshot lacks
 #: them or disagrees on their shape (tracer state, waves_per_sync, a
 #: re-shard): trace-gated telemetry rewritten inside the chunk before
-#: any row is read, the sharded engine's per-receive-class wave
-#: counter (telemetry, restarting at zero) and its routed-payload
-#: scratch.
+#: any row is read, the sharded engine's per-pair-class and
+#: per-receive-class wave counters (telemetry, restarting at zero)
+#: and its routed-payload scratch.
 _SYNTH_LEAVES = frozenset({"wlog", "slog", "swave", "wv_pairs",
-                           "wv_canon", "pstash", "route", "recv_waves"})
+                           "wv_canon", "pstash", "route", "recv_waves",
+                           "pair_waves"})
 
 #: carry leaves a snapshot leaves out: scratch rewritten inside every
 #: wave before any row is read (the sharded engine's routed payload),

@@ -37,29 +37,36 @@ Per wave, inside one ``shard_map``-wrapped ``lax.while_loop``:
    ``psum``/``pmin`` reductions: every device agrees on ``done``
    without touching the host.
 
-**Adaptive classes (round 4).** Round 3's sharded waves compiled ONE
-worst-case shape, re-importing the flat-wave cost profile whose
-single-chip version caused the round-2 rm=8 cliff. Waves now dispatch
-through the same frontier/visited class ladders as the single-chip
-engine — every shard agrees on the class via ``lax.pmax`` over local
-frontier/unique counts (collectives are collective: the ``lax.switch``
-must take the same branch on every shard). The frontier class sizes
-the send side: expansion, fingerprints, the routing sort and the
-routed payload. The receive side has a class of its own, because the
-frontier class does not bound a wave's routed rows: at paxos-4's
-mesh budgets ``F_c * EV`` fills the candidate budget in every
-frontier class, so a per-destination tile sized by it is the lossless
-cap ``Bd_c`` in every wave. After the routing sort all shards agree on
-the largest per-destination count (``lax.pmax``) and take the smallest
-receive class that holds it, on the ladder ``Bd, Bd/4, Bd/16, Bd/64``
-(no rung below 128 rows; :func:`recv_ladder`, :func:`recv_tile_class`)
-— the tiles, the ``all_to_all`` and the owner-local merge (candidate
-sort, membership, winner compaction, visited append, winner fetch)
-run at ``S * Bd_r`` rows, sized by the wave. The class holds the
-largest count, so no candidate is dropped and every wave's visited
-set, winners and their order are those of the lossless cap. The two
-switches run in sequence, so the merge is traced once per receive
-class. Encodings implementing
+**Adaptive classes.** Waves dispatch through the same frontier and
+visited class ladders as the single-chip engine, and every shard
+agrees on each class via ``lax.pmax`` over its local count
+(collectives are collective: a ``lax.switch`` must take the same
+branch on every shard). A wave passes three class switches in
+sequence, each sized by what the wave holds:
+
+* the FRONTIER class (``F_c`` rows, from the largest frontier block):
+  the property bits and frontier fingerprints, the enabled bits, the
+  peel and the compaction of the live (pair, slot) indices into the
+  dense prefix of a fixed-shape pair buffer;
+* the PAIR class (``P_r`` rows, from the largest pair count): the pair
+  step, the candidate fingerprints, the 4-lane routing sort and the
+  routed payload, written into the ``route`` lane. ``F_c * EV`` fills
+  the candidate budget in every frontier class at paxos-4's mesh
+  budgets, so the pair class is what sizes the send side to the wave;
+* the RECEIVE class (``S * Bd_r`` rows, from the largest
+  per-destination run): the tiles sliced from ``route``, the
+  ``all_to_all`` and the owner-local merge (candidate sort,
+  membership, winner compaction, visited append, winner fetch).
+
+The pair and receive ladders are ``top, top/4, top/16, top/64`` (no
+rung below 128 rows; :func:`class_ladder`, :func:`class_of`) over the
+candidate budget and the lossless per-destination cap ``Bd``. Each
+class holds the largest shard's count and the compaction keeps the
+pairs in pair-index order, so no candidate is dropped and every
+wave's candidates, routed rows, visited set, winners and their order
+are those of the largest class. The dense path (encodings without
+pair extraction) has no pair class: its frontier class expands,
+fingerprints and routes. Encodings implementing
 ``SparseEncodedModel`` get sparse action dispatch here too: pairs are
 extracted and stepped shard-locally (the shared pipeline in
 checkers/tpu_sortmerge.py — including the round-6 WORD-NATIVE enabled
@@ -96,21 +103,23 @@ from ..checkers.tpu_sortmerge import SortMergeTpuBfsChecker
 _SENT = 0xFFFFFFFF
 
 
-def recv_ladder(top: int, step: int = 4, rungs: int = 4,
-                floor: int = 128) -> list:
-    """The receive-tile classes, smallest first: the lossless
-    per-destination cap ``top``, then ``top/4``, ``top/16``,
-    ``top/64``, no rung below ``floor`` rows (nor below ``top`` where
-    that is smaller)."""
+def class_ladder(top: int, step: int = 4, rungs: int = 4,
+                 floor: int = 128) -> list:
+    """The row classes of a per-wave count, smallest first: ``top``
+    (the lossless per-destination cap for the receive classes, the
+    largest candidate budget for the pair classes), then ``top/4``,
+    ``top/16``, ``top/64``, no rung below ``floor`` rows (nor below
+    ``top`` where that is smaller)."""
     lo = min(floor, top)
     return sorted({max(lo, top // step ** i) for i in range(rungs)})
 
 
-def recv_tile_class(n, ladder):
+def class_of(n, ladder):
     """Index of the smallest rung of ``ladder`` that holds ``n`` rows,
     for a Python int or a traced scalar alike. A count above the top
-    rung picks the top; the routing stage's overflow check has then
-    already refused the wave."""
+    rung picks the top; the wave's overflow check (routing for the
+    receive class, the candidate budget for the pair class) has then
+    already refused it."""
     return sum((n > rung) * 1 for rung in ladder[:-1])
 
 
@@ -240,20 +249,27 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 int(extra[1]) << 32
             )
             self.metrics["max_wave_candidates"] = int(extra[2])
-        n_recv = len((self._build_info or {}).get("recv_classes", ()))
-        if n_recv and extra.size >= 3 + n_recv:
-            # waves per receive class, smallest first (pack_stats'
-            # last lanes)
+        info = self._build_info or {}
+        n_recv = len(info.get("recv_classes", ()))
+        n_pair = len(info.get("pair_classes", ()))
+        if n_recv and extra.size >= 3 + n_pair + n_recv:
+            # waves per pair class, then per receive class, smallest
+            # first (pack_stats' last lanes)
             self.metrics["recv_class_waves"] = [
                 int(x) for x in extra[-n_recv:]
             ]
+            if n_pair:
+                self.metrics["pair_class_waves"] = [
+                    int(x) for x in extra[-(n_pair + n_recv):-n_recv]
+                ]
 
     def _run_stats(self) -> dict:
         """The trace's run_end summary plus the shuffle's run-level
         counters: the shard count, the rows routed off-shard (the
         psum'd ``sent`` counter), the worst per-wave candidate skew
         (max/mean over shards) of the run's ``shard_wave`` events, and
-        the waves run at each receive class, smallest first."""
+        the waves run at each pair class and each receive class,
+        smallest first."""
         from .. import telemetry
 
         stats = super()._run_stats()
@@ -268,6 +284,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             n_shards=self.n_shards,
             routed_rows=self.metrics.get("shuffle_volume"),
             candidate_skew_worst=worst["skew"] if worst else None,
+            pair_class_waves=self.metrics.get("pair_class_waves"),
             recv_class_waves=self.metrics.get("recv_class_waves"),
         )
         return stats
@@ -279,6 +296,14 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         # the tracer back-fills the wave event from the shard sum
         # instead of recording enabled_pairs=null.
         return False
+
+    def _pair_ladder(self) -> list:
+        """The pair classes, smallest first: the class ladder over the
+        largest frontier class's candidate budget (sparse only; the
+        dense path has no pairs)."""
+        if not self._use_sparse():
+            return []
+        return class_ladder(max(p[4] for p in self._f_class_params()))
 
     def _plan_sharded_names(self) -> tuple:
         # Mirrors the shard_map out_specs in _build_programs: these
@@ -300,7 +325,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             # the receive-tile classes; the shard log's dest_cap is
             # the wave's class, the top the lossless cap the routing
             # overflow check holds a run to
-            recv_classes=recv_ladder(
+            recv_classes=class_ladder(
                 max(p[5] for p in self._f_class_params())
             ),
         )
@@ -380,8 +405,9 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
     def _f_class_params(self) -> list:
         """Static per-shard shapes of each frontier class, smallest
         first: ``(F_c, NT, T, R_src, B_c, Bd_c)`` — frontier rows,
-        pair tiles and their rows, the candidate buffer, the candidate
-        budget, and the lossless per-destination cap."""
+        pair tiles and their rows, the candidate buffer (sparse: the
+        rows the class's compaction may write), the candidate budget,
+        and the lossless per-destination cap."""
         from ..checkers.tpu_sortmerge import _divisor_at_least, _ladder
 
         K, F, S = (self.encoded.max_actions, self.frontier_capacity,
@@ -392,17 +418,18 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         out = []
         for F_c in _ladder(self.f_min, F, self.ladder_step):
             if use_sparse:
+                # Every class compacts its live pairs into a dense
+                # prefix (the pair class steps only that prefix).
+                # Keep the packed-append headroom ≤ B_c/4 (see the
+                # single-chip engine's make_sparse_wave); a grid that
+                # fits the budget never writes past its own rows.
                 NPg = F_c * EV
                 B_c = min(B_user, NPg)
-                compaction = NPg > B_c
-                want = -(-NPg // self.tile_rows)
-                if compaction:
-                    # Keep the packed-append headroom ≤ B_c/4 (see
-                    # the single-chip engine's make_sparse_wave).
-                    want = max(want, -(-(4 * NPg) // max(B_c, 1)))
-                NT = _divisor_at_least(F_c, want) if compaction else 1
+                want = max(-(-NPg // self.tile_rows),
+                           -(-(4 * NPg) // max(B_c, 1)))
+                NT = _divisor_at_least(F_c, want)
                 T = F_c // NT
-                R_src = (B_c + T * EV) if compaction else NPg
+                R_src = min(NPg, B_c + T * EV)
             else:
                 NT = T = 1
                 R_src = F_c * K
@@ -543,22 +570,30 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         # per-class route_ovf checks keep refusing a wave whose run
         # exceeds its frontier class's cap.
         f_params = self._f_class_params()
-        r_ladder = recv_ladder(max(p[5] for p in f_params))
+        r_ladder = class_ladder(max(p[5] for p in f_params))
+        # The pair classes (sparse only, see the module docstring):
+        # each frontier class compacts its live pairs into the dense
+        # prefix of an R_pair-row buffer, and the pair step,
+        # fingerprints, routing sort and payload run at the smallest
+        # rung of this ladder that holds every shard's pair count.
+        p_ladder = self._pair_ladder()
+        R_pair = max(p[3] for p in f_params) if use_sparse else 0
         # The routed payload lands in the fixed-shape ``route`` carry
-        # lane, rows [0, R_src) of the wave's frontier class written
-        # in routing order; R_route covers the largest class. Where a
-        # class fingerprints in chunks (the memory-lean mode: the
-        # [R_src, W] successor tensor would blow the flat budget) the
-        # lane holds (pair, slot, parent meta, key) rows instead and
-        # the receive class recomputes each tile's successors — one
-        # layout for every class, so the receive branches need not
-        # know the frontier class.
+        # lane, rows [0, R) of the wave's pair class (dense: frontier
+        # class) written in routing order; R_route covers the largest
+        # class. Where a class fingerprints in chunks (the memory-lean
+        # mode: the [R, W] successor tensor would blow the flat
+        # budget) the lane holds (pair, slot, parent meta, key) rows
+        # instead and the receive class recomputes each tile's
+        # successors — one layout for every class, so the receive
+        # branches need not know the sending class.
         row_pad = -(-W // 128) * 512
-        recompute = use_sparse and any(
-            p[3] * row_pad > self.flat_budget_bytes for p in f_params
+        recompute = use_sparse and (
+            p_ladder[-1] * row_pad > self.flat_budget_bytes
         )
         n_meta = 3 if track_paths else 1  # ebits (+ parent fp limbs)
-        R_route = max(p[3] for p in f_params)
+        R_route = (p_ladder[-1] if use_sparse
+                   else max(p[3] for p in f_params))
         route_lanes = 2 + n_meta + 2 if recompute else E + 2
 
         def bool_any(x):
@@ -630,6 +665,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 pl_n=jnp.zeros(1, jnp.uint32),
                 route=jnp.zeros((R_route, route_lanes), jnp.uint32),
                 recv_waves=jnp.zeros(len(r_ladder), jnp.uint32),
+                **(
+                    dict(pair_waves=jnp.zeros(len(p_ladder), jnp.uint32))
+                    if use_sparse else {}
+                ),
                 frontier=frontier,
                 fval=fval,
                 ebits=ebits,
@@ -679,12 +718,12 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             the switch are the simpler contract.
 
             ``shard_log`` (traced runs only) is the wave's
-            ``(enabled_pairs, routed_rows, dest_fill_peak, dest_cap)``
-            per-shard scalars from the routing stage and the receive
-            class; this stage adds
+            ``(enabled_pairs, routed_rows, dest_fill_peak, dest_cap,
+            pair_cap)`` per-shard scalars from the routing stage and
+            the two classes; this stage adds
             the quantities it owns (received rows, post-dedup new,
             the visited total) and returns the assembled
-            ``swave: uint32[SHARD_LOG_LANES]`` row in the carry — 36
+            ``swave: uint32[SHARD_LOG_LANES]`` row in the carry — 40
             bytes of extra switch output, priced by the lint's
             sharded wave-body fixture (analysis/tables.py)."""
             disc_found, disc_lo, disc_hi = disc
@@ -873,8 +912,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 )
                 trace_extra = {}
                 if shard_log is not None:
-                    wv_pairs, cross_rows, fill_peak, dest_cap = \
-                        shard_log
+                    (wv_pairs, cross_rows, fill_peak, dest_cap,
+                     pair_cap) = shard_log
                     # provisional lanes 7/8 — the commit patches them
                     # with the confirmed count before the slog write
                     trace_extra = dict(
@@ -889,6 +928,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                                 dest_cap,
                                 nc_u32,
                                 c["u_loc"][0] + nc_u32,
+                                pair_cap,
                             ]
                         )
                     )
@@ -948,7 +988,8 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 # The per-shard mesh wave row (SHARD_LOG_FIELDS),
                 # assembled where the local quantities exist — lanes
                 # 0-1 close the sharded enabled_pairs=null hole.
-                wv_pairs, cross_rows, fill_peak, dest_cap = shard_log
+                (wv_pairs, cross_rows, fill_peak, dest_cap,
+                 pair_cap) = shard_log
                 trace_extra = dict(
                     swave=jnp.stack(
                         [
@@ -961,6 +1002,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                             dest_cap,
                             nc_u32,
                             c["u_loc"][0] + nc_u32,
+                            pair_cap,
                         ]
                     )
                 )
@@ -995,330 +1037,417 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 done=~cont,
             )
 
+        def fold_discoveries(c, ex, fval_x):
+            """This wave's property hits over the local frontier block,
+            folded globally (the lowest hitting shard index wins,
+            mirroring whichever racing thread lands first in the
+            reference)."""
+            if not n_props:
+                return c["disc_found"], c["disc_lo"], c["disc_hi"]
+            me = lax.axis_index("shard").astype(jnp.uint32)
+            hits, los, his = wave_hits(props, ex, fval_x)
+            ghits = bool_any(hits)
+            pri = jnp.where(hits, me, jnp.uint32(S))
+            winner = lax.pmin(pri, "shard")
+            sel = hits & (pri == winner)
+            g_lo = lax.psum(jnp.where(sel, los, jnp.uint32(0)), "shard")
+            g_hi = lax.psum(jnp.where(sel, his, jnp.uint32(0)), "shard")
+            fresh = ghits & ~c["disc_found"]
+            return (
+                c["disc_found"] | ghits,
+                jnp.where(fresh, g_lo, c["disc_lo"]),
+                jnp.where(fresh, g_hi, c["disc_hi"]),
+            )
+
+        def route_stage(c, R, k_lo, k_hi, cand_valid, n_cand, Bd,
+                        payload):
+            """Route+compact ``R`` candidate rows in ONE sort ordered by
+            (owner, key): valid candidates form S contiguous
+            destination runs (invalid rows carry owner=S and sort
+            last); ``payload(s_row, s_lo, s_hi)`` builds the routed
+            rows in that order, written into the ``route`` lane.
+            Returns the stage's carry part, the local ``route_ovf``
+            (a run above the per-destination cap ``Bd``) and the rows
+            routed off-shard."""
+            me = lax.axis_index("shard").astype(jnp.uint32)
+            owner = jnp.where(
+                cand_valid, k_lo % jnp.uint32(S), jnp.uint32(S)
+            )
+            rows = jnp.arange(R, dtype=jnp.uint32)
+            s_owner, s_hi, s_lo, s_row = lax.sort(
+                (owner, k_hi, k_lo, rows), num_keys=3
+            )
+            # s_owner is sorted: all destination-run boundaries in one
+            # searchsorted pass.
+            edges = jnp.searchsorted(
+                s_owner, jnp.arange(S + 1, dtype=jnp.uint32)
+            ).astype(jnp.uint32)
+            starts = edges[:-1]
+            counts = edges[1:] - starts
+            route_ovf = jnp.any(counts > Bd)
+            cross = n_cand - counts[me]
+            g_cross = lax.psum(cross.astype(jnp.uint32), "shard")
+            sent = u64_add(
+                U64(c["sent_lo"], c["sent_hi"]),
+                U64(g_cross, jnp.uint32(0)),
+            )
+            out = dict(
+                route=lax.dynamic_update_slice(
+                    c["route"], payload(s_row, s_lo, s_hi),
+                    (jnp.uint32(0), jnp.uint32(0)),
+                ),
+                starts=starts,
+                counts=counts,
+                n_cand=n_cand.astype(jnp.uint32),
+                sent_lo=sent.lo,
+                sent_hi=sent.hi,
+            )
+            return out, route_ovf, cross.astype(jnp.uint32)
+
+        def wave_expand(c):
+            if target_depth is None:
+                return jnp.bool_(True)
+            return c["depth"] < target_depth
+
         def make_route(fc: int):
-            """The send side at frontier class ``fc``: expansion,
+            """The dense send side at frontier class ``fc``: expansion,
             fingerprints, the routing sort and the routed payload,
             written into the ``route`` lane."""
-            F_c, NT, T, R_src, B_c, Bd_c = f_params[fc]
+            F_c, _NT, _T, R_src, _B_c, Bd_c = f_params[fc]
 
             def route(c):
-                frontier_t = c["frontier"][:, :F_c]
                 fval_c = c["fval"][:F_c]
-                ebits_c = c["ebits"][:F_c]
-                me = lax.axis_index("shard").astype(jnp.uint32)
-
-                if target_depth is None:
-                    expand = jnp.bool_(True)
-                else:
-                    expand = c["depth"] < target_depth
-
-                e_overflow = c["e_overflow"]
-                c_overflow = c["c_overflow"]
-
-                if use_sparse:
-                    # Sparse action dispatch, shard-local: the shared
-                    # pair pipeline (checkers/tpu_sortmerge.py), then
-                    # per-pair transitions — only real candidates
-                    # enter the routing sort and the shuffle.
-                    cond, eb, fp_lo, fp_hi = frontier_props_t(
-                        enc, props, evt_idx, frontier_t, fval_c,
-                        ebits_c, sym_spec=sym,
-                    )
-                    (
-                        pidx, live, pslot, cnt, n_pairs, pair_ovf, _tm,
-                    ) = sparse_pair_candidates(
-                        # full resident buffer + explicit class width
-                        # (a strided column-prefix slice as a loop
-                        # operand would copy per wave — see the
-                        # n_rows note on the shared pipeline)
-                        enc, c["frontier"], fval_c, expand,
-                        EV=EV, B_p=B_c, NT=NT, T=T,
-                        mask_budget_cells=self.mask_budget_cells,
-                        Ba=R_src, axis_name="shard", n_rows=F_c,
-                        ample_words=ample_words,
-                    )
-                    # Pair-state gather seam: the shared backend
-                    # policy (encoding.pair_step_seam).
-                    pair_states = make_pair_states(c["frontier"],
-                                                   frontier_t)
-                    c_overflow = c_overflow | bool_any(pair_ovf)
-                    prow = pidx // jnp.uint32(EV)
-                    needs_scan = sparse_boundary or sparse_has_trunc
-
-                    def eval_pairs(pidx_b, live_b, slot_b):
-                        prow_b = pidx_b // jnp.uint32(EV)
-                        succ_t, ptr_b, hard_b = step_cols(
-                            pair_states(prow_b), slot_b
-                        )
-                        # hard trunc (unrepresentable successor, e.g.
-                        # an un-harvested history transition) is raised
-                        # regardless of the boundary — the garbage
-                        # successor can't faithfully evaluate it.
-                        eov = jnp.bool_(False)
-                        if hard_b is not None:
-                            eov = jnp.any(live_b & hard_b)
-                            live_b = live_b & ~hard_b
-                        if sparse_boundary:
-                            inb = within_boundary_cols(enc, succ_t)
-                            ok = live_b & inb
-                        else:
-                            ok = live_b
-                        if ptr_b is not None:
-                            eov = eov | jnp.any(ok & ptr_b)
-                            ok = ok & ~ptr_b
-                        if sym is not None:
-                            # canonical key, concrete successor block
-                            canon_t = canonicalize_t(sym, succ_t, jnp)
-                            lo, hi = fingerprint_u32v_t(canon_t, jnp)
-                        else:
-                            lo, hi = fingerprint_u32v_t(succ_t, jnp)
-                        lo, hi = clamp_keys(lo, hi)
-                        return succ_t, lo, hi, ok, prow_b, eov
-
-                    # Memory-lean mode (mirrors the single-chip chunked
-                    # path): when the [R_src, W] successor tensor would
-                    # blow the flat budget, fingerprint pairs in chunks
-                    # and RECOMPUTE the routed tiles' successors in the
-                    # receive class (step_slot purity makes this exact).
-                    chunked = (
-                        R_src * row_pad > self.flat_budget_bytes
-                    )
-                    if chunked:
-                        NC = -(-(R_src * row_pad)
-                               // self.flat_budget_bytes)
-                        Bc = -(-R_src // NC)
-                        pad = NC * Bc - R_src
-                        pidx_p = jnp.pad(pidx, (0, pad))
-                        live_p = jnp.pad(live, (0, pad))
-                        pslot_p = jnp.pad(pslot, (0, pad))
-
-                        def fchunk(ti, acc):
-                            kl, kh, pok, nc, eov, rok = acc
-                            off = ti * Bc
-                            pb = lax.dynamic_slice(pidx_p, (off,), (Bc,))
-                            lb = lax.dynamic_slice(live_p, (off,), (Bc,))
-                            sb = lax.dynamic_slice(
-                                pslot_p, (off,), (Bc,)
-                            )
-                            _, lo, hi, ok, prow_b, ev = eval_pairs(
-                                pb, lb, sb
-                            )
-                            kl = lax.dynamic_update_slice(kl, lo, (off,))
-                            kh = lax.dynamic_update_slice(kh, hi, (off,))
-                            pok = lax.dynamic_update_slice(
-                                pok, ok, (off,)
-                            )
-                            if needs_scan:
-                                nc = nc + jnp.sum(ok, dtype=jnp.uint32)
-                                rok = rok.at[
-                                    jnp.where(
-                                        ok, prow_b, jnp.uint32(F_c)
-                                    )
-                                ].max(jnp.uint32(1), mode="drop")
-                            return kl, kh, pok, nc, eov | ev, rok
-
-                        def pv(x):
-                            return lax.pcast(x, "shard", to="varying")
-
-                        kl, kh, pok, nc_acc, eov_acc, row_ok = (
-                            lax.fori_loop(
-                                0,
-                                NC,
-                                fchunk,
-                                (
-                                    pv(jnp.zeros(NC * Bc, jnp.uint32)),
-                                    pv(jnp.zeros(NC * Bc, jnp.uint32)),
-                                    pv(jnp.zeros(NC * Bc, bool)),
-                                    pv(jnp.uint32(0)),
-                                    pv(jnp.bool_(False)),
-                                    pv(jnp.zeros(
-                                        F_c if needs_scan else 1,
-                                        jnp.uint32,
-                                    )),
-                                ),
-                            )
-                        )
-                        k_lo = kl[:R_src]
-                        k_hi = kh[:R_src]
-                        pair_ok = pok[:R_src]
-                        e_overflow = e_overflow | bool_any(eov_acc)
-                        if needs_scan:
-                            has_succ = row_ok != 0
-                            n_cand = nc_acc
-                        else:
-                            has_succ = cnt > 0
-                            n_cand = n_pairs
-                        cand_state = None  # the receive class recomputes
-                    else:
-                        (succ_t, k_lo, k_hi, pair_ok, _,
-                         eov) = eval_pairs(pidx, live, pslot)
-                        e_overflow = e_overflow | bool_any(eov)
-                        if needs_scan:
-                            row_ok = jnp.zeros(F_c, jnp.uint32).at[
-                                jnp.where(
-                                    pair_ok, prow, jnp.uint32(F_c)
-                                )
-                            ].max(jnp.uint32(1), mode="drop")
-                            has_succ = row_ok != 0
-                            n_cand = jnp.sum(pair_ok, dtype=jnp.uint32)
-                        else:
-                            has_succ = cnt > 0
-                            n_cand = n_pairs
-                        # Routed-tile staging is a gather seam: the
-                        # successor block transposes back to rows once
-                        # (PERF.md §layout — row-major gathers win).
-                        cand_state = succ_t.T
-                    terminal = fval_c & ~has_succ & expand
-                    evt_cex = terminal & (eb != 0)
-                    ex = dict(
-                        cond=cond, ebits=eb, evt_cex=evt_cex,
-                        f_lo=fp_lo, f_hi=fp_hi,
-                    )
-                    cand_valid = pair_ok
-                    cand_par = prow
-                else:
-                    # Dense expansion: one seam transpose of the class
-                    # prefix (step_vec is the row contract).
-                    frontier_rows = frontier_t.T
-                    ex = expand_frontier(
-                        enc, props, evt_idx, frontier_rows, fval_c,
-                        ebits_c, expand, with_repeats=False,
-                        sym_spec=sym,
-                    )
-                    e_overflow = e_overflow | bool_any(
-                        jnp.any(ex["trunc"])
-                    )
-                    cand_state, cand_valid = ex["flat"], ex["v"]
-                    n_cand = jnp.sum(cand_valid).astype(jnp.uint32)
-                    fp_flat = (canonicalize_rows(sym, cand_state, jnp)
-                               if sym is not None else cand_state)
-                    k_lo, k_hi = fingerprint_u32v(fp_flat, jnp)
-                    k_lo, k_hi = clamp_keys(k_lo, k_hi)
-                    cand_par = None  # parent row = candidate // K
-
-                # Discoveries: local per-wave hits, globally folded
-                # (the lowest hitting shard index wins, mirroring
-                # whichever racing thread lands first in the
-                # reference).
-                if n_props:
-                    hits, los, his = wave_hits(props, ex, fval_c)
-                    ghits = bool_any(hits)
-                    pri = jnp.where(hits, me, jnp.uint32(S))
-                    winner = lax.pmin(pri, "shard")
-                    sel = hits & (pri == winner)
-                    g_lo = lax.psum(
-                        jnp.where(sel, los, jnp.uint32(0)), "shard"
-                    )
-                    g_hi = lax.psum(
-                        jnp.where(sel, his, jnp.uint32(0)), "shard"
-                    )
-                    fresh = ghits & ~c["disc_found"]
-                    disc_found = c["disc_found"] | ghits
-                    disc_lo = jnp.where(fresh, g_lo, c["disc_lo"])
-                    disc_hi = jnp.where(fresh, g_hi, c["disc_hi"])
-                else:
-                    disc_found = c["disc_found"]
-                    disc_lo = c["disc_lo"]
-                    disc_hi = c["disc_hi"]
-
-                owner = jnp.where(
-                    cand_valid, k_lo % jnp.uint32(S), jnp.uint32(S)
+                expand = wave_expand(c)
+                # Dense expansion: one seam transpose of the class
+                # prefix (step_vec is the row contract).
+                frontier_rows = c["frontier"][:, :F_c].T
+                ex = expand_frontier(
+                    enc, props, evt_idx, frontier_rows, fval_c,
+                    c["ebits"][:F_c], expand, with_repeats=False,
+                    sym_spec=sym,
                 )
-
-                # Route+compact in ONE sort: order by (owner, key);
-                # valid candidates form S contiguous destination runs
-                # (invalid rows carry owner=S and sort last).
-                rows = jnp.arange(R_src, dtype=jnp.uint32)
-                s_owner, s_hi, s_lo, s_row = lax.sort(
-                    (owner, k_hi, k_lo, rows), num_keys=3
+                e_overflow = c["e_overflow"] | bool_any(
+                    jnp.any(ex["trunc"])
                 )
-                # s_owner is sorted: all destination-run boundaries in
-                # one searchsorted pass.
-                edges = jnp.searchsorted(
-                    s_owner, jnp.arange(S + 1, dtype=jnp.uint32)
-                ).astype(jnp.uint32)
-                starts = edges[:-1]
-                counts = edges[1:] - starts
-                route_ovf = jnp.any(counts > jnp.uint32(Bd_c))
-                c_overflow = c_overflow | bool_any(route_ovf)
-
-                # The routed payload in ONE gather (PERF.md §gathers:
-                # TPU gathers cost ~12ns/row regardless of lane count,
-                # so one [R_src, lanes] multi-lane gather replaces
-                # per-destination payload/fp/ebits/key gathers; the
-                # receive class's tiles are then slices). Parent meta
-                # (ebits + parent fp) packs into the same payload:
-                # broadcast for dense (candidate // K is a K-fold
-                # repeat, no gather), one packed gather for sparse.
+                cand_state, cand_valid = ex["flat"], ex["v"]
+                n_cand = jnp.sum(cand_valid).astype(jnp.uint32)
+                fp_flat = (canonicalize_rows(sym, cand_state, jnp)
+                           if sym is not None else cand_state)
+                k_lo, k_hi = fingerprint_u32v(fp_flat, jnp)
+                k_lo, k_hi = clamp_keys(k_lo, k_hi)
+                disc_found, disc_lo, disc_hi = fold_discoveries(
+                    c, ex, fval_c
+                )
+                # Parent meta (ebits + parent fp) packs into the
+                # payload as a broadcast: candidate // K is a K-fold
+                # repeat, no gather. The routed payload is ONE gather
+                # (PERF.md §gathers: TPU gathers cost ~12ns/row
+                # regardless of lane count; the receive class's tiles
+                # are then slices).
                 fr_meta = jnp.stack(
                     [ex["ebits"]]
                     + ([ex["f_lo"], ex["f_hi"]] if track_paths else []),
                     axis=1,
                 )
-                if cand_par is None:
-                    pmeta = jnp.broadcast_to(
-                        fr_meta[:, None, :],
-                        (F_c, K, fr_meta.shape[1]),
-                    ).reshape(R_src, fr_meta.shape[1])
-                else:
-                    pmeta = fr_meta[cand_par]
-                if recompute:
-                    # successors are never materialized at [R_src, W]
-                    # here: the receive class recomputes its tiles'
-                    # successors from (pair, slot)
-                    spay = jnp.concatenate(
-                        [
-                            jnp.concatenate(
-                                [pidx[:, None], pslot[:, None], pmeta],
-                                axis=1,
-                            )[s_row],
-                            jnp.stack([s_lo, s_hi], axis=1),
-                        ],
-                        axis=1,
-                    )
-                else:
-                    spay = dest_tile_pack(
+                pmeta = jnp.broadcast_to(
+                    fr_meta[:, None, :], (F_c, K, fr_meta.shape[1]),
+                ).reshape(R_src, fr_meta.shape[1])
+
+                def payload(s_row, _s_lo, _s_hi):
+                    return dest_tile_pack(
                         jnp, cand_state,
                         pmeta[:, 1:2] if track_paths else None,
                         pmeta[:, 2:3] if track_paths else None,
                         pmeta[:, 0:1], k_lo, k_hi,
                     )[s_row]
-                cross = n_cand - counts[me]
-                g_cross = lax.psum(cross.astype(jnp.uint32), "shard")
-                sent = u64_add(
-                    U64(c["sent_lo"], c["sent_hi"]),
-                    U64(g_cross, jnp.uint32(0)),
+
+                out, route_ovf, cross = route_stage(
+                    c, R_src, k_lo, k_hi, cand_valid, n_cand,
+                    jnp.uint32(Bd_c), payload,
                 )
-                out = dict(
-                    route=lax.dynamic_update_slice(
-                        c["route"], spay,
-                        (jnp.uint32(0), jnp.uint32(0)),
-                    ),
-                    starts=starts,
-                    counts=counts,
-                    n_cand=n_cand.astype(jnp.uint32),
-                    sent_lo=sent.lo,
-                    sent_hi=sent.hi,
+                out.update(
                     disc_found=disc_found,
                     disc_lo=disc_lo,
                     disc_hi=disc_hi,
-                    c_overflow=c_overflow,
+                    c_overflow=c["c_overflow"] | bool_any(route_ovf),
                     e_overflow=e_overflow,
                 )
                 if trace_log:
                     # Routing-stage lanes of the per-shard log: the
-                    # local enabled-pair popcount (the quantity the
-                    # global log can't see; candidates on the dense
-                    # path, mirroring the single-chip convention) and
-                    # the rows routed off-shard.
-                    out.update(
-                        wv_pairs=(n_pairs if use_sparse
-                                  else n_cand).astype(jnp.uint32),
-                        cross=cross.astype(jnp.uint32),
-                    )
+                    # candidate count (the dense path has no pairs,
+                    # mirroring the single-chip convention) and the
+                    # rows routed off-shard.
+                    out.update(wv_pairs=n_cand, cross=cross,
+                               pair_cap=jnp.uint32(0))
                 return out
 
             return route
+
+        def make_pairs(fc: int):
+            """The sparse send side's frontier-class half at class
+            ``fc``: properties, the enabled bits, the peel and the
+            compaction of the live (pair, slot) indices into the dense
+            prefix of the ``R_pair``-row pair buffer — the shared pair
+            pipeline (checkers/tpu_sortmerge.py) with compaction
+            forced. The frontier-row outputs (property bits, ebits,
+            fingerprints, rows with enabled pairs) are written into
+            F-row blocks at offset 0; rows past ``F_c`` are dead
+            (``fval`` is False there)."""
+            F_c, NT, T, _R_src, B_c, Bd_c = f_params[fc]
+
+            def to_f(x):
+                if F_c == F:
+                    return x
+                return lax.dynamic_update_slice(
+                    jnp.zeros((F,) + x.shape[1:], x.dtype), x,
+                    (jnp.uint32(0),) * x.ndim,
+                )
+
+            def pairs(c):
+                cond, eb, fp_lo, fp_hi = frontier_props_t(
+                    enc, props, evt_idx, c["frontier"][:, :F_c],
+                    c["fval"][:F_c], c["ebits"][:F_c], sym_spec=sym,
+                )
+                (
+                    pidx, live, pslot, cnt, n_pairs, pair_ovf, _tm,
+                ) = sparse_pair_candidates(
+                    # full resident buffer + explicit class width (a
+                    # strided column-prefix slice as a loop operand
+                    # would copy per wave — see the n_rows note on the
+                    # shared pipeline)
+                    enc, c["frontier"], c["fval"][:F_c], wave_expand(c),
+                    EV=EV, B_p=B_c, NT=NT, T=T,
+                    mask_budget_cells=self.mask_budget_cells,
+                    Ba=R_pair, axis_name="shard", n_rows=F_c,
+                    ample_words=ample_words, compact=True,
+                )
+                return dict(
+                    pkey=jnp.where(live, pidx, jnp.uint32(_SENT)),
+                    pslot=pslot,
+                    n_pairs=n_pairs,
+                    pair_ovf=pair_ovf,
+                    dest_cap=jnp.uint32(Bd_c),
+                    cond=to_f(cond),
+                    ebits=to_f(eb),
+                    f_lo=to_f(fp_lo),
+                    f_hi=to_f(fp_hi),
+                    has_succ=to_f(cnt > 0),
+                )
+
+            return pairs
+
+        def make_pair_route(pc: int):
+            """The sparse send side's pair-class half at pair class
+            ``pc``: the first ``P_r`` rows of the pair buffer are
+            stepped and fingerprinted, routed by the (owner, key) sort
+            and their payload written into the ``route`` lane."""
+            P_r = p_ladder[pc]
+            # Memory-lean mode (mirrors the single-chip chunked path):
+            # when the [P_r, W] successor tensor would blow the flat
+            # budget, fingerprint pairs in chunks and RECOMPUTE the
+            # routed tiles' successors in the receive class
+            # (step_slot purity makes this exact).
+            chunked = P_r * row_pad > self.flat_budget_bytes
+            needs_scan = sparse_boundary or sparse_has_trunc
+
+            def pair_route(args):
+                c, fo = args
+                pkey = fo["pkey"][:P_r]
+                live = pkey != jnp.uint32(_SENT)
+                pidx = jnp.where(live, pkey, jnp.uint32(0))
+                pslot = fo["pslot"][:P_r]
+                prow = pidx // jnp.uint32(EV)
+                # Pair-state gather seam: the shared backend policy
+                # (encoding.pair_step_seam), over the whole resident
+                # frontier (this class does not know the frontier
+                # class; prow < F_c).
+                pair_states = make_pair_states(c["frontier"],
+                                               c["frontier"])
+
+                def eval_pairs(pidx_b, live_b, slot_b):
+                    prow_b = pidx_b // jnp.uint32(EV)
+                    succ_t, ptr_b, hard_b = step_cols(
+                        pair_states(prow_b), slot_b
+                    )
+                    # hard trunc (unrepresentable successor, e.g. an
+                    # un-harvested history transition) is raised
+                    # regardless of the boundary — the garbage
+                    # successor can't faithfully evaluate it.
+                    eov = jnp.bool_(False)
+                    if hard_b is not None:
+                        eov = jnp.any(live_b & hard_b)
+                        live_b = live_b & ~hard_b
+                    if sparse_boundary:
+                        ok = live_b & within_boundary_cols(enc, succ_t)
+                    else:
+                        ok = live_b
+                    if ptr_b is not None:
+                        eov = eov | jnp.any(ok & ptr_b)
+                        ok = ok & ~ptr_b
+                    if sym is not None:
+                        # canonical key, concrete successor block
+                        canon_t = canonicalize_t(sym, succ_t, jnp)
+                        lo, hi = fingerprint_u32v_t(canon_t, jnp)
+                    else:
+                        lo, hi = fingerprint_u32v_t(succ_t, jnp)
+                    lo, hi = clamp_keys(lo, hi)
+                    return succ_t, lo, hi, ok, prow_b, eov
+
+                row_ok = None
+                if chunked:
+                    NC = -(-(P_r * row_pad) // self.flat_budget_bytes)
+                    Bc = -(-P_r // NC)
+                    pad = NC * Bc - P_r
+                    pidx_p = jnp.pad(pidx, (0, pad))
+                    live_p = jnp.pad(live, (0, pad))
+                    pslot_p = jnp.pad(pslot, (0, pad))
+
+                    def fchunk(ti, acc):
+                        kl, kh, pok, nc, eov, rok = acc
+                        off = ti * Bc
+                        pb = lax.dynamic_slice(pidx_p, (off,), (Bc,))
+                        lb = lax.dynamic_slice(live_p, (off,), (Bc,))
+                        sb = lax.dynamic_slice(pslot_p, (off,), (Bc,))
+                        _, lo, hi, ok, prow_b, ev = eval_pairs(
+                            pb, lb, sb
+                        )
+                        kl = lax.dynamic_update_slice(kl, lo, (off,))
+                        kh = lax.dynamic_update_slice(kh, hi, (off,))
+                        pok = lax.dynamic_update_slice(pok, ok, (off,))
+                        if needs_scan:
+                            nc = nc + jnp.sum(ok, dtype=jnp.uint32)
+                            rok = rok.at[
+                                jnp.where(ok, prow_b, jnp.uint32(F))
+                            ].max(jnp.uint32(1), mode="drop")
+                        return kl, kh, pok, nc, eov | ev, rok
+
+                    def pv(x):
+                        return lax.pcast(x, "shard", to="varying")
+
+                    kl, kh, pok, nc_acc, eov, rok = lax.fori_loop(
+                        0,
+                        NC,
+                        fchunk,
+                        (
+                            pv(jnp.zeros(NC * Bc, jnp.uint32)),
+                            pv(jnp.zeros(NC * Bc, jnp.uint32)),
+                            pv(jnp.zeros(NC * Bc, bool)),
+                            pv(jnp.uint32(0)),
+                            pv(jnp.bool_(False)),
+                            pv(jnp.zeros(F if needs_scan else 1,
+                                         jnp.uint32)),
+                        ),
+                    )
+                    k_lo, k_hi, pair_ok = kl[:P_r], kh[:P_r], pok[:P_r]
+                    if needs_scan:
+                        row_ok, n_cand = rok, nc_acc
+                    cand_state = None  # the receive class recomputes
+                else:
+                    (succ_t, k_lo, k_hi, pair_ok, _,
+                     eov) = eval_pairs(pidx, live, pslot)
+                    if needs_scan:
+                        row_ok = jnp.zeros(F, jnp.uint32).at[
+                            jnp.where(pair_ok, prow, jnp.uint32(F))
+                        ].max(jnp.uint32(1), mode="drop")
+                        n_cand = jnp.sum(pair_ok, dtype=jnp.uint32)
+                    # Routed-tile staging is a gather seam: the
+                    # successor block transposes back to rows once
+                    # (PERF.md §layout — row-major gathers win).
+                    cand_state = succ_t.T
+                if not needs_scan:
+                    n_cand = fo["n_pairs"]
+                # Parent meta (ebits + parent fp) of each pair's
+                # frontier row, one packed gather.
+                pmeta = jnp.stack(
+                    [fo["ebits"]]
+                    + ([fo["f_lo"], fo["f_hi"]] if track_paths else []),
+                    axis=1,
+                )[prow]
+
+                def payload(s_row, s_lo, s_hi):
+                    if recompute:
+                        # successors are never materialized at
+                        # [P_r, W] here: the receive class recomputes
+                        # its tiles' successors from (pair, slot)
+                        return jnp.concatenate(
+                            [
+                                jnp.concatenate(
+                                    [pidx[:, None], pslot[:, None],
+                                     pmeta],
+                                    axis=1,
+                                )[s_row],
+                                jnp.stack([s_lo, s_hi], axis=1),
+                            ],
+                            axis=1,
+                        )
+                    # The routed payload in ONE gather (PERF.md
+                    # §gathers: ~12ns/row regardless of lane count).
+                    return dest_tile_pack(
+                        jnp, cand_state,
+                        pmeta[:, 1:2] if track_paths else None,
+                        pmeta[:, 2:3] if track_paths else None,
+                        pmeta[:, 0:1], k_lo, k_hi,
+                    )[s_row]
+
+                out, route_ovf, cross = route_stage(
+                    c, P_r, k_lo, k_hi, pair_ok, n_cand, fo["dest_cap"],
+                    payload,
+                )
+                out.update(route_ovf=bool_any(route_ovf),
+                           eov=bool_any(eov))
+                if needs_scan:
+                    out.update(has_succ=row_ok != 0)
+                if trace_log:
+                    out.update(cross=cross, pair_cap=jnp.uint32(P_r))
+                return out
+
+            return pair_route
+
+        def send_sparse(c, f_class):
+            """The sparse send side: the frontier-class switch, the
+            pair class agreed by every shard (the smallest rung that
+            holds the largest shard's pair count), the pair-class
+            switch, then the wave's discoveries and overflow flags
+            outside both switches."""
+            fo = lax.switch(
+                f_class, [make_pairs(fc) for fc in range(len(f_ladder))],
+                c,
+            )
+            p_class = jnp.asarray(
+                class_of(lax.pmax(fo["n_pairs"], "shard"),
+                                p_ladder),
+                jnp.int32,
+            )
+            pr = lax.switch(
+                p_class,
+                [make_pair_route(pc) for pc in range(len(p_ladder))],
+                (c, fo),
+            )
+            has_succ = pr.pop("has_succ", fo["has_succ"])
+            terminal = c["fval"] & ~has_succ & wave_expand(c)
+            ex = dict(
+                cond=fo["cond"], ebits=fo["ebits"],
+                evt_cex=terminal & (fo["ebits"] != 0),
+                f_lo=fo["f_lo"], f_hi=fo["f_hi"],
+            )
+            disc_found, disc_lo, disc_hi = fold_discoveries(
+                c, ex, c["fval"]
+            )
+            route_ovf, eov = pr.pop("route_ovf"), pr.pop("eov")
+            rt = dict(
+                pr,
+                disc_found=disc_found,
+                disc_lo=disc_lo,
+                disc_hi=disc_hi,
+                c_overflow=c["c_overflow"] | bool_any(fo["pair_ovf"])
+                | route_ovf,
+                e_overflow=c["e_overflow"] | eov,
+            )
+            if trace_log:
+                # the local enabled-pair popcount: the quantity the
+                # global log can't see
+                rt.update(wv_pairs=fo["n_pairs"].astype(jnp.uint32))
+            return rt, p_class
 
         def make_recv(rc: int, v_class):
             """The receive side at receive class ``rc``: per-destination
@@ -1380,7 +1509,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     # travelled in (the receive class).
                     shard_log = (
                         rt["wv_pairs"], rt["cross"], jnp.max(counts),
-                        jnp.uint32(Bd_r),
+                        jnp.uint32(Bd_r), rt["pair_cap"],
                     )
                 return merge_stage(
                     c, v_class, S * Bd_r, recv, rt["n_cand"],
@@ -1395,12 +1524,15 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
 
         # Memory ledger (stateright_tpu/memplan.py): per-ladder-class
         # staging rows, PER SHARD (the shard_map body's view), from
-        # the same _f_class_params the wave programs compile from. The
-        # chunked memory-lean gate mirrors make_route's (R_src rows at
-        # the padded ~512 B/row cost vs the flat budget); chunked
-        # classes land an ``engine_mode`` record like the single-chip
-        # engine's. The tiles are priced at the largest receive class
-        # a wave of the frontier class can take.
+        # the same _f_class_params the wave programs compile from.
+        # Sparse classes price the pair buffer and the stepped rows at
+        # the largest pair class a wave of the frontier class can take
+        # (its candidate budget B_c); the chunked memory-lean gate
+        # mirrors make_pair_route's (P_r rows at the padded ~512 B/row
+        # cost vs the flat budget), and chunked classes land an
+        # ``engine_mode`` record like the single-chip engine's. The
+        # tiles are priced at the largest receive class a wave of the
+        # frontier class can take.
         from ..memplan import buffer_entry, plan_total
 
         from ..ops.bitmask import mask_words as _mask_words
@@ -1409,9 +1541,11 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         _modes = []
         for fc in range(len(f_ladder)):
             F_c, NT_c, _T_c, R_src, B_c, Bd_c = f_params[fc]
-            Bd_hi = r_ladder[recv_tile_class(Bd_c, r_ladder)]
+            Bd_hi = r_ladder[class_of(Bd_c, r_ladder)]
+            R_step = (p_ladder[class_of(B_c, p_ladder)]
+                      if use_sparse else R_src)
             staging = [
-                buffer_entry("cand_keys", (2, R_src), "uint32"),
+                buffer_entry("cand_keys", (2, R_step), "uint32"),
                 buffer_entry("send_tiles", (S * Bd_hi, E + 2),
                              "uint32"),
                 buffer_entry("recv_tiles", (S * Bd_hi, E + 2),
@@ -1423,25 +1557,25 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     "enabled_bits", (F_c, _mask_words(K)), "uint32"
                 ))
                 staging.insert(1, buffer_entry(
-                    "pair_index", (3, R_src), "uint32"
+                    "pair_buffer", (2, R_pair), "uint32"
                 ))
-                chunked_c = R_src * row_pad > self.flat_budget_bytes
+                chunked_c = R_step * row_pad > self.flat_budget_bytes
                 if chunked_c:
-                    NC_c = -(-(R_src * row_pad)
+                    NC_c = -(-(R_step * row_pad)
                              // self.flat_budget_bytes)
-                    Bc_c = -(-R_src // NC_c)
+                    Bc_c = -(-R_step // NC_c)
                     staging.append(buffer_entry(
                         "succ_chunk", (W, Bc_c), "uint32"
                     ))
                     _modes.append(dict(
                         engine=type(self).__name__, mode="chunked",
-                        f_class=fc, buffer_rows=R_src, chunks=NC_c,
+                        f_class=fc, buffer_rows=R_step, chunks=NC_c,
                         chunk_rows=Bc_c, row_pad_bytes=row_pad,
                         flat_budget_bytes=self.flat_budget_bytes,
                     ))
                 else:
                     staging.append(buffer_entry(
-                        "succ_t", (W, R_src), "uint32"
+                        "succ_t", (W, R_step), "uint32"
                     ))
             else:
                 staging.insert(0, buffer_entry(
@@ -1452,7 +1586,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 mode=("chunked" if chunked_c
                       else "sparse" if use_sparse else "dense"),
                 frontier_rows=F_c, budget_rows=B_c, tiles=NT_c,
-                buffer_rows=R_src, dest_cap=Bd_c,
+                buffer_rows=R_src, step_rows=R_step, dest_cap=Bd_c,
                 staging=staging, staging_bytes=plan_total(staging),
             ))
         from ..memplan import v_class_entries
@@ -1460,6 +1594,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
         self._build_info = dict(
             classes=_classes,
             v_classes=v_class_entries(v_ladder, min(F, S * r_ladder[-1])),
+            pair_classes=p_ladder,
             recv_classes=r_ladder,
             engine_modes=_modes,
         )
@@ -1486,15 +1621,18 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                 n_tot = lax.psum(c["n_loc"][0], "shard")
             ci = {k: v for k, v in c.items()
                   if k not in ("wlog", "slog", "pstash")}
-            rt = lax.switch(
-                f_class,
-                [make_route(fc) for fc in range(len(f_ladder))],
-                ci,
-            )
+            if use_sparse:
+                rt, p_class = send_sparse(ci, f_class)
+            else:
+                rt = lax.switch(
+                    f_class,
+                    [make_route(fc) for fc in range(len(f_ladder))],
+                    ci,
+                )
             # The receive class, agreed like the others: the smallest
             # rung holding every shard's largest destination run.
             r_class = jnp.asarray(
-                recv_tile_class(
+                class_of(
                     lax.pmax(jnp.max(rt["counts"]), "shard"), r_ladder
                 ),
                 jnp.int32,
@@ -1512,6 +1650,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     jnp.arange(len(r_ladder)) == r_class
                 ).astype(jnp.uint32),
             )
+            if use_sparse:
+                c2["pair_waves"] = c["pair_waves"] + (
+                    jnp.arange(len(p_ladder)) == p_class
+                ).astype(jnp.uint32)
             if trace_log and tier_mode:
                 # the wave-log/shard-log rows can't be written yet —
                 # the confirmed counts settle at the NEXT dispatch's
@@ -1606,8 +1748,10 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             ]
             if trace_log:
                 parts.append(c["wlog"].reshape(-1))
-            # waves per receive class, smallest first: the last lanes
-            # (_consume_extra_stats)
+            # waves per pair class, then per receive class, smallest
+            # first: the last lanes (_consume_extra_stats)
+            if use_sparse:
+                parts.append(c["pair_waves"])
             parts.append(c["recv_waves"])
             stats = jnp.concatenate(parts)
             if trace_log:
@@ -1635,6 +1779,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
             pl_n=P_shard,
             route=P("shard", None),
             recv_waves=P(),
+            **(dict(pair_waves=P()) if use_sparse else {}),
             frontier=P(None, "shard"),
             fval=P_shard,
             ebits=P_shard,
@@ -1807,6 +1952,7 @@ class ShardedSortMergeTpuBfsChecker(SortMergeTpuBfsChecker):
                     sw = jnp.concatenate([
                         c["swave"][:7],
                         jnp.stack([conf, u_loc2[0]]),
+                        c["swave"][9:],
                     ])
                     out["slog"] = lax.dynamic_update_slice(
                         c["slog"], sw[None, :],

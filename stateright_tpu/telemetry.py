@@ -139,7 +139,7 @@ WAVE_LOG_OPT_FIELDS = (
 #: global ``wave`` event from the shard sum). On dense paths — which
 #: have no (row, slot) pair extraction — the lane holds the candidate
 #: count, mirroring the single-chip dense wave's convention.
-SHARD_LOG_LANES = 9
+SHARD_LOG_LANES = 10
 SHARD_LOG_FIELDS = (
     "frontier_rows",   # live rows entering the wave on this shard
     "enabled_pairs",   # local enabled-pair popcount (candidates on dense)
@@ -150,6 +150,16 @@ SHARD_LOG_FIELDS = (
     "dest_cap",        # the wave's receive-tile class (rows, Bd_r)
     "new_states",      # post-dedup winners this shard appended
     "visited_total",   # this shard's visited count AFTER the wave
+)
+
+#: optional trailing lanes of the per-shard log (after
+#: SHARD_LOG_FIELDS), recorded on ``shard_wave`` events when the row
+#: carries them. ``pair_cap``: the wave's pair class (rows the sparse
+#: send side stepped, fingerprinted and routed; 0 on the dense path).
+#: NOT in the trace-validation REQUIRED set, so traces written before
+#: it stay valid.
+SHARD_LOG_OPT_FIELDS = (
+    "pair_cap",        # the wave's pair class (rows, P_r)
 )
 
 #: compile-cache hit tiers a ``program_build`` event may carry
@@ -609,8 +619,10 @@ class RunTracer:
                         dict(
                             ev="shard_wave", run=self._run_idx,
                             wave=wave0 + i, chunk=chunk, shard=s,
-                            **dict(zip(SHARD_LOG_FIELDS,
-                                       [int(x) for x in srows[i]])),
+                            **dict(zip(
+                                SHARD_LOG_FIELDS + SHARD_LOG_OPT_FIELDS,
+                                [int(x) for x in srows[i]],
+                            )),
                         )
                     )
 

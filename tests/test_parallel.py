@@ -305,13 +305,13 @@ def test_recv_tile_class(count, want):
     import jax.numpy as jnp
 
     from stateright_tpu.parallel.engine_sortmerge import (
-        recv_ladder,
-        recv_tile_class,
+        class_ladder,
+        class_of,
     )
 
-    assert recv_ladder(1 << 19) == _RECV_LADDER
+    assert class_ladder(1 << 19) == _RECV_LADDER
     if want == "overflow":
-        assert recv_tile_class(count, _RECV_LADDER) == 3
+        assert class_of(count, _RECV_LADDER) == 3
         # the same past the top of a real engine's ladder: per-shard
         # destination runs of 2pc rm=3 outgrow an 8-row cap
         ck = (
@@ -330,8 +330,8 @@ def test_recv_tile_class(count, want):
             ck.join()
         assert ck._build_info["recv_classes"] == [8]
         return
-    assert recv_tile_class(count, _RECV_LADDER) == want
-    assert int(recv_tile_class(jnp.uint32(count), _RECV_LADDER)) == want
+    assert class_of(count, _RECV_LADDER) == want
+    assert int(class_of(jnp.uint32(count), _RECV_LADDER)) == want
 
 
 @pytest.fixture(scope="module")
@@ -435,3 +435,140 @@ def test_sharded_recv_class_waves_2pc_rm5():
         sum(1 for cap in caps.values() if cap == {rung})
         for rung in ladder
     ] == waves
+
+
+# -- the pair class (parallel/engine_sortmerge.py) -------------------------
+
+
+@pytest.fixture(scope="module")
+def mesh_cell_pair_ladder():
+    """The pair classes of the paxos-4 mesh cell's engine, as the CLI's
+    ``check-tpu 4 --shards 4`` builds it (no program is built)."""
+    import jax
+
+    from stateright_tpu.models.paxos import PaxosModelCfg, paxos_model
+    from stateright_tpu.models.paxos_tpu import SHARDED_SIZES
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the virtual CPU mesh")
+    ck = (
+        paxos_model(PaxosModelCfg(client_count=4, server_count=3))
+        .checker()
+        .spawn_tpu_sharded_sortmerge(
+            n_shards=4, track_paths=False, **SHARDED_SIZES[4]
+        )
+    )
+    return ck._pair_ladder()
+
+
+@pytest.mark.parametrize(
+    "n_pairs, want",
+    [
+        (0, 0),
+        (1 << 13, 0), ((1 << 13) + 1, 1),
+        (1 << 15, 1), ((1 << 15) + 1, 2),
+        (1 << 17, 2), ((1 << 17) + 1, 3),
+        (1 << 19, 3),
+    ],
+)
+def test_pair_class_boundaries(n_pairs, want, mesh_cell_pair_ladder):
+    """The mesh cell's pair classes are the class ladder over its
+    per-shard candidate budget, 2^19: a wave with no pairs takes the
+    floor, a count equal to a rung takes that rung, one more the next,
+    and the budget itself the top."""
+    import jax.numpy as jnp
+
+    from stateright_tpu.parallel.engine_sortmerge import class_of
+
+    ladder = mesh_cell_pair_ladder
+    assert ladder == _RECV_LADDER
+    assert class_of(n_pairs, ladder) == want
+    assert int(class_of(jnp.uint32(n_pairs), ladder)) == want
+
+
+@pytest.fixture(scope="module")
+def one_chip_2pc5():
+    return (
+        TwoPhaseSys(rm_count=5)
+        .checker()
+        .spawn_tpu_sortmerge(
+            capacity=1 << 15, frontier_capacity=1 << 13,
+            track_paths=False,
+        )
+        .join()
+    )
+
+
+@pytest.mark.parametrize(
+    "mode, kw",
+    [
+        ("untiered", {}),
+        ("tiered", dict(tier_hot_rows=256)),
+        ("chunked", dict(flat_budget_bytes=1 << 16)),
+    ],
+)
+def test_sharded_pair_classes_2pc_rm5(mode, kw, one_chip_2pc5):
+    """2pc rm=5 over four shards with a pair ladder of four rungs
+    (128 to 4,096 rows): untiered, tiered (the hot tier spills) and
+    chunked (the flat budget forces the recompute payload in every
+    class above the floor) each explore exactly the one-chip
+    engine's space, every wave runs in one pair class, more than one
+    class engages, and the traced run reports the untraced run's
+    per-class wave counts — in ``metrics``, in ``run_end`` and in
+    each shard's ``pair_cap`` lane."""
+    import jax
+
+    from stateright_tpu.telemetry import RunTracer
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the virtual CPU mesh")
+
+    def spawn():
+        return (
+            TwoPhaseSys(rm_count=5)
+            .checker()
+            .spawn_tpu_sharded_sortmerge(
+                n_shards=4,
+                capacity=1 << 12,
+                frontier_capacity=1 << 10,
+                cand_capacity=1 << 12,
+                waves_per_sync=4,
+                track_paths=False,
+                **kw,
+            )
+        )
+
+    c0 = spawn().join()
+    tr = RunTracer()
+    with tr.activate():
+        c1 = spawn().join()
+    ref = one_chip_2pc5
+    for c in (c0, c1):
+        assert c.unique_state_count() == ref.unique_state_count() == 8832
+        assert c.max_depth() == ref.max_depth()
+        assert (c.discovered_property_names()
+                == ref.discovered_property_names())
+    ladder = c1._build_info["pair_classes"]
+    assert ladder == [128, 256, 1024, 4096]
+    waves = c0.metrics["pair_class_waves"]
+    assert len(waves) == len(ladder)
+    assert sum(waves) == c0.metrics["waves"]
+    assert sum(1 for n in waves if n) > 1
+    assert c1.metrics["pair_class_waves"] == waves
+    end = next(e for e in tr.events if e["ev"] == "run_end")
+    assert end["pair_class_waves"] == waves
+    caps = {}
+    for e in tr.events:
+        if e["ev"] == "shard_wave":
+            caps.setdefault(e["wave"], set()).add(e["pair_cap"])
+            assert e["enabled_pairs"] <= e["pair_cap"]
+    assert [
+        sum(1 for cap in caps.values() if cap == {rung})
+        for rung in ladder
+    ] == waves
+    if mode == "tiered":
+        assert c0.metrics["tier_spills"] >= 1
+    if mode == "chunked":
+        assert {m["mode"] for m in c0._build_info["engine_modes"]} == {
+            "chunked"
+        }
